@@ -23,23 +23,28 @@ Public surface:
 
 __version__ = "0.1.0"
 
-from mpi_opt_tpu.space import (
-    SearchSpace,
-    Uniform,
-    LogUniform,
-    IntUniform,
-    Choice,
-)
-from mpi_opt_tpu.trial import Trial, TrialResult, TrialStatus
+# The public names resolve on first use (PEP 562): ``space`` imports
+# jax, and processes that must stay off jax import this package too —
+# the launch supervisor and ``chip_smoke.py``'s parent start children
+# that need the chip, which a parent that has touched jax would hold.
+_LAZY = {
+    "SearchSpace": "space",
+    "Uniform": "space",
+    "LogUniform": "space",
+    "IntUniform": "space",
+    "Choice": "space",
+    "Trial": "trial",
+    "TrialResult": "trial",
+    "TrialStatus": "trial",
+}
 
-__all__ = [
-    "SearchSpace",
-    "Uniform",
-    "LogUniform",
-    "IntUniform",
-    "Choice",
-    "Trial",
-    "TrialResult",
-    "TrialStatus",
-    "__version__",
-]
+__all__ = [*_LAZY, "__version__"]
+
+
+def __getattr__(name):
+    module = _LAZY.get(name)
+    if module is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    import importlib
+
+    return getattr(importlib.import_module(f"{__name__}.{module}"), name)

@@ -25,11 +25,12 @@ What is flagged, outside ``parallel/coord.py``:
 
 "Coord-ish" is judged lexically and conservatively: a string constant
 containing ``vote.json`` / ``decision.json``, or an identifier (name,
-attribute, string path segment) whose ``coord``/``coords`` appears as
-a whole ``_``-delimited word — so ``coord_dir``, ``args.coord_dir``,
-``"run/coord"`` all match while ``coordinator`` (the jax.distributed
-address plumbing) and ``coordinates`` never do. Reads stay free:
-status surfaces may inspect votes at will.
+attribute, string path segment) in which ``coord``, ``vote`` or
+``decision`` (or its plural) appears as a whole ``_``-delimited word —
+so ``coord_dir``, ``args.coord_dir`` and ``vote_path`` all match while
+``coordinator`` (the jax.distributed address plumbing) and
+``coordinates`` never do. Reads stay free: status surfaces may inspect
+votes at will.
 """
 
 from __future__ import annotations
@@ -39,10 +40,10 @@ import re
 
 from mpi_opt_tpu.analysis.core import Checker, FileContext
 
-#: `coord` / `coords` as a whole word inside an identifier's
-#: underscore-split (or at a dotted/word boundary): `coord_dir` yes,
-#: `args.coord` yes (attr == "coord"), `coordinator`/`coordinates` no
-_COORD_WORD = re.compile(r"(?:^|_)coords?(?:_|$)")
+#: `coord` / `vote` / `decision` (or the plural) as a whole word inside
+#: an identifier's underscore-split: `coord_dir` yes, `args.coord` yes
+#: (attr == "coord"), `vote_path` yes, `coordinator`/`coordinates` no
+_COORD_WORD = re.compile(r"(?:^|_)(?:coord|vote|decision)s?(?:_|$)")
 
 #: the plane's file-name suffixes; a constant carrying one IS an
 #: agreement path regardless of what the variable around it is called

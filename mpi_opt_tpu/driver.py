@@ -232,7 +232,9 @@ def run_search(
     """
     metrics = metrics or null_logger()
     tracker = _FailureTracker(policy or FailurePolicy(), metrics)
-    replay: dict[int, dict] = {} if ledger is None else ledger.completed()
+    replay: dict[tuple, dict] = (
+        {} if ledger is None else ledger.completed_evaluations()
+    )
     if cache is None and ledger is not None:
         from mpi_opt_tpu.ledger.cache import EvalCache
 
@@ -270,7 +272,7 @@ def run_search(
         served: dict[int, TrialResult] = {}
         pending: list[Trial] = []
         for t in batch:
-            rec = replay.pop(t.trial_id, None)
+            rec = replay.pop((t.trial_id, int(t.budget)), None)
             if rec is not None:
                 _verify_replay(algorithm.space, t, rec, ledger)
                 served[t.trial_id] = result_from_record(rec)
@@ -362,7 +364,9 @@ def run_search(
         # journal records the resumed algorithm never re-suggested: not
         # fatal (the search completed), but operators should know the
         # ledger holds trials this configuration no longer produces
-        metrics.log("ledger_replay_unconsumed", trials=sorted(replay))
+        metrics.log(
+            "ledger_replay_unconsumed", trials=sorted({tid for tid, _step in replay})
+        )
     wall = time.perf_counter() - t0
     return SearchResult(
         best=algorithm.best(),

@@ -1,6 +1,6 @@
 """Rank health: graceful shutdown, progress heartbeats, stall detection.
 
-SURVEY.md §5's failure taxonomy has two classes the trial- and
+SURVEY.md §5's failure classification has two classes the trial- and
 rank-death layers (PR 1/PR 2) structurally cannot reach:
 
 - PREEMPTION: the platform asks the process to die (SIGTERM) instead of

@@ -151,6 +151,22 @@ def _is_collective_phase(phase) -> bool:
     )
 
 
+def _rank_platform(rest: list[str]):
+    """The platform the ranks are told to use: their ``--platform``
+    argument, else the first entry of an inherited JAX_PLATFORMS, else
+    None (jax picks at bring-up; this supervisor stays off jax and
+    cannot ask)."""
+    platform = None
+    for i, tok in enumerate(rest):
+        if tok == "--platform" and i + 1 < len(rest):
+            platform = rest[i + 1]
+        elif tok.startswith("--platform="):
+            platform = tok.split("=", 1)[1]
+    if platform is None:
+        platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0] or None
+    return platform
+
+
 def _spawn_ranks(
     n: int, rest: list[str], log_dir: str, heartbeat: bool = False, coord=None
 ):
@@ -162,11 +178,10 @@ def _spawn_ranks(
     plane — the epoch is the supervisor's relaunch counter, so every
     attempt votes in a namespace no dead attempt ever touched."""
     port = _free_port()
-    # rank env is INHERITED (Popen env=None): MPI_OPT_TPU_CACHE_DIR
-    # reaches every restart/resume attempt of every rank, where
-    # cli.wire_compile_cache reads it before backend init — a
-    # preemption-resume cycle pays a disk read, not the 140–210 s
-    # recompile warmup
+    # rank env is INHERITED (Popen env=None): a JAX_COMPILATION_CACHE_DIR
+    # set on the supervisor reaches every restart/resume attempt of
+    # every rank (utils/compile_cache.py), so a preemption-resume cycle
+    # loads its programs from disk instead of compiling them again
     procs = []
     # incremental build + cleanup-on-failure: if Popen dies mid-loop
     # (fork EAGAIN, interpreter gone), the already-spawned ranks would
@@ -424,6 +439,18 @@ def main(argv=None) -> int:
                 f"{banned} is owned by the supervisor; don't pass it in "
                 "the per-rank arguments"
             )
+    if args.n_proc > 1 and _rank_platform(rest) == "tpu":
+        # a chip belongs to one process at a time, and ranks inherit
+        # ONE environment: every rank would open every chip of this
+        # host, and all but the first would fail or hang in bring-up.
+        # Giving each rank its own chip takes per-rank visibility
+        # settings this supervisor does not make yet (ROADMAP R0)
+        parser.error(
+            f"--n-proc {args.n_proc} on the tpu platform: the ranks of one "
+            "host would all open the same chips and hang. Drive a host's "
+            "chips from ONE process (the CLI meshes over them by itself), "
+            "or pass --platform cpu to rehearse multi-process SPMD"
+        )
     log_dir = args.log_dir or tempfile.mkdtemp(prefix="mpi_opt_tpu_launch_")
     os.makedirs(log_dir, exist_ok=True)
     coord_root = None

@@ -275,12 +275,18 @@ def validate_ledger(path: str) -> list[str]:
         return [f"unreadable: {e}"]
     if header is None:
         problems.append("empty ledger (no header record)")
+    # one final record per EVALUATION: a multi-fidelity search (ASHA,
+    # Hyperband) evaluates a trial once per rung it reaches, each at
+    # its own cumulative step, so (trial, step) names a record and the
+    # trial id alone does not
     seen: set = set()
     for rec in records:
-        tid = rec["trial_id"]
-        if tid in seen:
-            problems.append(f"trial {tid}: duplicated final record")
-        seen.add(tid)
+        key = (rec["trial_id"], rec["step"])
+        if key in seen:
+            problems.append(
+                f"trial {key[0]}: duplicated final record (step {key[1]})"
+            )
+        seen.add(key)
     if any("boundary" in r for r in records):
         # fused member journal: the boundary-granular invariants are
         # part of the schema — a torn FINAL boundary is flagged here
@@ -664,8 +670,15 @@ class SweepLedger:
     # -- replay view -------------------------------------------------------
 
     def completed(self) -> dict[int, dict]:
-        """trial_id -> FINAL record (ok or failed) for replay-resume."""
+        """trial_id -> its NEWEST final record (ok or failed)."""
         return {int(r["trial_id"]): r for r in self.records}
+
+    def completed_evaluations(self) -> dict[tuple, dict]:
+        """(trial_id, step) -> FINAL record of that evaluation, for
+        replay-resume. Keyed by the trial id alone, a resumed
+        multi-fidelity search would be served a trial's LAST rung as
+        its first."""
+        return {(int(r["trial_id"]), int(r["step"])): r for r in self.records}
 
     def ok_records(self) -> Sequence[dict]:
         return [r for r in self.records if r["status"] == "ok"]

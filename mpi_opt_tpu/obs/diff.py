@@ -1,8 +1,9 @@
 """Trace diffing: make two phase attributions COMPARABLE, with a gate.
 
 PR 8 made phase time *emittable*; this module makes it *decidable*.
-The bench plateau (8.35 -> 8.81 trials/s/chip across BENCH_r01-r05)
-was only discoverable by a human re-reading JSON files, and the
+A plateau across bench rounds (the previous installation's five driver
+rounds moved 8.35 -> 8.81 trials/s/chip) was only discoverable by a
+human re-reading JSON files, and the
 raw-speed arc ahead (Pallas kernel, bf16, fused-engine refactor) needs
 every round judged by a machine, not an eyeball:
 
@@ -15,7 +16,7 @@ every round judged by a machine, not an eyeball:
   ``--state-dir`` — every rank/tenant merges, same as ``trace DIR``);
 - a ``trace --json`` **attribution file**;
 - a **bench record** (``bench.py`` stdout line saved to a file, a
-  ``BENCH_r0*.json`` driver wrapper with the record under ``parsed``,
+  driver wrapper file with the record under ``parsed``,
   or a ``BENCH_ALL.json`` list) carrying an embedded ``trace``
   attribution — the BENCH trajectory becomes diffable directly.
 
@@ -90,8 +91,8 @@ DIFF_SCHEMA_VERSION = 1
 #: embedded ``trace`` attribution (may be null under --no-trace) and the
 #: ``device_memory`` watermark (obs/memory.py). Records WITHOUT a
 #: schema_version are the pre-round-7 legacy shape (metric/value/unit
-#: only) and stay loadable — the BENCH_r01-r05 trajectory must not
-#: become unreadable history.
+#: only) and stay loadable — records written before the field existed
+#: must not become unreadable history.
 BENCH_SCHEMA_VERSION = 2
 
 _TOL_KEYS = frozenset(
@@ -125,7 +126,7 @@ SINGLE_SAMPLE_REL = 0.5
 def _embedded_attribution(doc):
     """The attribution dict inside a parsed JSON document, or None.
     Accepts: an attribution itself (has ``phases``), a bench record
-    (``trace`` key), a BENCH_r0*.json driver wrapper (``parsed``), or a
+    (``trace`` key), a driver wrapper (``parsed``), or a
     BENCH_ALL.json list (exactly one record may carry a trace — with
     several, the caller must extract one; ambiguity is an error, not a
     guess)."""
@@ -753,8 +754,8 @@ def diff_main(targets, json_out: bool, gate_path, error, peak_tflops=None) -> in
 
 def validate_bench_record(rec) -> list:
     """Problems with one bench record (empty = valid). Legacy records
-    (no ``schema_version``) need only metric/value/unit — the
-    BENCH_r01-r05 history stays valid; version-2 records must also
+    (no ``schema_version``) need only metric/value/unit — pre-schema
+    history stays valid; version-2 records must also
     carry the ``trace`` and ``device_memory`` keys (null allowed: a
     --no-trace bench, a jax-less validator host) so the trajectory
     comparison can rely on their PRESENCE."""
@@ -793,8 +794,8 @@ def validate_bench_record(rec) -> list:
                     if stat not in p:
                         problems.append(f"trace phase {name!r} missing {stat!r}")
                         break
-            # the round-8 intra-phase sections are OPTIONAL (committed
-            # BENCH_r01-r05 history and --no-trace records must keep
+            # the round-8 intra-phase sections are OPTIONAL (pre-schema
+            # history and --no-trace records must keep
             # validating forever), but when present they must be objects
             for opt in ("bubbles", "staging", "roofline"):
                 if tr.get(opt) is not None and not isinstance(tr[opt], dict):

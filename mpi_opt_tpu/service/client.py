@@ -160,7 +160,8 @@ def serve_main(argv) -> int:
     # copy once dropped its --local-devices >= 1 guard and turned a
     # usage error into a deferred backend crash); the persistent
     # compile cache multiplies across every tenant of the server
-    from mpi_opt_tpu.cli import pin_platform, wire_compile_cache
+    from mpi_opt_tpu.cli import pin_platform
+    from mpi_opt_tpu.utils.compile_cache import wire_compile_cache
 
     wire_compile_cache()
     pin_platform(args.platform, args.local_devices, p.error)

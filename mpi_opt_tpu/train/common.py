@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import weakref
+
 import jax
 import jax.numpy as jnp
 
@@ -278,19 +280,28 @@ class HParamsFn:
     """Hashable (space, workload)-bound unit->OptHParams mapping, usable
     as a static jit argument (identity-hashed: space/workload come from
     per-workload caches, so identity is stable across calls and a fresh
-    pair correctly forces a retrace)."""
+    pair correctly forces a retrace).
+
+    The workload is held WEAKLY. A static argument lives in the jit
+    cache of the trainer's program, where the cycle collector cannot
+    see it; a strong reference from here would close the loop workload
+    -> trainer -> program -> this -> workload through that blind spot,
+    and no workload, trainer or executable would ever be freed. Every
+    caller holds the workload for as long as the program can trace."""
 
     def __init__(self, space, workload):
         self.space = space
-        self.workload = workload
+        self._workload = weakref.ref(workload)
+        self._hash = hash((id(space), id(workload)))
 
     def __call__(self, unit):
-        return self.workload.make_hparams(self.space.from_unit(unit))
+        return self._workload().make_hparams(self.space.from_unit(unit))
 
     def __hash__(self):
-        return hash((id(self.space), id(self.workload)))
+        return self._hash
 
     def __eq__(self, other):
-        return isinstance(other, HParamsFn) and (
-            self.space is other.space and self.workload is other.workload
-        )
+        if not isinstance(other, HParamsFn) or self.space is not other.space:
+            return False
+        workload = self._workload()
+        return workload is not None and workload is other._workload()

@@ -46,14 +46,13 @@ backend for any wave size (tested).
 from __future__ import annotations
 
 import contextlib
-import functools
 
 import jax
 import jax.numpy as jnp
 
 from mpi_opt_tpu.obs import memory, trace
 from mpi_opt_tpu.train.common import launch_boundary, oom_funnel
-from mpi_opt_tpu.train.population import PopState
+from mpi_opt_tpu.train.population import PopState, trainer_jit
 from mpi_opt_tpu.utils import profiling, resources
 
 
@@ -208,10 +207,8 @@ def resolve_wave_size(trainer, sample_x, population: int, *, wave_size, mesh=Non
     return wave_size
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("trainer", "hparams_fn", "steps", "n_total"),
-    donate_argnames=("state",),
+@trainer_jit(
+    static_argnames=("hparams_fn", "steps", "n_total"), donate_argnames=("state",)
 )
 def _wave_train_program(
     trainer, state, unit_slice, hparams_fn, train_x, train_y, key, steps, n_total, offset
@@ -231,11 +228,7 @@ def _wave_train_program(
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("trainer", "steps", "n_total"),
-    donate_argnames=("state",),
-)
+@trainer_jit(static_argnames=("steps", "n_total"), donate_argnames=("state",))
 def _wave_train_hp_program(
     trainer, state, hp_slice, train_x, train_y, key, steps, n_total, offset
 ):

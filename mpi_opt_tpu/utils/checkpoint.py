@@ -20,8 +20,12 @@ is never blocked on serialization of a multi-GB pool; ``close()`` (or
 the context manager) drains pending writes.
 
 Integrity (utils/integrity.py): every save writes a ``manifest`` item
-with per-item content digests; restore verifies digests BEFORE any
-state is applied, quarantines a failing step (rename to
+with per-item content digests, and once the async write has committed
+the step is SEALED with a file-level manifest (at the next save or at
+close — wherever the loop would wait for the writer anyway). Restore
+re-hashes a sealed step's files before orbax decodes anything, then
+verifies the item digests BEFORE any state is applied, quarantines a
+failing step (rename to
 ``<step>.corrupt``) and walks back to the newest older retained step —
 ``keep`` is therefore the fallback budget (default 3: the latest may be
 torn by a SIGKILL mid-async-save, leaving two verified fallbacks). Only
@@ -60,8 +64,35 @@ def _prune_superseded(mgr, directory: str) -> Optional[int]:
     return victim
 
 
-def _wait_classified(mgr, directory: str) -> None:
-    """Drain pending async saves with the storage classification: orbax
+def _seal_committed(mgr, directory: str, unsealed: list) -> None:
+    """Wait for the async writer, then seal every step in ``unsealed``
+    that committed (``integrity.seal_step``); ``unsealed`` is emptied.
+    A step whose write failed, or that retention already deleted, has
+    no committed directory and is skipped. One process writes the seal:
+    process 0, which is also the one that commits the step."""
+    import jax
+
+    mgr.wait_until_finished()
+    while unsealed:
+        step_dir = os.path.join(directory, str(unsealed.pop(0)))
+        if jax.process_index() == 0 and os.path.exists(
+            os.path.join(step_dir, integrity.COMMIT_MARKER)
+        ):
+            integrity.seal_step(step_dir)
+
+
+def _check_seal(directory: str, step: int) -> None:
+    """Raise SnapshotCorruptError unless every file of a sealed step
+    still matches its seal (an unsealed step passes: see
+    ``integrity.check_seal``)."""
+    problems = integrity.check_seal(os.path.join(directory, str(step)))
+    if problems:
+        raise integrity.SnapshotCorruptError("; ".join(problems))
+
+
+def _wait_classified(mgr, directory: str, unsealed: list) -> None:
+    """Drain pending async saves (and seal what they committed) with the
+    storage classification: orbax
     saves are asynchronous, so a REAL disk-full often surfaces not at
     the enqueue (_save_storage_guard's territory) but in the background
     writer — re-raised here at close()'s ``wait_until_finished``. An
@@ -71,7 +102,7 @@ def _wait_classified(mgr, directory: str) -> None:
     write never committed its step, so durable state is the last
     committed step and the free-disk + --resume recovery holds."""
     try:
-        mgr.wait_until_finished()
+        _seal_committed(mgr, directory, unsealed)
     except Exception as e:
         if not resources.is_storage_full(e):
             raise
@@ -83,8 +114,10 @@ def _wait_classified(mgr, directory: str) -> None:
         ) from e
 
 
-def _save_storage_guard(mgr, directory: str, enqueue) -> None:
-    """Run ``enqueue()`` (the orbax save) with the storage-exhaustion
+def _save_storage_guard(mgr, directory: str, unsealed: list, enqueue) -> None:
+    """Seal what the previous save committed (orbax waits for that
+    write before it starts a new one anyway), then run ``enqueue()``
+    (the orbax save) — both under the storage-exhaustion
     lifecycle (ISSUE 13): a classified ENOSPC/EDQUOT gets ONE
     retention-prune retry — delete the oldest superseded retained step,
     never the newest — then parks by raising typed ``StorageFull`` (the
@@ -96,6 +129,7 @@ def _save_storage_guard(mgr, directory: str, enqueue) -> None:
 
     def attempt():
         resources.disk_fault("snapshot_save", directory)
+        _seal_committed(mgr, directory, unsealed)
         enqueue()
 
     try:
@@ -223,6 +257,7 @@ class SearchCheckpointer:
             self.directory,
             options=ocp.CheckpointManagerOptions(max_to_keep=keep, create=True),
         )
+        self._unsealed: list = []  # steps saved here, not yet sealed
 
     # -- save --------------------------------------------------------------
 
@@ -257,8 +292,10 @@ class SearchCheckpointer:
             _save_storage_guard(
                 self._mgr,
                 self.directory,
+                self._unsealed,
                 lambda: self._mgr.save(step, args=ocp.args.Composite(**items)),
             )
+            self._unsealed.append(step)
 
     # -- restore -----------------------------------------------------------
 
@@ -278,6 +315,7 @@ class SearchCheckpointer:
         """
 
         def attempt(step):
+            _check_seal(self.directory, step)
             items: dict[str, Any] = {"search": ocp.args.JsonRestore()}
             names = self._item_names(step)
             has_pool = "pool" in names
@@ -332,7 +370,7 @@ class SearchCheckpointer:
         # surfaces on the host (the drain before the manager closes) —
         # and where a background writer's ENOSPC re-raises, classified
         with trace.span("save_wait"):
-            _wait_classified(self._mgr, self.directory)
+            _wait_classified(self._mgr, self.directory, self._unsealed)
         self._mgr.close()
 
     def __enter__(self):
@@ -364,6 +402,7 @@ class SweepCheckpointer:
             self.directory,
             options=ocp.CheckpointManagerOptions(max_to_keep=keep, create=True),
         )
+        self._unsealed: list = []  # steps saved here, not yet sealed
 
     def save(self, step: int, sweep: dict, meta_extra: dict) -> None:
         with trace.span("save", step=step) as sp:
@@ -376,6 +415,7 @@ class SweepCheckpointer:
             _save_storage_guard(
                 self._mgr,
                 self.directory,
+                self._unsealed,
                 lambda: self._mgr.save(
                     step,
                     args=ocp.args.Composite(
@@ -385,6 +425,7 @@ class SweepCheckpointer:
                     ),
                 ),
             )
+            self._unsealed.append(step)
 
     def restore(self):
         """(sweep_arrays, meta) from the newest VERIFIED snapshot, or
@@ -395,6 +436,7 @@ class SweepCheckpointer:
         ValueError on a config mismatch."""
 
         def attempt(step):
+            _check_seal(self.directory, step)
             items = {
                 "sweep": ocp.args.StandardRestore(),
                 "meta": ocp.args.JsonRestore(),
@@ -468,9 +510,8 @@ class SweepCheckpointer:
 
     def close(self) -> None:
         with trace.span("save_wait"):
-            _wait_classified(self._mgr, self.directory)
+            _wait_classified(self._mgr, self.directory, self._unsealed)
         self._mgr.close()
-
 
     # -- population-sweep payload (shared by fused PBT / SHA) -------------
 

@@ -18,16 +18,17 @@ from __future__ import annotations
 from typing import Optional
 
 # (substring of jax Device.device_kind, dense bf16 peak FLOP/s per chip)
-# Published per-chip numbers: v4 275 TF, v5e 394 TF, v5p 459 TF,
-# v6e/Trillium 918 TF. Matching is substring-based because device_kind
-# strings vary across libtpu versions ("TPU v5 lite", "TPU v5e", ...).
+# Published per-chip numbers (Google Cloud TPU documentation, each
+# generation's system-architecture page): v4 275 TF, v5e 197 TF (its
+# 394 is the int8 figure), v5p 459 TF, v6e/Trillium 918 TF. Matching is
+# substring-based because device_kind strings vary across libtpu
+# versions ("TPU v5 lite", "TPU v5e", ...).
 _PEAKS = (
     ("v6e", 918e12),
     ("trillium", 918e12),
-    ("v5 lite", 394e12),
-    ("v5e", 394e12),
+    ("v5 lite", 197e12),
+    ("v5e", 197e12),
     ("v5p", 459e12),
-    ("v5", 459e12),  # bare "TPU v5" reports as v5p-class
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
@@ -38,6 +39,8 @@ def peak_flops_per_chip(device=None) -> Optional[float]:
     """Dense bf16 peak FLOP/s for ``device`` (default: first device).
 
     Returns None off-TPU (CPU has no meaningful single peak for MFU).
+    A TPU kind that is not in the table is an error, never a guess: a
+    utilization against the wrong peak reads as a measurement.
     """
     import jax
 
@@ -49,7 +52,10 @@ def peak_flops_per_chip(device=None) -> Optional[float]:
     for tag, peak in _PEAKS:
         if tag in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no published bf16 peak for TPU kind {device.device_kind!r} in "
+        "utils/flops.py _PEAKS; add it with its source"
+    )
 
 
 def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
@@ -68,13 +74,7 @@ def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
     ``population_sweep_flops`` below.
     """
     try:
-        if isinstance(jitted_fn, __import__("functools").partial):
-            args = (*jitted_fn.args, *args)
-            kwargs = {**jitted_fn.keywords, **kwargs}
-            jitted_fn = jitted_fn.func
         cost = jitted_fn.lower(*args, **kwargs).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-            cost = cost[0]
         return float(cost["flops"])
     except Exception:
         return None
@@ -115,12 +115,10 @@ def population_sweep_flops(
         key = jax.random.key(0)
         state = trainer.init_population(key, tx[:2], 1)
         hp = OptHParams.defaults(1)
-        jf = trainer.train_segment  # functools.partial(jit(...), self)
-        f_step = compiled_flops(jf, state, hp, tx, ty, key, steps=1)
-        # the unbound jitted function: 'self' is a static argname, and a
-        # bound PjitFunction does not expose .lower
+        f_step = compiled_flops(trainer.train_segment, state, hp, tx, ty, key, steps=1)
         f_eval = compiled_flops(
-            type(trainer).eval_population, trainer, state, vx, vy, eval_chunk=eval_chunk
+            type(trainer).eval_population.program(trainer),
+            state, vx, vy, eval_chunk=eval_chunk,
         )
         if f_step is None or f_eval is None:
             raise RuntimeError(

@@ -15,6 +15,16 @@ This module is the bounding layer:
   extra JSON item inside the same orbax step. ``verify_restored``
   recomputes digests from the restored values before any state is
   applied.
+- **Sealed steps**: the item digests prove what a restore RETURNS; they
+  say nothing of the files a restore never decodes, and the installed
+  orbax logs and tolerates a mangled ``_CHECKPOINT_METADATA`` (a
+  truncated step restored as if whole). So once a step has committed,
+  ``seal_step`` writes a file-level manifest (``_SEAL``: size + SHA-256
+  of every file of the step, itself self-digested) and ``check_seal``
+  re-hashes the files BEFORE orbax is asked to decode anything. "Is
+  this step whole" is decided by this repo's own digests, not by
+  whether orbax raises. A step killed between its commit and its seal
+  carries no ``_SEAL`` and falls back to the item digests alone.
 - **Quarantine**: a step that fails restore or digest verification is
   renamed ``<step>.corrupt`` (never deleted — it is evidence), an
   observer event ``snapshot_corrupt`` fires (the CLI wires it into the
@@ -257,6 +267,113 @@ def verify_restored(manifest: dict, json_items: dict, tree_items: dict) -> list:
     return problems
 
 
+# -- sealed steps -----------------------------------------------------------
+
+SEAL_FILE = "_SEAL"
+SEAL_VERSION = 1
+#: orbax's step metadata file: present once a step has committed
+COMMIT_MARKER = "_CHECKPOINT_METADATA"
+
+
+def _file_digest(path: str) -> tuple:
+    """(size, SHA-256 hex) of one file, read in 1 MiB blocks."""
+    h = hashlib.sha256()
+    size = 0
+    with open(path, "rb") as f:
+        while True:
+            block = f.read(1 << 20)
+            if not block:
+                break
+            size += len(block)
+            h.update(block)
+    return size, h.hexdigest()
+
+
+def _step_files(step_dir: str) -> list:
+    """Every regular file of a step except the seal, as sorted
+    ``/``-separated paths relative to the step dir."""
+    out = []
+    for root, _dirs, files in os.walk(step_dir):
+        for f in files:
+            rel = os.path.relpath(os.path.join(root, f), step_dir)
+            if rel != SEAL_FILE:
+                out.append(rel.replace(os.sep, "/"))
+    return sorted(out)
+
+
+def _hash_files(step_dir: str, rels: list) -> dict:
+    """``{rel: {"size", "sha256"}}``; hashed on a thread pool (hashlib
+    releases the GIL), since a ResNet pool's step is gigabytes."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    paths = [os.path.join(step_dir, *rel.split("/")) for rel in rels]
+    workers = max(1, min(8, os.cpu_count() or 1, len(paths)))
+    with ThreadPoolExecutor(max_workers=workers) as ex:
+        digests = list(ex.map(_file_digest, paths))
+    return {rel: {"size": n, "sha256": d} for rel, (n, d) in zip(rels, digests)}
+
+
+def seal_step(step_dir: str) -> None:
+    """Write ``<step_dir>/_SEAL`` for a COMMITTED step: one JSON line
+    listing every file with its size and SHA-256, then a second line
+    holding the SHA-256 of the first — so no byte of the seal can change
+    unnoticed either. Written tmp + fsync + rename: a kill mid-seal
+    leaves no seal, never half of one."""
+    from mpi_opt_tpu.obs import trace
+
+    rels = _step_files(step_dir)
+    with trace.span("digest", op="seal", items=len(rels)):
+        body = json.dumps(
+            {"version": SEAL_VERSION, "files": _hash_files(step_dir, rels)},
+            sort_keys=True,
+            separators=(",", ":"),
+        ).encode()
+        path = os.path.join(step_dir, SEAL_FILE)
+        tmp = f"{path}.tmp"
+        with open(tmp, "wb") as f:
+            f.write(body + b"\n" + hashlib.sha256(body).hexdigest().encode() + b"\n")
+            f.flush()
+            os.fsync(f.fileno())
+        os.replace(tmp, path)
+
+
+def check_seal(step_dir: str) -> Optional[list]:
+    """None when the step carries no seal (killed between commit and
+    seal, or written before seals existed); otherwise the problems
+    found re-hashing every file against it (empty = the step is whole)."""
+    from mpi_opt_tpu.obs import trace
+
+    path = os.path.join(step_dir, SEAL_FILE)
+    try:
+        with open(path, "rb") as f:
+            raw = f.read()
+    except FileNotFoundError:
+        return None
+    body, _, tail = raw.partition(b"\n")
+    if tail != hashlib.sha256(body).hexdigest().encode() + b"\n":
+        return [f"{SEAL_FILE}: self-digest mismatch (torn or altered seal)"]
+    try:
+        doc = json.loads(body)
+        recorded = doc["files"]
+        if doc["version"] != SEAL_VERSION or not isinstance(recorded, dict):
+            raise ValueError(f"unknown seal version {doc['version']!r}")
+    except (ValueError, KeyError, TypeError) as e:
+        return [f"{SEAL_FILE}: unreadable ({type(e).__name__}: {e})"]
+    present = _step_files(step_dir)
+    problems = [f"file {rel!r}: sealed but missing" for rel in recorded if rel not in present]
+    problems += [f"file {rel!r}: present but not sealed" for rel in present if rel not in recorded]
+    both = [rel for rel in present if rel in recorded]
+    with trace.span("digest", op="check_seal", items=len(both)):
+        got = _hash_files(step_dir, both)
+    for rel in both:
+        if got[rel] != recorded[rel]:
+            problems.append(
+                f"file {rel!r}: content changed since the step was sealed "
+                f"(size {recorded[rel].get('size')} -> {got[rel]['size']})"
+            )
+    return problems
+
+
 # -- quarantine -------------------------------------------------------------
 
 
@@ -329,9 +446,7 @@ def _committed_steps(root: str) -> list:
     marker, sorted ascending."""
     out = []
     for d in os.listdir(root):
-        if d.isdigit() and os.path.exists(
-            os.path.join(root, d, "_CHECKPOINT_METADATA")
-        ):
+        if d.isdigit() and os.path.exists(os.path.join(root, d, COMMIT_MARKER)):
             out.append(int(d))
     return sorted(out)
 
@@ -342,9 +457,7 @@ def _torn_steps(root: str) -> list:
     surfaces them so --repair can quarantine the debris."""
     out = []
     for d in os.listdir(root):
-        if d.isdigit() and not os.path.exists(
-            os.path.join(root, d, "_CHECKPOINT_METADATA")
-        ):
+        if d.isdigit() and not os.path.exists(os.path.join(root, d, COMMIT_MARKER)):
             out.append(int(d))
     return sorted(out)
 
@@ -375,6 +488,9 @@ def verify_step(root: str, step: int, mgr=None) -> tuple:
         d for d in os.listdir(step_dir)
         if os.path.isdir(os.path.join(step_dir, d))
     )
+    seal_problems = check_seal(step_dir)
+    if seal_problems:
+        return "corrupt", seal_problems
     own_mgr = mgr is None
     if own_mgr:
         mgr = ocp.CheckpointManager(root)
@@ -431,9 +547,11 @@ def deep_verify_step(root: str, step: int) -> list:
     checksums on read, so rot inside b-tree nodes or data files
     surfaces here even when it hides from a normal restore: measured in
     this container, a bit-flip in a nested process store's data file
-    reads back clean through the top-level database (the manifest
-    digest layer verifies what a restore RETURNS, not every byte on
-    disk). Returns problems (empty = every stored byte decoded clean).
+    reads back clean through the top-level database (the item-digest
+    layer verifies what a restore RETURNS, not every byte on disk; the
+    seal covers every byte, but a step killed between commit and seal
+    has none). Returns problems (empty = every stored byte decoded
+    clean).
     """
     problems: list = []
     try:

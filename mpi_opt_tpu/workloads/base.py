@@ -176,8 +176,8 @@ class PopulationWorkload(Workload):
         """Single-trial from-scratch training; see class docstring.
 
         The trainer and device arrays are cached on the instance —
-        train_segment is jitted with ``self`` static, so a fresh trainer
-        per call would recompile every trial.
+        a trainer owns its compiled programs, so a fresh trainer per
+        call would recompile every trial.
         """
         trainer, state, val_x, val_y = self._eval_state(params, budget, seed)
         acc = trainer.eval_population(state, val_x, val_y)

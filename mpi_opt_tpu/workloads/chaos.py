@@ -120,15 +120,20 @@ def _committed_step_dirs(checkpoint_dir: str) -> list:
 
 
 def _corruption_target(step_dir: str) -> str:
-    """The file a fault strikes: the LARGEST regular file in the step
-    (ties broken by path) — in any real snapshot that is array data,
-    the payload whose rot matters most; in toy snapshots it may be the
-    manifest itself, which verification must also survive."""
+    """The file a fault strikes: the LARGEST file orbax wrote in the
+    step (ties broken by path) — in any real snapshot that is array
+    data, the payload whose rot matters most; in toy snapshots it is
+    orbax's own ``_CHECKPOINT_METADATA``, which the installed orbax
+    would read past — exactly the file only the step's seal protects.
+    The seal itself is never the target (tests tear it directly)."""
+    from mpi_opt_tpu.utils.integrity import SEAL_FILE
+
     candidates = []
     for root, _dirs, files in os.walk(step_dir):
         for f in files:
             p = os.path.join(root, f)
-            candidates.append((os.path.getsize(p), p))
+            if os.path.relpath(p, step_dir) != SEAL_FILE:
+                candidates.append((os.path.getsize(p), p))
     if not candidates:
         raise ValueError(f"no files to corrupt under {step_dir}")
     # largest first; the path tiebreak keeps the pick stable when sizes

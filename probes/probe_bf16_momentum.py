@@ -22,7 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 from mpi_opt_tpu.train.fused_pbt import fused_pbt  # noqa: E402
 from mpi_opt_tpu.workloads import get_workload  # noqa: E402

@@ -1,7 +1,6 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 # platform cap probe: ideal MXU shapes, work >> dispatch overhead
 M = K = N = 4096

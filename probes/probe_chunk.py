@@ -1,7 +1,6 @@
 import sys, time
 sys.path.insert(0, "/root/repo")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.train.fused_pbt import fused_pbt
 from mpi_opt_tpu.workloads import get_workload
 wl = get_workload("cifar10_cnn")

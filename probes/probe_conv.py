@@ -1,6 +1,5 @@
 import time, jax, jax.numpy as jnp, numpy as np
 from functools import partial
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 P, B, H, W, Cin, Cout = 32, 256, 32, 32, 32, 32
 N = 100
 k = jax.random.key(0)

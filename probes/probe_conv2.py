@@ -1,7 +1,6 @@
 """Compare population-conv strategies: P members, each its own 3x3 kernel."""
 import time, functools
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 P, B, H, W, C, O = 32, 256, 32, 32, 32, 32
 kx = jax.random.key(0)

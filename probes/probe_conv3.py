@@ -1,7 +1,6 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 conv1 = lambda xi, wi: jax.lax.conv_general_dilated(xi, wi, (1,1), "SAME", dimension_numbers=("NHWC","HWIO","NHWC"))
 

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 from mpi_opt_tpu.train.population import OptHParams, PopulationTrainer
 from mpi_opt_tpu.workloads import get_workload

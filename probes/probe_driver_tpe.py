@@ -2,7 +2,6 @@
 import sys, time
 sys.path.insert(0, "/root/repo")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.algorithms import get_algorithm
 from mpi_opt_tpu.backends import get_backend
 from mpi_opt_tpu.driver import run_search

@@ -13,7 +13,7 @@ state = tr.init_population(key, tx[:2], P)
 hp = OptHParams.defaults(P)
 # cost of a 1-step segment
 jf = tr.train_segment  # functools.partial(jit(...), self)
-c = jf.func.lower(jf.args[0], state, hp, tx, ty, key, steps=1).compile().cost_analysis()
+c = jf.lower(state, hp, tx, ty, key, steps=1).compile().cost_analysis()
 if isinstance(c, (list, tuple)): c = c[0]
 print("train_segment P=8 steps=1 flops:", c.get("flops"), "bytes accessed:", c.get("bytes accessed"))
 print("per member-step GFLOP:", c.get("flops")/P/1e9)

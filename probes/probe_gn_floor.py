@@ -11,7 +11,6 @@ sys.path.insert(0, "/root/repo")
 import flax.linen as nn
 import jax, jax.numpy as jnp
 import numpy as np
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 P, B, H, W, C = 32, 256, 32, 32, 32
 gn = nn.GroupNorm(num_groups=8, dtype=jnp.bfloat16)

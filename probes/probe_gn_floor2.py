@@ -4,7 +4,6 @@ GN's pass count = GN time / per-pass time."""
 import statistics, sys, time
 sys.path.insert(0, "/root/repo")
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 P, B, H, W, C = 32, 256, 32, 32, 32
 x = jax.random.normal(jax.random.key(0), (P, B, H, W, C), jnp.bfloat16)

@@ -1,5 +1,4 @@
 import jax, jax.numpy as jnp, re
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.train.population import OptHParams
 wl = get_workload("cifar10_cnn")
@@ -10,7 +9,7 @@ P = 32
 state = tr.init_population(jax.random.key(0), tx[:2], P)
 hp = OptHParams.defaults(P)
 jf = tr.train_segment
-txt = jf.func.lower(jf.args[0], state, hp, tx, ty, jax.random.key(1), steps=1).compile().as_text()
+txt = jf.lower(state, hp, tx, ty, jax.random.key(1), steps=1).compile().as_text()
 convs = [l.strip() for l in txt.splitlines() if "convolution(" in l or "%convolution" in l and "fusion" not in l]
 for l in convs[:20]:
     print(l[:240])

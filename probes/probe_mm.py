@@ -1,7 +1,6 @@
 import time, sys
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 def timed(name, fl_per_iter, step, init, n=20):
     print(f"compiling {name} ...", flush=True)

@@ -28,7 +28,6 @@ sys.path.insert(0, ".")
 def main(mode):
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
     import mpi_opt_tpu.models.cnn as cnn
 
     if mode == "new":  # the refuted variant

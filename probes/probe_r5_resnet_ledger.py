@@ -23,7 +23,6 @@ sys.path.insert(0, "/root/repo")
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 
 from mpi_opt_tpu.train.population import OptHParams
 from mpi_opt_tpu.workloads import get_workload

@@ -1,5 +1,4 @@
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.utils.flops import population_sweep_flops
 import mpi_opt_tpu.utils.flops as F

@@ -1,7 +1,6 @@
 import time
 import numpy as np
 import jax, jax.numpy as jnp
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.train.population import OptHParams
 wl = get_workload("cifar10_cnn")

@@ -1,6 +1,5 @@
 """Ablate the member train step: where do the 36ms/step go?"""
 import time, jax, jax.numpy as jnp, numpy as np, flax.linen as nn
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.models import SmallCNN
 from mpi_opt_tpu.train import PopulationTrainer, OptHParams
 from mpi_opt_tpu.data import load_dataset

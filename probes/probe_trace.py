@@ -1,5 +1,4 @@
 import time, jax, numpy as np
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.train.fused_pbt import fused_pbt
 from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.utils.profiling import profile_window

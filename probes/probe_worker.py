@@ -2,7 +2,6 @@ import sys, time, os
 sys.path.insert(0, "/root/repo")
 import jax
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_cpu")
 from mpi_opt_tpu.workloads import get_workload
 
 t0 = time.perf_counter()

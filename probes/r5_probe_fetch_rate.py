@@ -1,7 +1,6 @@
 import sys, time
 sys.path.insert(0, "/root/repo")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 import numpy as np
 from mpi_opt_tpu.workloads.vision import Cifar100ResNet18
 from mpi_opt_tpu.train.common import workload_arrays

@@ -1,7 +1,6 @@
 import sys, time
 sys.path.insert(0, "/root/repo")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 import jax.numpy as jnp
 import numpy as np
 

@@ -1,7 +1,6 @@
 import sys, time, shutil
 sys.path.insert(0, "/root/repo")
 import jax
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache_tpu")
 from mpi_opt_tpu.workloads.vision import Cifar100ResNet18
 from mpi_opt_tpu.train.fused_pbt import fused_pbt
 

@@ -1272,7 +1272,7 @@ def test_lint_json_schema_gate(tmp_path, capsys):
         "event-registry", "lease-write", "corpus-index-write",
         "resource-funnel", "fsync-before-rename", "guarded-by",
         "beat-path-nonblocking", "signal-safety", "lock-order",
-        "http-handler-contained",
+        "http-handler-contained", "coord-write",
         "project-table",  # synthetic: pass-1 symbol-table build time
     }
     assert all(

@@ -4,11 +4,14 @@ The headline is the determinism drill: a seeded random-search sweep
 with ~20-30% injected trial failures (exceptions + NaN scores) must
 complete, report the injected failures in the summary counters, and
 return the SAME best trial as the clean run — failures cost coverage,
-never correctness. The constants (algorithm seed 0, chaos seed 10,
+never correctness. The constants (algorithm seed 0, chaos seed 19,
 30 trials, capacity 2) were chosen so the injection hits 9 trials
 (5 exceptions + 4 NaNs) and the clean winner is not among them; chaos
 faults are a pure function of (chaos_seed, params), so these counts are
-stable across machines and runs.
+stable across machines and runs — for ONE jax.random stream. The
+sampled params are jax's to define: the partitionable threefry that
+became the default after jax 0.4 draws a different 30-trial stream
+(chaos seed 10 gave these same counts on the old one, and 3 + 2 here).
 """
 
 import math
@@ -26,7 +29,7 @@ from mpi_opt_tpu.workloads.chaos import ChaosInjectedError, parse_chaos_spec
 pytestmark = pytest.mark.chaos
 
 # the determinism drill's injection mix: ~20% of trials faulted
-CHAOS = {"inner": "quadratic", "exc": 0.12, "nan": 0.08, "seed": 10}
+CHAOS = {"inner": "quadratic", "exc": 0.12, "nan": 0.08, "seed": 19}
 N_INJECTED = 9  # 5 exc + 4 nan over the 30-trial seed-0 stream
 
 
@@ -180,12 +183,13 @@ def test_timeout_spares_innocent_trials_in_the_batch():
     """One hung trial must not eat the whole batch's deadline budget:
     trials queued behind it still get their own window and report real
     scores."""
-    # chaos seed 26 puts the ONE hang at batch position 0 (scanned):
+    # chaos seed 16 puts the ONE hang at batch position 0 (scanned, on
+    # this jax's sample stream — see the module docstring):
     # the worst position — every innocent trial queues behind it. With
     # 2+ hangs on 2 workers the whole pool wedges and reaping all of
     # them as timeouts is the correct outcome, which is why this test
     # pins a single-hang draw.
-    kw = {"inner": "digits", "hang": 0.3, "hang_s": 120.0, "seed": 26}
+    kw = {"inner": "digits", "hang": 0.3, "hang_s": 120.0, "seed": 16}
     wl = get_workload("chaos", **kw)
     algo = RandomSearch(wl.default_space(), seed=0, max_trials=6, budget=20)
     batch = algo.next_batch(6)

@@ -188,7 +188,7 @@ def test_search_checkpointer_keep_depth_is_fallback_budget(tmp_path, quad):
     assert kept == [4, 5, 6]
 
 
-@pytest.mark.slow
+@pytest.mark.slow  # 24 s of subprocess sweeps here (2026-09-26); passes
 def test_sigkill_during_async_save_resumes_on_prior_verified_step(tmp_path):
     """The ISSUE-5 acceptance drill for the driver path, end to end
     through real processes: SIGKILL a journaled+checkpointed sweep while

@@ -14,10 +14,9 @@ tests drive the protocol three ways:
   locally-seen request must WAIT for the agreed verdict) and the slice
   hook chaining;
 - DRILLS: real ``python -m mpi_opt_tpu`` rank subprocesses over a
-  shared ``--coord-dir``. jax 0.4.x CPU has no cross-process
-  collectives, so the 2-rank drills run ``--no-mesh`` (each rank
-  computes locally; the control plane is what is under test — it is
-  pure filesystem and identical under a real mesh). The heavyweight
+  shared ``--coord-dir``. The 2-rank drills run ``--no-mesh`` (each
+  rank computes locally; the control plane is what is under test — it
+  is pure filesystem and identical under a real mesh). The heavyweight
   kill -> wedge-classification -> coordinated-resume drill is
   slow-marked and run by probes/tier1.sh (SPMD_DRILL).
 """
@@ -394,8 +393,9 @@ def test_one_sided_sigterm_drains_both_ranks_at_same_boundary(tmp_path):
 
 
 @pytest.mark.slow  # 2 supervised 2-rank jobs + a --term-grace drain: the
-# full kill -> wedge -> coordinated-resume arc. probes/tier1.sh runs it
-# as SPMD_DRILL (T1_SKIP_SPMD_DRILL=1 to skip there).
+# full kill -> wedge -> coordinated-resume arc, 51 s here (2026-09-26;
+# it passes). probes/tier1.sh runs it as SPMD_DRILL
+# (T1_SKIP_SPMD_DRILL=1 to skip there).
 def test_rank_kill_escalates_to_coordinated_resume_record_identical(tmp_path):
     """A rank SIGKILLed mid-wave leaves its survivor frozen in the
     boundary barrier. The supervisor classifies the shape (dead rank +

@@ -44,3 +44,25 @@ def test_peak_and_mfu_off_tpu_is_none():
     else:
         assert peak_flops_per_chip(dev) is None
         assert mfu(1e12, 1.0, dev) is None
+
+
+class _FakeTpu:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize(
+    "kind,peak",
+    [("TPU v5 lite", 197e12), ("TPU v5e", 197e12), ("TPU v5p", 459e12), ("TPU v4", 275e12)],
+)
+def test_peak_table_is_the_published_bf16_figure(kind, peak):
+    # v5e: 197 TF/s in bf16 — 394 is its int8 rating
+    assert peak_flops_per_chip(_FakeTpu(kind)) == peak
+
+
+@pytest.mark.parametrize("kind", ["TPU v5", "TPU v9 hyper"])
+def test_unknown_tpu_kind_is_an_error_not_a_guess(kind):
+    with pytest.raises(ValueError, match="no published bf16 peak"):
+        peak_flops_per_chip(_FakeTpu(kind))

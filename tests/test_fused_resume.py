@@ -7,6 +7,8 @@ of an uninterrupted run — the RNG key is part of the snapshot, so the
 continued trajectory is exactly the one the crash interrupted.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -103,6 +105,9 @@ def test_pre_upgrade_snapshot_resume_reports_no_launch_walls(tmp_path, monkeypat
 
     for mdir in glob.glob(f"{ckpt}/*/manifest"):
         shutil.rmtree(mdir)
+    # ... and the file-level seal, which would flag both edits
+    for seal in glob.glob(f"{ckpt}/*/_SEAL"):
+        os.remove(seal)
 
     resumed = fp.fused_pbt(wl, checkpoint_dir=ckpt, **KW)
     np.testing.assert_array_equal(resumed["best_curve"], whole["best_curve"])

@@ -412,7 +412,12 @@ def test_fsck_deep_catches_ocdbt_internal_rot(tmp_path, capsys):
     assert fsck_main([ck, "--deep"]) == 0  # clean tree audits clean, deeply
     capsys.readouterr()
     _rot_nested_process_store(os.path.join(ck, "1"))
-    # the manifest layer verifies what a restore RETURNS — it passes
+    # a SEALED step: the file-level digests flag it without --deep
+    assert fsck_main([ck]) == 1
+    capsys.readouterr()
+    # a step killed between commit and seal has only the item digests,
+    # which verify what a restore RETURNS — that layer passes
+    os.remove(os.path.join(ck, "1", integrity.SEAL_FILE))
     assert fsck_main([ck]) == 0
     capsys.readouterr()
     # --deep reads every ocdbt key back: tensorstore's CRC-32C flags it
@@ -425,3 +430,83 @@ def test_fsck_deep_catches_ocdbt_internal_rot(tmp_path, capsys):
     assert fsck_main([ck, "--deep", "--repair"]) == 1
     capsys.readouterr()
     assert integrity.list_quarantined(ck)
+
+
+# -- sealed steps: every file of a step is covered -------------------------
+
+
+def test_any_one_truncated_file_quarantines_and_walks_back(tmp_path):
+    """The guarantee in full: whichever ONE file of the newest step is
+    truncated — orbax's own metadata (which the installed orbax reads
+    past), array data, a nested process store, the seal itself — the
+    step is quarantined and restore lands on the step before it."""
+
+    def files_of(d):  # data-file names differ from save to save: go by position
+        return integrity._step_files(os.path.join(d, "2")) + [integrity.SEAL_FILE]
+
+    d0 = str(tmp_path / "ck0")
+    _save_steps(d0, [1, 2])
+    n = len(files_of(d0))
+    assert n >= 8  # metadata, three items, nested stores, seal
+    integrity.set_observer(lambda *a, **k: None)
+    try:
+        for i in range(n):
+            d = str(tmp_path / f"ck{i + 1}")
+            _save_steps(d, [1, 2])
+            rels = files_of(d)
+            assert len(rels) == n
+            rel = rels[i]
+            path = os.path.join(d, "2", *rel.split("/"))
+            with open(path, "r+b") as f:
+                f.truncate(os.path.getsize(path) // 2)
+            ck = SweepCheckpointer(d, CFG)
+            _sweep, meta = ck.restore()
+            ck.close()
+            assert meta["gen"] == 1, rel
+            assert os.path.isdir(os.path.join(d, "2.corrupt")), rel
+    finally:
+        integrity.clear_observer()
+
+
+def test_seal_lands_at_the_next_save_and_at_close(tmp_path):
+    """A step is sealed where the loop waits for the async writer
+    anyway: at the next save, and at close."""
+    d = str(tmp_path / "ck")
+    ck = SweepCheckpointer(d, CFG)
+    sweep = {"state": {"p": np.zeros((4,), np.float32)}}
+    ck.save(1, sweep=sweep, meta_extra={"gen": 1})
+    ck.save(2, sweep=sweep, meta_extra={"gen": 2})
+    assert integrity.check_seal(os.path.join(d, "1")) == []
+    ck.close()
+    assert integrity.check_seal(os.path.join(d, "2")) == []
+
+
+def test_unsealed_step_falls_back_to_item_digests(tmp_path):
+    """Killed between commit and seal: the step has no _SEAL, restores
+    on its item digests alone, and a flipped payload bit still
+    quarantines it."""
+    d = str(tmp_path / "ck")
+    _save_steps(d, [1, 2])
+    for s in (1, 2):
+        os.remove(os.path.join(d, str(s), integrity.SEAL_FILE))
+    assert integrity.check_seal(os.path.join(d, "2")) is None
+    ck = SweepCheckpointer(d, CFG)
+    _sweep, meta = ck.restore()
+    ck.close()
+    assert meta["gen"] == 2
+    data = sorted(
+        os.path.join(r, f)
+        for r, _d, fs in os.walk(os.path.join(d, "2", "sweep", "d"))
+        for f in fs
+    )[0]
+    raw = bytearray(open(data, "rb").read())
+    raw[len(raw) // 2] ^= 0x01
+    open(data, "wb").write(bytes(raw))
+    integrity.set_observer(lambda *a, **k: None)
+    try:
+        ck = SweepCheckpointer(d, CFG)
+        _sweep, meta = ck.restore()
+        ck.close()
+    finally:
+        integrity.clear_observer()
+    assert meta["gen"] == 1

@@ -106,9 +106,9 @@ def _first_snapshot_exists(ck):
     return False
 
 
-@pytest.mark.slow  # 2-rank SPMD: needs a runtime with cross-process
-# collectives (jax 0.4.x CPU backend: "Multiprocess computations aren't
-# implemented"); the single-rank supervisor tests below stay in tier-1
+@pytest.mark.slow  # 2-rank SPMD over real cross-process CPU collectives:
+# it passes on the installed jax, in 84-102 s here (2026-09-26) — out of
+# the tier-1 time limit; the single-rank supervisor tests below stay in
 def test_supervisor_recovers_from_rank_kill_bit_identically(tmp_path):
     ck_clean = str(tmp_path / "clean")
     ck_kill = str(tmp_path / "kill")
@@ -415,7 +415,7 @@ def test_supervisor_preemption_restart_does_not_consume_retries(tmp_path):
     chaos ``preempt`` SIGTERMs the rank mid-sweep; the rank drains
     (flushed ledger, exit 75); the supervisor — with --retries 0 —
     still restarts it with --resume (preemptions are free), the resumed
-    rank replays the journal and completes. Chaos seed 7 puts the one
+    rank replays the journal and completes. Chaos seed 13 puts the one
     preempt draw at trial index 6 of the 12-trial seed-0 stream, so the
     resumed run replays exactly 7 trials."""
     led = str(tmp_path / "sweep.jsonl")
@@ -425,7 +425,7 @@ def test_supervisor_preemption_restart_does_not_consume_retries(tmp_path):
         ["--workload", "quadratic", "--algorithm", "random",
          "--trials", "12", "--budget", "10", "--workers", "1",
          "--seed", "0", "--ledger", led,
-         "--chaos", "preempt=0.15,seed=7",
+         "--chaos", "preempt=0.15,seed=13",
          "--platform", "cpu", "--no-mesh"],
         str(tmp_path / "logs"),
         timeout=300,

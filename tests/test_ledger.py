@@ -599,3 +599,37 @@ def test_replay_consistency_cross_check(tmp_path):
     assert len(problems) == 1 and "7" in problems[0]
     # unreadable journal degrades to a problem string, not a crash
     assert replay_consistency(str(tmp_path / "nope.jsonl"), search)
+
+
+def test_multi_fidelity_journal_validates_and_replays_every_rung(tmp_path):
+    """ASHA evaluates a promoted trial once per rung: the journal holds
+    one final record per (trial, step), `report --validate` accepts it,
+    and a resume is served EACH rung's own record — never a trial's top
+    rung as its first (found by chip_smoke's driver tier, PR 21)."""
+    from mpi_opt_tpu.algorithms import ASHA
+    from mpi_opt_tpu.ledger.store import validate_ledger
+
+    wl = get_workload("quadratic")
+
+    def asha():
+        return ASHA(
+            wl.default_space(), seed=0, max_trials=8, min_budget=10, max_budget=270, eta=3
+        )
+
+    led = _ledger(tmp_path)
+    algo1, res1, _, b1 = _search(wl, ledger=led, algo=asha(), backend=SpyBackend(wl, n_workers=1))
+    led.close()
+    assert validate_ledger(led.path) == []
+    records = SweepLedger(led.path).records
+    evaluations = {(r["trial_id"], r["step"]) for r in records}
+    assert len(evaluations) == len(records) > 8  # promotions journaled per rung
+    assert len({r["trial_id"] for r in records}) == 8
+
+    led2 = SweepLedger(led.path)
+    algo2, res2, _, b2 = _search(wl, ledger=led2, algo=asha(), backend=SpyBackend(wl, n_workers=1))
+    led2.close()
+    assert b2.evaluated_ids == []  # every rung replayed, none re-evaluated
+    assert res2.n_replayed == len(records) and res2.n_evals == 0
+    assert res2.best.params == res1.best.params and res2.best.score == res1.best.score
+    assert len(SweepLedger(led.path).records) == len(records)  # nothing re-journaled
+    assert validate_ledger(led.path) == []

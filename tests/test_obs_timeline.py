@@ -272,9 +272,14 @@ def test_staging_engine_emits_cumulative_attrs(tmp_path):
 # -- the roofline verdict -------------------------------------------------
 
 
-def test_resolve_peak_cli_beats_calibration():
+def test_resolve_peak_cli_beats_calibration(monkeypatch):
     spans = [_rec("setup", 100.0, 0.1, device="TPU v5 lite")]
     assert bubbles.resolve_peak(spans, 200.0) == (200.0, "cli")
+    # the shipped table is empty until a cap is measured on this
+    # installation: an uncalibrated device resolves to nothing ...
+    assert bubbles.resolve_peak(spans) == (None, None)
+    # ... and a calibrated one to its line
+    monkeypatch.setitem(bubbles.CALIBRATED_PEAK_TFLOPS, "TPU v5 lite", 157.0)
     peak, src = bubbles.resolve_peak(spans)
     assert peak == 157.0 and src == "calibration:TPU v5 lite"
     assert bubbles.resolve_peak([_rec("setup", 0.1, 0.1, device="martian")]) == (

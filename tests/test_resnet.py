@@ -8,9 +8,9 @@ import pytest
 from mpi_opt_tpu.models import ResNet18
 from mpi_opt_tpu.workloads import get_workload
 
-# ResNet XLA:CPU compiles cost minutes of wall in one process — out
-# of the tier-1 870s single-process window; run explicitly or with
-# ``-m slow``
+# Full ResNet-18 programs compiled AND run on XLA:CPU: 12 + 26 + 38 +
+# 51 s here (measured 2026-09-26, 8 cores) — out of the tier-1 time
+# limit; run explicitly or with ``-m slow``
 pytestmark = pytest.mark.slow
 
 

@@ -992,35 +992,37 @@ def test_readonly_clients_refuse_a_nonexistent_spool(tmp_path):
 
 
 def test_compile_cache_env_wiring(tmp_path, monkeypatch):
-    """MPI_OPT_TPU_CACHE_DIR -> jax_compilation_cache_dir, wired the way
-    backends/cpu.py already does for pool workers, but for the main
-    process's default/TPU path (cli.wire_compile_cache, called before
-    backend init and inherited by launch.py rank processes)."""
+    """utils/compile_cache.py is the one place the cache is placed:
+    where JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and no
+    directory is set in code; where it is not, the fixed directory
+    inside the checkout."""
     import jax
 
-    from mpi_opt_tpu.cli import wire_compile_cache
+    from mpi_opt_tpu.utils.compile_cache import DEFAULT_DIR, wire_compile_cache
 
     prev = jax.config.jax_compilation_cache_dir
     try:
-        monkeypatch.delenv("MPI_OPT_TPU_CACHE_DIR", raising=False)
-        assert wire_compile_cache() is False  # unset: never touches config
-        assert jax.config.jax_compilation_cache_dir == prev
         cache = str(tmp_path / "xla-cache")
-        monkeypatch.setenv("MPI_OPT_TPU_CACHE_DIR", cache)
-        assert wire_compile_cache() is True
-        assert jax.config.jax_compilation_cache_dir == cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+        assert wire_compile_cache() == cache  # placed from outside ...
+        assert jax.config.jax_compilation_cache_dir == prev  # ... config untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert wire_compile_cache() == DEFAULT_DIR
+        assert jax.config.jax_compilation_cache_dir == DEFAULT_DIR
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert DEFAULT_DIR == os.path.join(repo, ".jax_cache")  # fixed, in-checkout
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_spawn_ranks_propagate_cache_env(tmp_path, monkeypatch):
     """launch.py rank processes INHERIT the environment (Popen env=None),
-    so MPI_OPT_TPU_CACHE_DIR set on the supervisor reaches every rank of
-    every restart/resume attempt without an explicit copy."""
+    so JAX_COMPILATION_CACHE_DIR set on the supervisor reaches every
+    rank of every restart/resume attempt without an explicit copy."""
     import mpi_opt_tpu.launch as launch_mod
 
     cache = str(tmp_path / "xla-cache")
-    monkeypatch.setenv("MPI_OPT_TPU_CACHE_DIR", cache)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
     captured = {}
 
     class FakeProc:
@@ -1045,7 +1047,7 @@ def test_spawn_ranks_propagate_cache_env(tmp_path, monkeypatch):
     # env=None IS the propagation mechanism: the child shares os.environ,
     # where the cache dir is already set
     assert captured["env"] is None
-    assert os.environ["MPI_OPT_TPU_CACHE_DIR"] == cache
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == cache
 
 
 # -- priority / deadline scheduling (ISSUE 16) ----------------------------
