@@ -1,0 +1,9 @@
+"""1 - union of device-operation intervals / traced window, from the
+profiler's device trace (never from host spans)."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
