@@ -24,7 +24,7 @@ from mpi_opt_tpu.health import shutdown as _shutdown
 from mpi_opt_tpu.obs import trace as _trace
 from mpi_opt_tpu.ops.pbt import PBTConfig
 from mpi_opt_tpu.utils import integrity, resources
-from mpi_opt_tpu.utils.compile_cache import wire_compile_cache
+from mpi_opt_tpu.utils.compile_cache import keyed_by_names, wire_compile_cache
 from mpi_opt_tpu.utils.exitcodes import EX_DATAERR, EX_IOERR, EX_TEMPFAIL
 from mpi_opt_tpu.utils.integrity import NoVerifiedSnapshotError
 from mpi_opt_tpu.utils.metrics import stdout_logger
@@ -1730,7 +1730,9 @@ def main(argv=None, *, _workload=None) -> int:
     # must hand the server back its own sink, not a cleared one.
     trace_entry = _trace.save()
     try:
-        with _shutdown.ShutdownGuard():
+        # a profiled run is read by the program's own names: they join
+        # the persistent cache's key for its compiles
+        with _shutdown.ShutdownGuard(), keyed_by_names(bool(args.profile_dir)):
             if args.heartbeat_file:
                 _heartbeat.configure(args.heartbeat_file)
             return _run_sweep(args, parser, _workload=_workload)

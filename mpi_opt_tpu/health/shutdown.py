@@ -212,6 +212,26 @@ def clear_slice_hook() -> None:
     set_slice_hook(None)
 
 
+#: the installed boundary observer (see set_boundary_observer)
+_BOUNDARY_OBSERVER: Optional[Callable] = None
+
+
+def set_boundary_observer(fn: Optional[Callable]) -> None:
+    """Install (or, with None, remove) the boundary observer:
+    ``fn(stage, state)`` is called from every
+    ``train.common.launch_boundary``, final ones included, before the
+    slice hook, with the population state the sweep holds there (None
+    where the call site passes none). A reader of the state at a
+    boundary — a benchmark's comparison, a debugger — needs no frame
+    walk. Same rules as the slice hook: cheap, and it does not raise."""
+    global _BOUNDARY_OBSERVER
+    _BOUNDARY_OBSERVER = fn
+
+
+def get_boundary_observer() -> Optional[Callable]:
+    return _BOUNDARY_OBSERVER
+
+
 def poll_slice(stage: str) -> None:
     """Drain points' service call: give an installed slice hook its
     per-boundary look (no-op without one)."""

@@ -66,6 +66,7 @@ CAUSE_OF_SPAN = {
     "digest": "checkpoint",
     "setup": "setup",
     "slice_setup": "setup",
+    "profile": "profile",  # the profiler's stop writes its trace: seconds
 }
 
 #: run-level verdict thresholds (see module docstring). A quarter of

@@ -129,7 +129,11 @@ EVENTS = frozenset(
 #: every legal ``span`` name (the ``span`` field of a ``span`` event)
 SPANS = frozenset(
     {
-        "setup",  # workload data load + trainer/backend construction
+        # setup: workload data load + trainer/backend construction; with
+        # op="startup" the process's start to trace.configure (one
+        # synthesized span), op="init_population" the fused drivers'
+        # initial hyperparameters + weights + mesh placement
+        "setup",
         "compile",  # XLA compile (cache attr: cold | persistent)
         "train",  # one fused train launch / one driver evaluate batch
         "boundary",  # exploit / rung cut / generation-boundary op
@@ -143,7 +147,28 @@ SPANS = frozenset(
         "journal",  # ledger fsync (per final trial / per fused boundary)
         "slice",  # one service scheduling quantum (server side)
         "slice_setup",  # service program-cache acquire + log open
+        "profile",  # jax.profiler start / stop-and-write (op, dir)
     }
+)
+
+#: every ``jax.named_scope`` the device programs carry, outermost
+#: first. A scope is trace-time metadata (it names the HLO operations'
+#: ``op_name`` paths, nothing runs differently), so a profiler trace of
+#: a fused generation can be split by phase: a reduction books each
+#: device operation to the INNERMOST of these names on its path, and
+#: ``transpose(jvp(member_loss))`` (JAX's own wrapping) is the backward
+#: pass. The benchmark's reduction (benchmarks/scopes.py) keeps a copy;
+#: tests/test_device_scopes.py holds the two and the call sites equal.
+DEVICE_SCOPES = (
+    "train_segment",  # the scan over a generation's train steps
+    "train_input",  # minibatch gather + per-member key splits
+    "map_members",  # vmap / chunked lax.map over members (stitching, carries)
+    "member_loss",  # forward; backward as transpose(jvp(member_loss))
+    "augment",  # flips and shifts (inside member_loss)
+    "optimizer_update",  # SGD + momentum + weight decay
+    "eval_population",  # validation pass(es) of the population
+    "exploit",  # ops/pbt.py truncation + explore
+    "gather_members",  # the winners' state copy
 )
 
 
@@ -183,6 +208,7 @@ SPAN_ATTRS = frozenset(
         "cache",  # compile: cold | persistent (listener)
         "during",  # compile: enclosing span name (listener)
         "device",  # local device kind (setup; keys the roofline cap table)
+        "dir",  # profile: the directory the profiler writes its trace under
         # device-memory watermark (obs/memory.py; set at exit)
         "mem_bytes",  # steady bytes_in_use at phase exit
         "mem_peak_bytes",  # peak/watermark bytes at phase exit

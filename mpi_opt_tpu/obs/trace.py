@@ -47,6 +47,7 @@ instead of bare op names.
 from __future__ import annotations
 
 import contextlib
+import os
 import threading
 import time
 from typing import Optional
@@ -97,7 +98,40 @@ def configure(metrics, rank: int = 0, tenant: Optional[str] = None):
         tags["tenant"] = str(tenant)
     _TAGS = tags
     _install_compile_listener()
+    _emit_startup()
     return prior
+
+
+# the ``setup op=startup`` span was emitted (once a process: a later
+# configure — a tenant slice, a second in-process CLI run — is not a
+# process start)
+_STARTUP_EMITTED = False
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process (Linux: the start time
+    in /proc/self/stat against the boot clock), None where the OS does
+    not say."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def _emit_startup() -> None:
+    """The span no ``with`` can open: process start to the moment a
+    sink exists (interpreter, imports of jax and flax, argument
+    parsing, backend start), emitted with its true duration so the
+    seconds before the first launch carry a name."""
+    global _STARTUP_EMITTED
+    if _STARTUP_EMITTED or _SINK is None:
+        return
+    _STARTUP_EMITTED = True
+    age = _process_age_s()
+    if age is not None and age > 0:
+        _emit("setup", age, age, {"op": "startup"})
 
 
 def deconfigure(prior=None) -> None:

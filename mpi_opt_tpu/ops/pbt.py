@@ -56,7 +56,8 @@ def pbt_exploit_explore(
 
     Fully jittable; ``n``, ``d`` and ``cfg`` are static.
     """
-    return _exploit_explore(key, unit, scores, discrete_mask, cfg)
+    with jax.named_scope("exploit"):
+        return _exploit_explore(key, unit, scores, discrete_mask, cfg)
 
 
 def pbt_exploit_explore_mo(
@@ -79,10 +80,11 @@ def pbt_exploit_explore_mo(
     """
     from mpi_opt_tpu.objectives.pareto import pareto_score
 
-    eff = pareto_score(norm_scores, norm_bounds=norm_bounds)
-    new_unit, src_idx, bottom = _exploit_explore(
-        key, unit, eff, discrete_mask, cfg
-    )
+    with jax.named_scope("exploit"):
+        eff = pareto_score(norm_scores, norm_bounds=norm_bounds)
+        new_unit, src_idx, bottom = _exploit_explore(
+            key, unit, eff, discrete_mask, cfg
+        )
     return new_unit, src_idx, bottom, eff
 
 

@@ -107,7 +107,9 @@ def oom_funnel(wave_size=None):
     return _funnel(wave_size)
 
 
-def launch_boundary(stage: str, *, final: bool, snapshot=None, **progress) -> None:
+def launch_boundary(
+    stage: str, *, final: bool, snapshot=None, state=None, **progress
+) -> None:
     """The fused host loops' per-launch service point (one call at the
     end of every launch/rung/generation): write the rank heartbeat, then
     honor a pending graceful-shutdown request — flush the boundary
@@ -136,6 +138,13 @@ def launch_boundary(stage: str, *, final: bool, snapshot=None, **progress) -> No
     issues the next collective alone. The ``resources.boundary_fault``
     seam fires first: the ``rank_kill`` chaos injector counts 1-based
     boundary ordinals here.
+
+    ``state`` is the population state the sweep holds at this boundary
+    (a ``PopState``, or None where the caller has none to show): an
+    installed boundary observer (``shutdown.set_boundary_observer``)
+    is handed it as ``fn(stage, state)`` before the slice hook runs.
+    The state is the live one, donated to the next launch: an observer
+    copies what it wants to keep.
     """
     from mpi_opt_tpu.health import heartbeat, shutdown
     from mpi_opt_tpu.parallel import coord
@@ -151,6 +160,9 @@ def launch_boundary(stage: str, *, final: bool, snapshot=None, **progress) -> No
         stage = f"boundary:{stage}"
     resources.boundary_fault(stage)
     heartbeat.beat(stage=stage, **progress)
+    observer = shutdown.get_boundary_observer()
+    if observer is not None:
+        observer(stage, state)
     if not final:
         shutdown.poll_slice(stage)
     if final or not shutdown.requested():
@@ -225,19 +237,20 @@ def eval_population_objectives(trainer, state, val_x, val_y, names):
     the driver's one per-boundary fetch.
     """
     cols = []
-    for name in names:
-        if name == "accuracy":
-            cols.append(trainer.eval_population(state, val_x, val_y))
-        elif name == "params":
-            cols.append(trainer.member_effective_params(state))
-        elif name == "latency":
-            cols.append(trainer.member_latency_proxy(state))
-        else:
-            raise ValueError(
-                f"unknown population objective {name!r}; "
-                f"supported: {POPULATION_METRICS}"
-            )
-    return jnp.stack(cols, axis=-1)
+    with jax.named_scope("eval_population"):
+        for name in names:
+            if name == "accuracy":
+                cols.append(trainer.eval_population(state, val_x, val_y))
+            elif name == "params":
+                cols.append(trainer.member_effective_params(state))
+            elif name == "latency":
+                cols.append(trainer.member_latency_proxy(state))
+            else:
+                raise ValueError(
+                    f"unknown population objective {name!r}; "
+                    f"supported: {POPULATION_METRICS}"
+                )
+        return jnp.stack(cols, axis=-1)
 
 
 def segment_flops_hint(workload, population: int, steps: int):

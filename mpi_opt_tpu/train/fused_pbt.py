@@ -31,6 +31,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from mpi_opt_tpu.health import shutdown
 from mpi_opt_tpu.obs import memory, trace
 from mpi_opt_tpu.ops.pbt import PBTConfig, pbt_exploit_explore, pbt_exploit_explore_mo
 from mpi_opt_tpu.train.common import (
@@ -241,6 +242,17 @@ def _wave_exploit(
     return new_u, src_idx, scores.max(), scores.mean(), n_fail, scores[src_idx]
 
 
+def _host_state(pool: dict, perm) -> PopState:
+    """The post-exploit population state of a wave-scheduled sweep,
+    materialized on HOST (that is where a beyond-residency population
+    lives): the winners' rows of the pool via the perm."""
+    return PopState(
+        params=jax.tree.map(lambda l: l[perm], pool["params"]),
+        momentum=jax.tree.map(lambda l: l[perm], pool["momentum"]),
+        step=pool["step"][perm],
+    )
+
+
 def _fused_pbt_waves(  # sweeplint: barrier(wave host loop: stages pools, gathers scores, exploits at generation boundaries)
     workload,
     trainer,
@@ -406,25 +418,26 @@ def _fused_pbt_waves(  # sweeplint: barrier(wave host loop: stages pools, gather
                 post_scores = np.asarray(sweep["scores"])
     journal = make_fused_journal(ledger, space)
     journal_require_prefix(journal, start_gen)
-    if restored is None:
-        unit = space.sample_unit(k_unit, population)
-        if warm_obs:
-            from mpi_opt_tpu.ledger.warmstart import best_observation
+    with trace.span("setup", op="init_population", members=int(population)):
+        if restored is None:
+            unit = space.sample_unit(k_unit, population)
+            if warm_obs:
+                from mpi_opt_tpu.ledger.warmstart import best_observation
 
-            bo = best_observation(warm_obs)
-            if bo is not None:
-                # same sampler-family seeding as the resident path
-                unit = np.array(unit)
-                unit[0] = np.asarray(bo.unit, dtype=unit.dtype)
-                unit = jnp.asarray(unit)
-        perm = np.arange(population)
-        # the cold population's host residence; gen 0 fills it by
-        # stage-out (members init on device per wave)
-        pool_front = population_pool(trainer, train_x[:2], population)
-    if pool_back is None:
-        pool_back = population_pool(trainer, train_x[:2], population)
-    if mesh is not None:
-        unit = place_pop(unit, mesh)
+                bo = best_observation(warm_obs)
+                if bo is not None:
+                    # same sampler-family seeding as the resident path
+                    unit = np.array(unit)
+                    unit[0] = np.asarray(bo.unit, dtype=unit.dtype)
+                    unit = jnp.asarray(unit)
+            perm = np.arange(population)
+            # the cold population's host residence; gen 0 fills it by
+            # stage-out (members init on device per wave)
+            pool_front = population_pool(trainer, train_x[:2], population)
+        if pool_back is None:
+            pool_back = population_pool(trainer, train_x[:2], population)
+        if mesh is not None:
+            unit = place_pop(unit, mesh)
 
     snapshot_every = max(1, snapshot_every)
     # the shared wave executor (train/engine.py) owns the StagingEngine,
@@ -619,6 +632,10 @@ def _fused_pbt_waves(  # sweeplint: barrier(wave host loop: stages pools, gather
                 f"pbt gen {g + 1}/{generations} wave {n_waves}/{n_waves}",
                 final=is_last,
                 snapshot=None if (snap is None or saved) else save_boundary,
+                # a host copy of the whole pool: only for an observer
+                state=_host_state(pool_front, perm)
+                if shutdown.get_boundary_observer() is not None
+                else None,
                 launch=(g + 1) * n_waves,
                 of=generations * n_waves,
             )
@@ -629,13 +646,7 @@ def _fused_pbt_waves(  # sweeplint: barrier(wave host loop: stages pools, gather
 
     best_i, diverged = finite_winner(post_scores)
     np_unit = fetch_global(unit)
-    # post-exploit population state, materialized on HOST (that is where
-    # a beyond-residency population lives): winners' rows via the perm
-    state = PopState(
-        params=jax.tree.map(lambda l: l[perm], pool_front["params"]),
-        momentum=jax.tree.map(lambda l: l[perm], pool_front["momentum"]),
-        step=pool_front["step"][perm],
-    )
+    state = _host_state(pool_front, perm)
     return {
         "best_score": float("nan") if diverged else float(post_scores[best_i]),
         "best_params": None if diverged else space.materialize_row(np_unit[best_i]),
@@ -983,26 +994,27 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
     # re-trained generations past the snapshot verify against their
     # records instead of re-writing
     journal_require_prefix(journal, sum(launch_lens[:start_launch]))
-    if restored is None:
-        unit = space.sample_unit(k_unit, population)
-        if warm_obs:
-            from mpi_opt_tpu.ledger.warmstart import best_observation
+    with trace.span("setup", op="init_population", members=int(population)):
+        if restored is None:
+            unit = space.sample_unit(k_unit, population)
+            if warm_obs:
+                from mpi_opt_tpu.ledger.warmstart import best_observation
 
-            bo = best_observation(warm_obs)
-            if bo is not None:
-                # sampler-family warm start: one population row starts
-                # at the prior sweep's best point; PBT's exploit/explore
-                # spreads it if it earns its keep
-                unit = np.array(unit)
-                unit[0] = np.asarray(bo.unit, dtype=unit.dtype)
-                unit = jax.numpy.asarray(unit)
-        state = trainer.init_population(k_init, train_x[:2], population)
-    if mesh is not None:
-        from mpi_opt_tpu.parallel.mesh import place_pop
+                bo = best_observation(warm_obs)
+                if bo is not None:
+                    # sampler-family warm start: one population row starts
+                    # at the prior sweep's best point; PBT's exploit/explore
+                    # spreads it if it earns its keep
+                    unit = np.array(unit)
+                    unit[0] = np.asarray(bo.unit, dtype=unit.dtype)
+                    unit = jax.numpy.asarray(unit)
+            state = trainer.init_population(k_init, train_x[:2], population)
+        if mesh is not None:
+            from mpi_opt_tpu.parallel.mesh import place_pop
 
-        # datasets were already replicated over the mesh by workload_arrays
-        state = shard_popstate(state, mesh)
-        unit = place_pop(unit, mesh)
+            # datasets were already replicated over the mesh by workload_arrays
+            state = shard_popstate(state, mesh)
+            unit = place_pop(unit, mesh)
 
     # hparams_fn must be hashable-static; space comes from the per-
     # workload cache above so its identity is stable across calls
@@ -1178,6 +1190,7 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                 f"pbt launch {i + 1}/{n_launches}",
                 final=is_last,
                 snapshot=None if (snap is None or saved) else save_now,
+                state=state,
                 launch=i + 1,
                 of=n_launches,
             )

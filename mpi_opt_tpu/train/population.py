@@ -117,14 +117,15 @@ def _augment(key: jax.Array, x: jax.Array, flip_prob: jax.Array, shift: jax.Arra
     pad-and-crop; equally effective as regularization, far cheaper to
     compile than dynamic_slice per sample).
     """
-    k_flip, k_dy, k_dx = jax.random.split(key, 3)
-    b = x.shape[0]
-    do_flip = jax.random.bernoulli(k_flip, flip_prob, (b, 1, 1, 1))
-    x = jnp.where(do_flip, x[:, :, ::-1, :], x)
-    max_s = jnp.maximum(shift, 0.0)
-    dy = jnp.round(jax.random.uniform(k_dy, (), minval=-max_s, maxval=max_s)).astype(jnp.int32)
-    dx = jnp.round(jax.random.uniform(k_dx, (), minval=-max_s, maxval=max_s)).astype(jnp.int32)
-    return jnp.roll(x, (dy, dx), axis=(1, 2))
+    with jax.named_scope("augment"):
+        k_flip, k_dy, k_dx = jax.random.split(key, 3)
+        b = x.shape[0]
+        do_flip = jax.random.bernoulli(k_flip, flip_prob, (b, 1, 1, 1))
+        x = jnp.where(do_flip, x[:, :, ::-1, :], x)
+        max_s = jnp.maximum(shift, 0.0)
+        dy = jnp.round(jax.random.uniform(k_dy, (), minval=-max_s, maxval=max_s)).astype(jnp.int32)
+        dx = jnp.round(jax.random.uniform(k_dx, (), minval=-max_s, maxval=max_s)).astype(jnp.int32)
+        return jnp.roll(x, (dy, dx), axis=(1, 2))
 
 
 class PopulationTrainer:
@@ -224,11 +225,14 @@ class PopulationTrainer:
     # -- member-level pieces (scalar hparams; vmapped below) -------------
 
     def _member_loss(self, params, hp: OptHParams, key, bx, by):
-        if self.augment and bx.ndim == 4:
-            bx = _augment(key, bx, hp.flip_prob, hp.shift)
-        logits = self.apply_fn(params, bx)
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-        return -jnp.mean(jnp.take_along_axis(logp, by[:, None], axis=1))
+        # the one scope that splits forward from backward in a device
+        # trace: JAX names the backward ops transpose(jvp(member_loss))
+        with jax.named_scope("member_loss"):
+            if self.augment and bx.ndim == 4:
+                bx = _augment(key, bx, hp.flip_prob, hp.shift)
+            logits = self.apply_fn(params, bx)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            return -jnp.mean(jnp.take_along_axis(logp, by[:, None], axis=1))
 
     def _member_update(self, params, momentum, step, hp: OptHParams, key, bx, by):
         loss, grads = jax.value_and_grad(self._member_loss)(params, hp, key, bx, by)
@@ -236,13 +240,14 @@ class PopulationTrainer:
         # gradient, so the effective decay is lr-scaled), hparams as
         # traced scalars. Math in f32 regardless of the momentum STORAGE
         # dtype (the astype is a no-op at the default f32 storage).
-        m32 = jax.tree.map(
-            lambda m, g, p: hp.momentum * m.astype(jnp.float32) + g + hp.weight_decay * p,
-            momentum, grads, params,
-        )
-        params = jax.tree.map(lambda p, m: p - hp.lr * m, params, m32)
-        dt = self.momentum_dtype
-        momentum = m32 if dt is None else jax.tree.map(lambda m: m.astype(dt), m32)
+        with jax.named_scope("optimizer_update"):
+            m32 = jax.tree.map(
+                lambda m, g, p: hp.momentum * m.astype(jnp.float32) + g + hp.weight_decay * p,
+                momentum, grads, params,
+            )
+            params = jax.tree.map(lambda p, m: p - hp.lr * m, params, m32)
+            dt = self.momentum_dtype
+            momentum = m32 if dt is None else jax.tree.map(lambda m: m.astype(dt), m32)
         return params, momentum, step + 1, loss
 
     def _constrain_data(self, bx, by):
@@ -277,30 +282,31 @@ class PopulationTrainer:
         so the view is exact; members are independent under ``fn``, so
         which of them share a chunk changes no result.
         """
-        chunk = self.member_chunk
-        if chunk <= 0:
-            return jax.vmap(fn)(xs)
-        n = jax.tree.leaves(xs)[0].shape[0]
-        n_pop = 1 if self.mesh is None else int(self.mesh.shape["pop"])
-        if n_pop == 1 or n % n_pop:
-            return jax.lax.map(fn, xs, batch_size=chunk)
-        from jax.sharding import NamedSharding, PartitionSpec
+        with jax.named_scope("map_members"):
+            chunk = self.member_chunk
+            if chunk <= 0:
+                return jax.vmap(fn)(xs)
+            n = jax.tree.leaves(xs)[0].shape[0]
+            n_pop = 1 if self.mesh is None else int(self.mesh.shape["pop"])
+            if n_pop == 1 or n % n_pop:
+                return jax.lax.map(fn, xs, batch_size=chunk)
+            from jax.sharding import NamedSharding, PartitionSpec
 
-        local = n // n_pop
-        chunk = max(c for c in range(1, min(chunk, local) + 1) if local % c == 0)
-        k = local // chunk
-        by_chunk = NamedSharding(self.mesh, PartitionSpec(None, "pop"))
+            local = n // n_pop
+            chunk = max(c for c in range(1, min(chunk, local) + 1) if local % c == 0)
+            k = local // chunk
+            by_chunk = NamedSharding(self.mesh, PartitionSpec(None, "pop"))
 
-        def split(a):  # [n, ...] -> [k, n_pop, chunk, ...], device-local
-            a = a.reshape((n_pop, k, chunk) + a.shape[1:])
-            return jax.lax.with_sharding_constraint(jnp.swapaxes(a, 0, 1), by_chunk)
+            def split(a):  # [n, ...] -> [k, n_pop, chunk, ...], device-local
+                a = a.reshape((n_pop, k, chunk) + a.shape[1:])
+                return jax.lax.with_sharding_constraint(jnp.swapaxes(a, 0, 1), by_chunk)
 
-        def join(a):  # [k, n_pop, chunk, ...] -> [n, ...]
-            a = jnp.swapaxes(a, 0, 1)
-            return a.reshape((n,) + a.shape[3:])
+            def join(a):  # [k, n_pop, chunk, ...] -> [n, ...]
+                a = jnp.swapaxes(a, 0, 1)
+                return a.reshape((n,) + a.shape[3:])
 
-        out = jax.lax.map(jax.vmap(jax.vmap(fn)), jax.tree.map(split, xs))
-        return jax.tree.map(join, out)
+            out = jax.lax.map(jax.vmap(jax.vmap(fn)), jax.tree.map(split, xs))
+            return jax.tree.map(join, out)
 
     def _pop_update(self, state: PopState, hp: OptHParams, keys, bx, by):
         """One step for the whole population on a shared batch."""
@@ -309,6 +315,32 @@ class PopulationTrainer:
             fn, (state.params, state.momentum, state.step, hp, keys)
         )
         return PopState(params=p, momentum=m, step=s), loss
+
+    def _train_input(self, k, train_x, train_y, n: int, window=None):
+        """One step's inputs: the carried key advanced, the ``n``
+        members' augmentation keys and the shared minibatch.
+
+        ``window=(n_total, offset)`` is the wave form: the keys are the
+        wave's window of the full population's per-step split
+        (``offset`` is traced, see ``_train_segment_window``).
+        """
+        with jax.named_scope("train_input"):
+            k, k_batch, k_aug = jax.random.split(k, 3)
+            idx = jax.random.randint(k_batch, (self.batch_size,), 0, train_x.shape[0])
+            bx = jnp.take(train_x, idx, axis=0)
+            by = jnp.take(train_y, idx, axis=0)
+            bx, by = self._constrain_data(bx, by)
+            if window is None:
+                member_keys = jax.random.split(k_aug, n)
+            else:
+                n_total, offset = window
+                all_keys = jax.random.split(k_aug, n_total)
+                member_keys = jax.random.wrap_key_data(
+                    jax.lax.dynamic_slice_in_dim(
+                        jax.random.key_data(all_keys), offset, n, axis=0
+                    )
+                )
+        return k, member_keys, bx, by
 
     def _train_segment(
         self,
@@ -326,20 +358,15 @@ class PopulationTrainer:
         ``trainer_jit``).
         """
         n = state.step.shape[0]
-        n_data = train_x.shape[0]
 
         def one_step(carry, t):
             st, k = carry
-            k, k_batch, k_aug = jax.random.split(k, 3)
-            idx = jax.random.randint(k_batch, (self.batch_size,), 0, n_data)
-            bx = jnp.take(train_x, idx, axis=0)
-            by = jnp.take(train_y, idx, axis=0)
-            bx, by = self._constrain_data(bx, by)
-            member_keys = jax.random.split(k_aug, n)
+            k, member_keys, bx, by = self._train_input(k, train_x, train_y, n)
             st, loss = self._pop_update(st, hp, member_keys, bx, by)
             return (st, k), jnp.mean(loss)
 
-        (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
+        with jax.named_scope("train_segment"):
+            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
         return state, losses
 
     def _train_segment_window(
@@ -368,25 +395,17 @@ class PopulationTrainer:
         all same-sized waves share one compiled program.
         """
         n = state.step.shape[0]
-        n_data = train_x.shape[0]
 
         def one_step(carry, t):
             st, k = carry
-            k, k_batch, k_aug = jax.random.split(k, 3)
-            idx = jax.random.randint(k_batch, (self.batch_size,), 0, n_data)
-            bx = jnp.take(train_x, idx, axis=0)
-            by = jnp.take(train_y, idx, axis=0)
-            bx, by = self._constrain_data(bx, by)
-            all_keys = jax.random.split(k_aug, n_total)
-            member_keys = jax.random.wrap_key_data(
-                jax.lax.dynamic_slice_in_dim(
-                    jax.random.key_data(all_keys), offset, n, axis=0
-                )
+            k, member_keys, bx, by = self._train_input(
+                k, train_x, train_y, n, window=(n_total, offset)
             )
             st, loss = self._pop_update(st, hp, member_keys, bx, by)
             return (st, k), jnp.mean(loss)
 
-        (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
+        with jax.named_scope("train_segment"):
+            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
         return state, losses
 
     def _train_segment_masked(
@@ -415,16 +434,10 @@ class PopulationTrainer:
         grouped path.
         """
         n = state.step.shape[0]
-        n_data = train_x.shape[0]
 
         def one_step(carry, t):
             st, k = carry
-            k, k_batch, k_aug = jax.random.split(k, 3)
-            idx = jax.random.randint(k_batch, (self.batch_size,), 0, n_data)
-            bx = jnp.take(train_x, idx, axis=0)
-            by = jnp.take(train_y, idx, axis=0)
-            bx, by = self._constrain_data(bx, by)
-            member_keys = jax.random.split(k_aug, n)
+            k, member_keys, bx, by = self._train_input(k, train_x, train_y, n)
             new_st, loss = self._pop_update(st, hp, member_keys, bx, by)
             active = t < rem  # bool[P]
 
@@ -435,7 +448,8 @@ class PopulationTrainer:
             st = jax.tree.map(pick, new_st, st)
             return (st, k), jnp.mean(jnp.where(active, loss, 0.0))
 
-        (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
+        with jax.named_scope("train_segment"):
+            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
         return state, losses
 
     @trainer_jit(static_argnames=("eval_chunk",))
@@ -451,28 +465,29 @@ class PopulationTrainer:
         ResNet-scale populations OOM the forward pass without this. The
         tail chunk is masked, not dropped.
         """
-        n_val = val_x.shape[0]
-        n_chunks = -(-n_val // eval_chunk)
-        pad = n_chunks * eval_chunk - n_val
-        vx = jnp.pad(val_x, [(0, pad)] + [(0, 0)] * (val_x.ndim - 1))
-        vy = jnp.pad(val_y, (0, pad), constant_values=-1)
-        vx = vx.reshape((n_chunks, eval_chunk) + val_x.shape[1:])
-        vy = vy.reshape((n_chunks, eval_chunk))
+        with jax.named_scope("eval_population"):
+            n_val = val_x.shape[0]
+            n_chunks = -(-n_val // eval_chunk)
+            pad = n_chunks * eval_chunk - n_val
+            vx = jnp.pad(val_x, [(0, pad)] + [(0, 0)] * (val_x.ndim - 1))
+            vy = jnp.pad(val_y, (0, pad), constant_values=-1)
+            vx = vx.reshape((n_chunks, eval_chunk) + val_x.shape[1:])
+            vy = vy.reshape((n_chunks, eval_chunk))
 
-        def member_correct(params, cx, cy):
-            logits = self.apply_fn(params, cx)
-            pred = jnp.argmax(logits, axis=-1)
-            return jnp.sum((pred == cy) & (cy >= 0))
+            def member_correct(params, cx, cy):
+                logits = self.apply_fn(params, cx)
+                pred = jnp.argmax(logits, axis=-1)
+                return jnp.sum((pred == cy) & (cy >= 0))
 
-        def chunk_step(acc, chunk):
-            cx, cy = chunk
-            cx, cy = self._constrain_data(cx, cy)
-            corr = self._map_members(lambda p: member_correct(p, cx, cy), state.params)
-            acc = acc + corr
-            return acc, None
+            def chunk_step(acc, chunk):
+                cx, cy = chunk
+                cx, cy = self._constrain_data(cx, cy)
+                corr = self._map_members(lambda p: member_correct(p, cx, cy), state.params)
+                acc = acc + corr
+                return acc, None
 
-        correct, _ = jax.lax.scan(chunk_step, jnp.zeros((state.step.shape[0],), jnp.int32), (vx, vy))
-        return correct.astype(jnp.float32) / n_val
+            correct, _ = jax.lax.scan(chunk_step, jnp.zeros((state.step.shape[0],), jnp.int32), (vx, vy))
+            return correct.astype(jnp.float32) / n_val
 
     # -- multi-objective member metrics (ISSUE 17) ------------------------
 
@@ -524,7 +539,8 @@ class PopulationTrainer:
 
         The MPI weight transfer of the reference, as one device gather.
         """
-        return jax.tree.map(lambda x: x[src_idx], state)
+        with jax.named_scope("gather_members"):
+            return jax.tree.map(lambda x: x[src_idx], state)
 
     @staticmethod
     @jax.jit

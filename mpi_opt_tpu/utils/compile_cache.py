@@ -18,6 +18,7 @@ CPU and the TPU share the directory without meeting.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -40,3 +41,38 @@ def wire_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR
+
+
+@contextlib.contextmanager
+def keyed_by_names(on: bool = True):
+    """For a run under the profiler: make the operations' names part of
+    the cache key.
+
+    jax keys the persistent cache on the program with its debug info
+    stripped, so two programs that differ only in their ``op_name``
+    paths (a ``jax.named_scope`` added or renamed: obs/events.py
+    ``DEVICE_SCOPES``) share one entry, and whichever was compiled
+    first comes back with ITS names. Measured, PR 25: the first traced
+    chip run after the scopes landed loaded the parent commit's
+    executable and its trace carried not one scope. A profiled run is
+    read by those names, so inside this context the key includes them
+    (``jax_compilation_cache_include_metadata_in_key``), and the
+    locations carry the name stack alone, no file or line
+    (``jax_traceback_in_locations_limit`` 0): the key then moves with a
+    name, not with every edit that shifts a line or with the checkout's
+    path. Runs without the profiler keep jax's default key.
+    """
+    if not on:
+        yield
+        return
+    import jax
+
+    names = ("jax_compilation_cache_include_metadata_in_key", "jax_traceback_in_locations_limit")
+    prior = [getattr(jax.config, n) for n in names]
+    for n, v in zip(names, (True, 0)):
+        jax.config.update(n, v)
+    try:
+        yield
+    finally:
+        for n, v in zip(names, prior):
+            jax.config.update(n, v)

@@ -33,6 +33,7 @@ import contextlib
 import sys
 
 _ACTIVE = False  # a jax profiler trace is currently recording
+_DIRECTORY = None  # where the recording trace will be written
 _WINDOW = None  # the installed _LaunchWindow, if any
 
 
@@ -40,20 +41,33 @@ def active() -> bool:
     return _ACTIVE
 
 
+def _span(op: str, directory):
+    """The ``profile`` span around a profiler start or stop. Opened
+    while ``_ACTIVE`` is false on both sides, so it is never a
+    ``TraceAnnotation`` itself; ``dir`` tells a reader of the stream
+    where the trace lies. (obs/trace.py imports this module, hence the
+    import here.)"""
+    from mpi_opt_tpu.obs import trace
+
+    return trace.span("profile", op=op, dir=str(directory))
+
+
 def _start(directory) -> bool:
-    global _ACTIVE
+    global _ACTIVE, _DIRECTORY
     import jax
 
-    try:
-        jax.profiler.start_trace(str(directory))
-    except Exception as e:
-        print(
-            f"[profile] trace start failed ({type(e).__name__}: {e}); "
-            "continuing unprofiled",
-            file=sys.stderr,
-        )
-        return False
+    with _span("start", directory):
+        try:
+            jax.profiler.start_trace(str(directory))
+        except Exception as e:
+            print(
+                f"[profile] trace start failed ({type(e).__name__}: {e}); "
+                "continuing unprofiled",
+                file=sys.stderr,
+            )
+            return False
     _ACTIVE = True
+    _DIRECTORY = directory
     return True
 
 
@@ -64,13 +78,14 @@ def _stop() -> None:
     _ACTIVE = False
     import jax
 
-    try:
-        jax.profiler.stop_trace()
-    except Exception as e:
-        print(
-            f"[profile] trace stop failed ({type(e).__name__}: {e})",
-            file=sys.stderr,
-        )
+    with _span("stop", _DIRECTORY):  # the stop writes the trace: seconds
+        try:
+            jax.profiler.stop_trace()
+        except Exception as e:
+            print(
+                f"[profile] trace stop failed ({type(e).__name__}: {e})",
+                file=sys.stderr,
+            )
 
 
 class _LaunchWindow:

@@ -30,6 +30,16 @@ jax.config.update("jax_enable_x64", False)
 jax.config.update("jax_enable_compilation_cache", False)
 
 
+# The `setup op=startup` span (obs/trace.py) is emitted once a process,
+# with the process's age as its duration: in this long-lived pytest
+# process it would put minutes of "setup" into whichever test traces
+# first. Latched as already emitted here; tests/test_obs.py re-arms it
+# where it is the subject.
+from mpi_opt_tpu.obs import trace as _trace  # noqa: E402
+
+_trace._STARTUP_EMITTED = True
+
+
 # -- runtime sanitizers (ISSUE 9 + 15; tests/sanitizers.py) ---------------
 #
 # Every test is followed by a leak check over process-global state:

@@ -74,6 +74,7 @@ def snapshot() -> dict:
         "observer": integrity._OBSERVER,
         "guard": shutdown._ACTIVE,
         "slice_hook": shutdown._SLICE_HOOK,
+        "boundary_observer": shutdown._BOUNDARY_OBSERVER,
         "beat_listener": heartbeat._LISTENER,
         "spool_faults": _spool_faults(),
         "resource_state": _resource_state(),
@@ -170,6 +171,11 @@ def leaks(before: dict) -> list:
         problems.append(
             "slice hook left installed — shutdown.clear_slice_hook() "
             "missing on a scheduler exit path"
+        )
+    if shutdown._BOUNDARY_OBSERVER is not before["boundary_observer"]:
+        problems.append(
+            "boundary observer left installed — "
+            "shutdown.set_boundary_observer(None) missing on an exit path"
         )
     if heartbeat._LISTENER is not before["beat_listener"]:
         problems.append(

@@ -483,6 +483,70 @@ def test_cli_validates_profile_launches(capsys):
     assert "requires --profile-dir" in capsys.readouterr().err
 
 
+# -- spans back to process start (ISSUE 25) --------------------------------
+
+
+def test_startup_span_is_synthesized_once_a_process(tmp_path, monkeypatch):
+    """The first ``configure`` of a process emits ``setup op=startup``
+    with its TRUE start (the OS's start time of the process); a later
+    configure (a tenant slice, a second in-process run) emits none."""
+    monkeypatch.setattr(trace, "_STARTUP_EMITTED", False)  # conftest latches it
+    path = str(tmp_path / "m.jsonl")
+    m = MetricsLogger(path=path)
+    age = trace._process_age_s()
+    prior = trace.configure(m)
+    trace.deconfigure(prior)
+    prior = trace.configure(m)  # again: no second startup
+    trace.deconfigure(prior)
+    m.close()
+    (sp,) = [r for r in _spans(path) if r.get("op") == "startup"]
+    assert sp["span"] == "setup" and sp["self_s"] == sp["dur_s"]
+    # this pytest process has been up for seconds at least, and the
+    # span's start is the process's start: ts - dur_s is the same
+    # moment the OS reports, to the clock tick
+    assert age > 1.0 and sp["dur_s"] == pytest.approx(age, abs=0.5)
+    assert sp["ts"] - sp["dur_s"] == pytest.approx(time.time() - trace._process_age_s(), abs=0.5)
+
+
+def test_traced_run_spans_init_population_and_the_profiler(tmp_path, capsys, monkeypatch):
+    """A traced tiny fused sweep with ``--profile-dir``: the stream
+    holds ``setup op=startup``, ``setup op=init_population`` and
+    ``profile op=start/stop`` with ``dir`` (where the trace lies), the
+    profile spans are not inside the trace they bracket, and the whole
+    stream passes the registry."""
+    from mpi_opt_tpu.cli import main
+    from mpi_opt_tpu.utils import profiling
+
+    monkeypatch.setattr(trace, "_STARTUP_EMITTED", False)
+    mf, pdir = str(tmp_path / "m.jsonl"), str(tmp_path / "prof")
+    rc = main(
+        [
+            "--workload", "fashion_mlp", "--algorithm", "pbt", "--fused",
+            "--no-mesh", "--population", "2", "--generations", "3",
+            "--steps-per-generation", "1", "--gen-chunk", "1", "--seed", "0",
+            "--metrics-file", mf, "--trace",
+            "--profile-dir", pdir, "--profile-launches", "2:2",
+        ]
+    )
+    capsys.readouterr()
+    assert rc == 0 and not profiling.active()
+    spans = _spans(mf)
+    assert spans[0]["span"] == "setup" and spans[0]["op"] == "startup"
+    (init,) = [r for r in spans if r.get("op") == "init_population"]
+    assert init["span"] == "setup" and init["members"] == 2
+    prof = [r for r in spans if r["span"] == "profile"]
+    assert [r["op"] for r in prof] == ["start", "stop"]
+    assert all(r["dir"] == pdir for r in prof)
+    assert os.path.isdir(os.path.join(pdir, "plugins", "profile"))
+    # start precedes launch 2's train span, stop follows it
+    order = [(r["span"], r.get("op") or r.get("launch")) for r in spans if r["span"] in ("profile", "train")]
+    assert order == [("train", 1), ("profile", "start"), ("train", 2), ("profile", "stop"), ("train", 3)]
+    for r in spans:
+        assert events.is_span(r["span"])
+        extra = set(r) - {"event", "t", "ts", "span", "dur_s", "self_s", "tid", "rank", "tenant"}
+        assert all(events.is_span_attr(k) for k in extra), (r["span"], extra)
+
+
 # -- service live phase --------------------------------------------------
 
 
