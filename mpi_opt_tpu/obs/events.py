@@ -160,9 +160,12 @@ SPANS = frozenset(
 #: pass. The benchmark's reduction (benchmarks/scopes.py) keeps a copy;
 #: tests/test_device_scopes.py holds the two and the call sites equal.
 DEVICE_SCOPES = (
-    "train_segment",  # the scan over a generation's train steps
+    "train_segment",  # a generation's train steps (scan over steps; chunked: the nest)
     "train_input",  # minibatch gather + per-member key splits
-    "map_members",  # vmap / chunked lax.map over members (stitching, carries)
+    # the loop over members: the vmap of a step; with member_chunk the
+    # train segment's chunk loop AROUND the step loop (each chunk cut
+    # from the state and written back once a segment), eval's lax.map
+    "map_members",
     "member_loss",  # forward; backward as transpose(jvp(member_loss))
     "augment",  # flips and shifts (inside member_loss)
     "optimizer_update",  # SGD + momentum + weight decay

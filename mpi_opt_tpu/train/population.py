@@ -30,6 +30,7 @@ Design notes (TPU):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable
 
@@ -128,6 +129,16 @@ def _augment(key: jax.Array, x: jax.Array, flip_prob: jax.Array, shift: jax.Arra
         return jnp.roll(x, (dy, dx), axis=(1, 2))
 
 
+def _where_members(mask: jax.Array, a, b):
+    """Per-member choice between two population pytrees: member m of
+    the result is ``a``'s where ``mask[m]``, else ``b``'s."""
+
+    def pick(x, y):
+        return jnp.where(mask.reshape((-1,) + (1,) * (x.ndim - 1)), x, y)
+
+    return jax.tree.map(pick, a, b)
+
+
 class PopulationTrainer:
     """Builds the jitted population train/eval programs for one model.
 
@@ -137,9 +148,14 @@ class PopulationTrainer:
         init_fn: ``init(rng, sample_x) -> params``.
         batch_size: per-step minibatch size (shared across members).
         augment: whether image augmentation applies (False for tabular).
-        member_chunk: if >0, process members in chunks of this size via
-            ``lax.map`` (activation-memory relief for big populations;
-            params/momentum still resident for all members).
+        member_chunk: if >0, at most this many members' activations
+            live at once (activation-memory relief for big
+            populations; params/momentum still resident for all
+            members). A train segment trains each chunk of that many
+            members through ALL its steps before the next, so the
+            population's state is cut and written back in place once a
+            chunk a segment, not once a step (``_train_nest``);
+            evaluation ``lax.map``s the chunks (``_map_members``).
         donate: donate the input state to ``train_segment`` so XLA can
             reuse its buffers for the output instead of holding old and
             new population state simultaneously — the difference between
@@ -263,38 +279,41 @@ class PopulationTrainer:
 
     # -- population programs ---------------------------------------------
 
+    def _per_device_chunks(self, n: int):
+        """``(n_pop, k, chunk)`` where a mesh's 'pop' axis shards ``n``
+        members evenly, else None: each device's ``n/n_pop`` members
+        walked as ``k`` chunks. The chunk shrinks to the largest
+        divisor of the per-device count, so the ``[n_pop, k, chunk]``
+        view is exact; members are independent, so which of them share
+        a chunk changes no result."""
+        n_pop = 1 if self.mesh is None else int(self.mesh.shape["pop"])
+        if n_pop == 1 or n % n_pop:
+            return None
+        local = n // n_pop
+        chunk = max(c for c in range(1, min(self.member_chunk, local) + 1) if local % c == 0)
+        return n_pop, local // chunk, chunk
+
     def _map_members(self, fn, xs):
         """``fn`` over the leading member axis of the pytree ``xs``:
         vmapped whole, or — with ``member_chunk`` — ``lax.map``'ed in
         chunks of that many members (activation-memory relief).
-
-        On a mesh whose 'pop' axis shards the members, the chunks are
-        cut PER DEVICE: each device's ``n/n_pop`` members are viewed as
-        ``[k, chunk]`` and step ``j`` of the map runs chunk ``j`` of
-        every device at once. Chunking the global axis instead
-        (``lax.map`` over ``[n/chunk, chunk]``) scans over the very
-        dimension that is sharded: the TPU compiler then all-gathers
-        the whole population's state onto every chip (433 all-gathers
-        and 17 GiB a chip for a pop=128 ResNet-18 on a 2x2 v5e, where
-        the per-device cut needs none — compiled for the described
-        topology, PR 21). Virtual CPU devices never showed it. The
-        chunk shrinks to the largest divisor of the per-device count,
-        so the view is exact; members are independent under ``fn``, so
-        which of them share a chunk changes no result.
+        Evaluation's loop over members: it cuts the parameters once a
+        validation chunk, twice a generation. The train segment has a
+        nest of its own (``_train_nest``), which cuts the whole state
+        once a segment; on a 'pop' mesh both cut per device
+        (``_member_chunks`` says why).
         """
         with jax.named_scope("map_members"):
             chunk = self.member_chunk
             if chunk <= 0:
                 return jax.vmap(fn)(xs)
             n = jax.tree.leaves(xs)[0].shape[0]
-            n_pop = 1 if self.mesh is None else int(self.mesh.shape["pop"])
-            if n_pop == 1 or n % n_pop:
+            per_device = self._per_device_chunks(n)
+            if per_device is None:
                 return jax.lax.map(fn, xs, batch_size=chunk)
             from jax.sharding import NamedSharding, PartitionSpec
 
-            local = n // n_pop
-            chunk = max(c for c in range(1, min(chunk, local) + 1) if local % c == 0)
-            k = local // chunk
+            n_pop, k, chunk = per_device
             by_chunk = NamedSharding(self.mesh, PartitionSpec(None, "pop"))
 
             def split(a):  # [n, ...] -> [k, n_pop, chunk, ...], device-local
@@ -309,11 +328,10 @@ class PopulationTrainer:
             return jax.tree.map(join, out)
 
     def _pop_update(self, state: PopState, hp: OptHParams, keys, bx, by):
-        """One step for the whole population on a shared batch."""
-        fn = lambda a: self._member_update(*a, bx, by)
-        p, m, s, loss = self._map_members(
-            fn, (state.params, state.momentum, state.step, hp, keys)
-        )
+        """One step of these members (the population or one chunk of it)
+        on a shared batch: ``(state, loss[members])``."""
+        fn = lambda *a: self._member_update(*a, bx, by)
+        p, m, s, loss = jax.vmap(fn)(state.params, state.momentum, state.step, hp, keys)
         return PopState(params=p, momentum=m, step=s), loss
 
     def _train_input(self, k, train_x, train_y, n: int, window=None):
@@ -342,6 +360,141 @@ class PopulationTrainer:
                 )
         return k, member_keys, bx, by
 
+    def _member_chunks(self, n: int):
+        """How a chunked train segment walks ``n`` members:
+        ``(k, chunk, view, unview, cut, put)``, or None where the
+        population is one vmap (no ``member_chunk``, or one that holds
+        every member). ``view`` is applied once to every per-member
+        array, ``cut(a, j, size=chunk)`` takes pass ``j``'s members out
+        of a viewed array with a ``dynamic_slice`` and ``put(a, piece,
+        j)`` writes them back with a ``dynamic_update_slice``, both on
+        an axis no device shares; passes ``0..k-1`` hold ``chunk``
+        members a device, and ``cut(a, k, n - k * chunk)`` is the
+        remainder where the chunk does not divide ``n`` (a pass of its
+        own, as ``lax.map(batch_size=)`` runs it).
+
+        On a mesh whose 'pop' axis shards the members, the chunks are
+        cut PER DEVICE: each device's ``n/n_pop`` members are viewed as
+        ``[k, chunk]`` and pass ``j`` runs chunk ``j`` of every device
+        at once. Chunking the global axis instead loops over the very
+        dimension that is sharded: the TPU compiler then all-gathers
+        the whole population's state onto every chip (433 all-gathers
+        and 17 GiB a chip for a pop=128 ResNet-18 on a 2x2 v5e, where
+        the per-device cut needs none — compiled for the described
+        topology, PR 21). Virtual CPU devices never showed it.
+        """
+        chunk = self.member_chunk
+        if chunk <= 0:
+            return None
+        per_device = self._per_device_chunks(n)
+        if per_device is None:
+            if chunk >= n:
+                return None
+            ident = lambda a: a
+
+            def cut(a, j, size=chunk):
+                return jax.lax.dynamic_slice_in_dim(a, j * chunk, size, axis=0)
+
+            def put(a, piece, j):
+                return jax.lax.dynamic_update_slice_in_dim(a, piece, j * chunk, axis=0)
+
+            return n // chunk, chunk, ident, ident, cut, put
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        n_pop, k, chunk = per_device
+        by_member = NamedSharding(self.mesh, PartitionSpec("pop"))
+
+        def view(a):  # [n, ...] -> [n_pop, k, chunk, ...], device-local
+            return a.reshape((n_pop, k, chunk) + a.shape[1:])
+
+        def unview(a):
+            return a.reshape((n,) + a.shape[3:])
+
+        def cut(a, j, size=chunk):  # -> [n_pop * chunk, ...], sharded as the members are
+            a = jax.lax.dynamic_slice_in_dim(a, j, 1, axis=1)
+            a = a.reshape((n_pop * size,) + a.shape[3:])
+            return jax.lax.with_sharding_constraint(a, by_member)
+
+        def put(a, piece, j):
+            piece = piece.reshape((n_pop, 1, chunk) + piece.shape[1:])
+            return jax.lax.dynamic_update_slice_in_dim(a, piece, j, axis=1)
+
+        return k, chunk, view, unview, cut, put
+
+    def _train_nest(self, state, hp, train_x, train_y, key, steps, window=None, rem=None):
+        """The loop nest of the three train-segment programs:
+        ``(state, mean losses [steps])``. They differ in ``window`` (the
+        wave form's ``(n_total, offset)`` of ``_train_input``) and in
+        ``rem`` (the masked form's int32[P] budgets) alone.
+
+        Without ``member_chunk``: scan over steps of one vmap over the
+        members. With it, the chunk loop is OUTSIDE the step loop: each
+        chunk is cut from the population's state once, trained through
+        all ``steps`` and written back in place, so the state is cut
+        and stitched once a segment (a step loop around a chunk loop
+        did it every step: 17% of a ResNet-18 generation, PR 25's
+        ``device_train_rest_s``). That is the same arithmetic because
+        members are independent given the shared minibatch, and the
+        minibatch and the augmentation keys depend on ``key`` alone,
+        never on the state: every chunk walks the same key chain from
+        ``key``, draws the same minibatches, and takes its own members'
+        rows of the same per-step key split.
+        """
+        n = state.step.shape[0]
+        chunks = self._member_chunks(n)
+        # the scope of the loop over members: around the vmap of each
+        # step, or around the whole chunk loop
+        step_scope = (
+            contextlib.nullcontext
+            if chunks
+            else functools.partial(jax.named_scope, "map_members")
+        )
+
+        def run(st, hp, rem, pick):
+            """``steps`` steps of the members of ``st``; ``pick`` takes
+            their rows of the whole state's per-step keys. A member
+            past its budget reads loss 0. Losses [steps, members] of a
+            chunk, for the one mean over all members; whole, the mean
+            is taken inside the step, where it is a scalar to reduce
+            over a mesh."""
+
+            def one_step(carry, t):
+                st, k = carry
+                k, member_keys, bx, by = self._train_input(k, train_x, train_y, n, window)
+                with step_scope():
+                    new_st, loss = self._pop_update(st, hp, pick(member_keys), bx, by)
+                if rem is not None:
+                    active = t < rem  # bool[members]
+                    new_st = _where_members(active, new_st, st)
+                    loss = jnp.where(active, loss, 0.0)
+                return (new_st, k), (loss if chunks else jnp.mean(loss))
+
+            (st, _), losses = jax.lax.scan(one_step, (st, key), jnp.arange(steps))
+            return st, losses
+
+        with jax.named_scope("train_segment"):
+            if not chunks:
+                return run(state, hp, rem, lambda keys: keys)
+            with jax.named_scope("map_members"):
+                k, chunk, view, unview, cut, put = chunks
+                hp_v, rem_v = jax.tree.map(view, (hp, rem))
+
+                def one_chunk(st, j, size=chunk):
+                    take = lambda a: cut(a, j, size)
+                    pick = lambda keys: jax.random.wrap_key_data(
+                        take(view(jax.random.key_data(keys)))
+                    )
+                    piece, losses = run(*jax.tree.map(take, (st, hp_v, rem_v)), pick)
+                    return jax.tree.map(lambda a, p: put(a, p, j), st, piece), losses
+
+                st, losses = jax.lax.scan(one_chunk, jax.tree.map(view, state), jnp.arange(k))
+                total = jnp.sum(losses, axis=(0, 2))  # [k, steps, members] -> [steps]
+                tail = n - k * losses.shape[2]
+                if tail:
+                    st, losses = one_chunk(st, k, tail)
+                    total = total + jnp.sum(losses, axis=1)
+                return jax.tree.map(unview, st), total / n
+
     def _train_segment(
         self,
         state: PopState,
@@ -357,17 +510,7 @@ class PopulationTrainer:
         per-instance, and the wrapper must die with the instance: see
         ``trainer_jit``).
         """
-        n = state.step.shape[0]
-
-        def one_step(carry, t):
-            st, k = carry
-            k, member_keys, bx, by = self._train_input(k, train_x, train_y, n)
-            st, loss = self._pop_update(st, hp, member_keys, bx, by)
-            return (st, k), jnp.mean(loss)
-
-        with jax.named_scope("train_segment"):
-            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
-        return state, losses
+        return self._train_nest(state, hp, train_x, train_y, key, steps)
 
     def _train_segment_window(
         self,
@@ -394,19 +537,9 @@ class PopulationTrainer:
         wave. ``offset`` is traced (dynamic_slice on the key data), so
         all same-sized waves share one compiled program.
         """
-        n = state.step.shape[0]
-
-        def one_step(carry, t):
-            st, k = carry
-            k, member_keys, bx, by = self._train_input(
-                k, train_x, train_y, n, window=(n_total, offset)
-            )
-            st, loss = self._pop_update(st, hp, member_keys, bx, by)
-            return (st, k), jnp.mean(loss)
-
-        with jax.named_scope("train_segment"):
-            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
-        return state, losses
+        return self._train_nest(
+            state, hp, train_x, train_y, key, steps, window=(n_total, offset)
+        )
 
     def _train_segment_masked(
         self,
@@ -433,24 +566,7 @@ class PopulationTrainer:
         deterministic given the batch plan, not bit-identical to the
         grouped path.
         """
-        n = state.step.shape[0]
-
-        def one_step(carry, t):
-            st, k = carry
-            k, member_keys, bx, by = self._train_input(k, train_x, train_y, n)
-            new_st, loss = self._pop_update(st, hp, member_keys, bx, by)
-            active = t < rem  # bool[P]
-
-            def pick(a, b):
-                m = active.reshape((-1,) + (1,) * (a.ndim - 1))
-                return jnp.where(m, a, b)
-
-            st = jax.tree.map(pick, new_st, st)
-            return (st, k), jnp.mean(jnp.where(active, loss, 0.0))
-
-        with jax.named_scope("train_segment"):
-            (state, _), losses = jax.lax.scan(one_step, (state, key), jnp.arange(steps))
-        return state, losses
+        return self._train_nest(state, hp, train_x, train_y, key, steps, rem=rem)
 
     @trainer_jit(static_argnames=("eval_chunk",))
     def eval_population(
@@ -546,7 +662,4 @@ class PopulationTrainer:
     @jax.jit
     def select_members(fresh_mask: jax.Array, fresh: PopState, existing: PopState) -> PopState:
         """Per-member choice between a fresh init and existing state."""
-        def pick(a, b):
-            m = fresh_mask.reshape((-1,) + (1,) * (a.ndim - 1))
-            return jnp.where(m, a, b)
-        return jax.tree.map(pick, fresh, existing)
+        return _where_members(fresh_mask, fresh, existing)

@@ -112,16 +112,28 @@ def test_pallas_gn_backward_compiles_to_kernels(on_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 2  # fwd + bwd kernels
 
 
+# `temp_size_in_bytes` of the program below at the parent of PR 26
+# (commit 9c6ab68: the chunk loop inside the step loop, and with it a
+# stacked second copy of the population's state)
+_PARENT_TEMP_BYTES = 5013452288
+
+
 def test_smallcnn_member_chunk_train_step_compiles_and_fits(on_chip, key_on_chip):
-    """One step of the headline trainer at SmallCNN's full 32/64
-    channels: one member chunk (32 members) under lax.map, batch 256."""
+    """A few steps of the headline trainer at SmallCNN's full 32/64
+    channels: 128 members in four chunks of 32, batch 256. The TPU
+    compiler's program cuts and stitches the population's state in the
+    chunk loop alone — the step loop's body holds no slice, update or
+    copy of a whole-population leaf — and needs less than the parent's
+    temporaries."""
+    import hlo_loops
+
     from mpi_opt_tpu.train.population import OptHParams
     from mpi_opt_tpu.workloads import get_workload
 
     wl = get_workload("cifar10_cnn")
     wl._data = {"n_classes": 10}  # the model needs only this; no data is made
     trainer = wl.make_trainer(member_chunk=32)
-    pop = 32
+    pop, steps = 128, 3
     sample = jax.ShapeDtypeStruct((2, 32, 32, 3), jnp.float32)
     key = jax.eval_shape(lambda: jax.random.key(0))
     place = lambda tree: jax.tree.map(lambda s: on_chip(s.shape, s.dtype), tree)
@@ -131,9 +143,13 @@ def test_smallcnn_member_chunk_train_step_compiles_and_fits(on_chip, key_on_chip
     hp = place(jax.eval_shape(lambda: OptHParams.defaults(pop)))
     compiled = trainer.train_segment.lower(
         state, hp, on_chip((wl.n_train, 32, 32, 3)), on_chip((wl.n_train,), jnp.int32),
-        key_on_chip, steps=1,
+        key_on_chip, steps=steps,
     ).compile()
+    loops = hlo_loops.loops(compiled.as_text())  # a loop's body includes the loops nested in it
+    assert steps in [l.trips for l in loops]
+    assert [l.trips for l in loops if hlo_loops.state_cuts(l, state, copies_of=pop)] == [4]
     ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < _PARENT_TEMP_BYTES
     live = (
         ma.argument_size_in_bytes + ma.output_size_in_bytes
         + ma.temp_size_in_bytes - ma.alias_size_in_bytes

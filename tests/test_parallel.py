@@ -129,6 +129,43 @@ def test_member_chunk_on_a_pop_mesh_cuts_each_devices_own_members(workload):
     np.testing.assert_array_equal(out[0][1], out[1][1])
 
 
+def test_chunked_segment_on_a_pop_mesh_equals_one_device(workload):
+    """The chunk loop outside the step loop, on four devices: chunk j
+    of every device at once, cut on an axis no device shares. The
+    result is the one-device result to the bit, the state stays sharded
+    over 'pop', and the program holds no all-gather."""
+    import re
+
+    import jax.numpy as jnp
+
+    from mpi_opt_tpu.parallel.mesh import replicate
+
+    d = workload.data()
+    mesh = make_mesh(n_pop=4, n_data=1, devices=jax.devices()[:4])
+    tx, ty = jnp.asarray(d["train_x"]), jnp.asarray(d["train_y"])
+    space = workload.default_space()
+    hp = workload.make_hparams(space.from_unit(space.sample_unit(jax.random.key(1), 16)))
+    key = jax.random.key(2)
+    rem = jnp.arange(16, dtype=jnp.int32) % 4
+
+    one = workload.make_trainer(member_chunk=2, donate=False)
+    st = one.init_population(jax.random.key(0), tx[:2], 16)
+    want, _ = one.train_segment(st, hp, tx, ty, key, 3)
+    want_masked, _ = one.train_segment_masked(st, hp, tx, ty, key, 3, rem)
+
+    four = workload.make_trainer(member_chunk=2, mesh=mesh, donate=False)  # 4 a device: 2 chunks
+    rep = replicate(mesh)
+    txm, tym = jax.device_put(tx, rep), jax.device_put(ty, rep)
+    stm = shard_popstate(st, mesh)
+    text = four.train_segment.lower(stm, hp, txm, tym, key, 3).compile().as_text()
+    assert not re.search(r"all-gather(-start)?\(", text)
+    got, _ = four.train_segment(stm, hp, txm, tym, key, 3)
+    got_masked, _ = four.train_segment_masked(stm, hp, txm, tym, key, 3, rem)
+    assert jax.tree.leaves(got.params)[0].sharding == pop_sharding(mesh)
+    for a, b in zip(jax.tree.leaves((got, got_masked)), jax.tree.leaves((want, want_masked))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_shard_popstate_places_on_mesh(workload):
     mesh = make_mesh(n_pop=8, n_data=1)
     trainer = workload.make_trainer()

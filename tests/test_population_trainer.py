@@ -106,6 +106,114 @@ def test_member_chunk_matches_full_vmap(setup):
     np.testing.assert_allclose(la, lb, rtol=2e-2, atol=2e-5)  # bf16 tolerance
 
 
+def _chunked(trainer, chunk=2):
+    return PopulationTrainer(
+        apply_fn=trainer.apply_fn, init_fn=trainer.init_fn, batch_size=128, member_chunk=chunk
+    )
+
+
+def _distinct_hparams(n):
+    """A different row for every member, augmentation on."""
+    r = jnp.arange(n, dtype=jnp.float32)
+    return OptHParams(
+        lr=0.02 + 0.01 * r,
+        momentum=0.8 + 0.02 * r,
+        weight_decay=1e-4 * (1.0 + r),
+        flip_prob=0.1 + 0.05 * r,
+        shift=1.0 + 0.5 * r,
+    )
+
+
+def _per_step_reference(trainer, st, hp, tx, ty, key, steps, chunk, n_total, offset, rem):
+    """The train segment as the old order had it, one launch a chunk a
+    step: ``for t in steps: for chunk: vmap(_member_update)``, with the
+    key chain and the members' window of the per-step split spelled out
+    (the contract of ``_train_input``, not a call to it)."""
+    n = st.step.shape[0]
+    update = jax.jit(jax.vmap(trainer._member_update, in_axes=(0, 0, 0, 0, 0, None, None)))
+    k = key
+    for t in range(steps):
+        k, k_batch, k_aug = jax.random.split(k, 3)
+        idx = jax.random.randint(k_batch, (trainer.batch_size,), 0, tx.shape[0])
+        bx, by = tx[idx], ty[idx]
+        keys = jax.random.split(k_aug, n_total)[offset : offset + n]
+        pieces = []
+        for lo in range(0, n, chunk):
+            cut = lambda a: a[lo : lo + chunk]
+            old = jax.tree.map(cut, st)
+            p, m, s, _ = update(old.params, old.momentum, old.step, jax.tree.map(cut, hp), cut(keys), bx, by)
+            new = PopState(params=p, momentum=m, step=s)
+            if rem is not None:
+                active = t < cut(rem)
+                keep = lambda a, b: jnp.where(active.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
+                new = jax.tree.map(keep, new, old)
+            pieces.append(new)
+        st = jax.tree.map(lambda *xs: jnp.concatenate(xs), *pieces)
+    return st
+
+
+@pytest.mark.parametrize("n", [4, 5], ids=["chunk_divides", "chunk_leaves_a_remainder"])
+@pytest.mark.parametrize("form", ["segment", "window", "masked"])
+def test_chunked_nest_matches_the_per_step_order_bit_for_bit(setup, form, n):
+    """The chunk loop outside the step loop trains every member exactly
+    as the step loop around the chunk loop did: same chunks, same
+    minibatches, same augmentation keys, ``step + 1`` a step."""
+    trainer, data = setup
+    tx, ty = data["train_x"], data["train_y"]
+    chunked = _chunked(trainer)
+    steps, key = 4, jax.random.key(21)
+    st = trainer.init_population(jax.random.key(20), tx[:2], n)
+    hp = _distinct_hparams(n)
+    n_total, offset, rem = n, 0, None
+    if form == "segment":
+        got, losses = chunked.train_segment(st, hp, tx, ty, key, steps)
+    elif form == "window":
+        n_total, offset = n + 6, 3
+        program = jax.jit(chunked._train_segment_window, static_argnames=("steps", "n_total"))
+        got, losses = program(st, hp, tx, ty, key, steps=steps, n_total=n_total, offset=jnp.int32(offset))
+    else:
+        rem = jnp.asarray([4, 1, 3, 0, 2][:n], jnp.int32)
+        got, losses = chunked.train_segment_masked(st, hp, tx, ty, key, steps, rem)
+    want = _per_step_reference(trainer, st, hp, tx, ty, key, steps, 2, n_total, offset, rem)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(got.step).tolist() == ([steps] * n if rem is None else rem.tolist())
+    assert losses.shape == (steps,) and np.isfinite(np.asarray(losses)).all()
+
+
+def test_chunked_losses_are_the_mean_over_all_members(setup):
+    """``losses[t]`` is assembled from the chunks' rows: the mean over
+    every member at step ``t``, as the unchunked program takes it."""
+    trainer, data = setup
+    tx, ty = data["train_x"], data["train_y"]
+    st = trainer.init_population(jax.random.key(22), tx[:2], 5)
+    hp = _distinct_hparams(5)
+    _, whole = trainer.train_segment(st, hp, tx, ty, jax.random.key(23), 3)
+    _, chunked = _chunked(trainer).train_segment(st, hp, tx, ty, jax.random.key(23), 3)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(whole), rtol=2e-2)  # bf16 members
+
+
+def test_step_loop_of_a_chunked_segment_never_cuts_the_state(setup):
+    """The structure, read from the compiled program: the loop over
+    steps holds no ``dynamic-slice`` and no ``dynamic-update-slice`` of
+    a state leaf — the population's state is cut and stitched in the
+    chunk loop, once a segment. (At the parent of PR 26 the chunk loop
+    sat inside the step loop and every step did both.)"""
+    import hlo_loops
+
+    trainer, data = setup
+    tx, ty = data["train_x"], data["train_y"]
+    chunked = _chunked(trainer)
+    st = trainer.init_population(jax.random.key(24), tx[:2], 6)
+    steps = 5  # three chunks of two: the two loops differ in trip count
+    text = chunked.train_segment.lower(
+        st, OptHParams.defaults(6), tx, ty, jax.random.key(25), steps
+    ).compile().as_text()
+    loops = hlo_loops.loops(text)  # a loop's body includes the loops nested in it
+    assert steps in [l.trips for l in loops]
+    assert [l.trips for l in loops if hlo_loops.state_cuts(l, st)] == [3]
+
+
 def test_momentum_storage_dtype_knob(setup):
     """momentum_dtype=bfloat16 stores momentum narrow (the bandwidth A/B
     probe's knob) while params stay f32 and training still learns; the
