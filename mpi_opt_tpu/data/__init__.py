@@ -12,6 +12,8 @@ impossible; datasets resolve as:
   (see synthetic.py for the generative recipe). Benchmarks measure
   throughput, which depends on shapes, not pixels; accuracy-style tests
   assert learnability of the synthetic task instead of absolute numbers.
+- ``successor_tokens``: seeded token rows for next-token workloads
+  (tokens.py: a walk over a seeded table of each token's successors).
 
 All loaders return host numpy; device placement is the backend's job
 (one transfer per search, not per trial — that is the point of the

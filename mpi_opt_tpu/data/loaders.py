@@ -5,6 +5,7 @@ from __future__ import annotations
 import numpy as np
 
 from mpi_opt_tpu.data.synthetic import make_image_classification
+from mpi_opt_tpu.data.tokens import make_token_rows
 
 _CACHE: dict = {}
 
@@ -68,6 +69,10 @@ DATASETS = {
     "cifar100": lambda seed=0, n_train=16384, n_val=2048, **kw: make_image_classification(
         n_train, n_val, 32, 32, 3, 100, seed=seed,
         **{"coarse": 6, "noise": 1.2, "delta": 0.3, "label_noise": 0.35, **kw}
+    ),
+    # token rows for next-token workloads (x = a row, y = its next tokens)
+    "successor_tokens": lambda seed=0, n_train=512, n_val=8, positions=8192, vocab=18992: make_token_rows(
+        n_train, n_val, positions, vocab, seed=seed
     ),
 }
 

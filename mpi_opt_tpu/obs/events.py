@@ -157,8 +157,9 @@ SPANS = frozenset(
 #: a fused generation can be split by phase: a reduction books each
 #: device operation to the INNERMOST of these names on its path, and
 #: ``transpose(jvp(member_loss))`` (JAX's own wrapping) is the backward
-#: pass. The benchmark's reduction (benchmarks/scopes.py) keeps a copy;
-#: tests/test_device_scopes.py holds the two and the call sites equal.
+#: pass. The benchmark's reduction (benchmarks/scopes.py) keeps a copy
+#: of the scopes that book a phase; tests/test_device_scopes.py holds
+#: that copy a subset of these, and these equal to the call sites.
 DEVICE_SCOPES = (
     "train_segment",  # a generation's train steps (scan over steps; chunked: the nest)
     "train_input",  # minibatch gather + per-member key splits
@@ -172,6 +173,14 @@ DEVICE_SCOPES = (
     "eval_population",  # validation pass(es) of the population
     "exploit",  # ops/pbt.py truncation + explore
     "gather_members",  # the winners' state copy
+    # inside member_loss / eval_population only, so they book no phase
+    # of their own and the phases stay an exact partition
+    # (models/sparse_moe_decoder.py); forward, backward and evaluation
+    "attention",  # q/k/v/o products, per-head norms, RoPE, attention over the selection
+    "indexer",  # index scorer: its products, index scores, the selection, its loss
+    "router",  # router product, the top experts a token, dispatch and combine
+    "experts",  # the held experts' products
+    "loss_head",  # final norm, the head's logits, cross-entropy
 )
 
 
@@ -203,6 +212,12 @@ SPAN_ATTRS = frozenset(
         "items",  # manifest items (digest)
         "bytes",  # bytes moved (stage_in/stage_out; set at exit)
         "flops",  # segment FLOPs for achieved TF/s (set at exit)
+        # a member's own counts of its work (``member.counters``), carried
+        # out of the train steps that made them: the launch's mean over
+        # member-steps (train; fused PBT's one-program launches)
+        "selected_keys",  # keys a query attends to under the selection
+        "routed_tokens",  # tokens routed to the held experts, a layer
+        "fullest_expert_tokens",  # the most any one held expert was sent in a step
         # provenance
         "op",  # boundary/digest flavor (exploit/rung_cut/suggest/...)
         "objectives",  # MO sweep: comma-joined objective names (train)

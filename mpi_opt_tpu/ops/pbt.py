@@ -39,6 +39,12 @@ class PBTConfig:
     perturb_scale: float = 0.15  # stddev of unit-space Gaussian perturbation
     resample_prob: float = 0.1  # per-discrete-dim chance to resample on explore
 
+    def __post_init__(self):
+        # past a half the bottom cut and the source pool overlap: a loser
+        # would copy a member that is itself replaced
+        if not 0.0 < self.truncation_frac <= 0.5:
+            raise ValueError(f"truncation_frac must be in (0, 0.5], got {self.truncation_frac}")
+
 
 def pbt_exploit_explore(
     key: jax.Array,
@@ -97,7 +103,10 @@ def _exploit_explore(key, unit, scores, discrete_mask, cfg):
     n, d = unit.shape
     k_src, k_noise, k_resample, k_resample_val = jax.random.split(key, 4)
 
-    n_cut = max(1, int(round(n * cfg.truncation_frac)))
+    # never past a half of the population (an odd ``n`` at 0.5 rounds
+    # up): no source is ever replaced, ``src_idx[src_idx] == src_idx``,
+    # which ``PopulationTrainer.exploit_members`` copies in place by
+    n_cut = max(1, min(int(round(n * cfg.truncation_frac)), n // 2))
     rank, order = rank_descending(scores)
 
     bottom = rank >= (n - n_cut)  # losers: exploit

@@ -126,6 +126,11 @@ def run_fused_pbt(
     ledger's ``scores`` vectors) and ``final_mo[P, m]`` (the final
     post-exploit population's objectives, for the winner pick /
     front summary). Scalar calls return the original 9-tuple.
+
+    A member that counts its own work (``trainer.member.counters``)
+    adds one last output to either: ``float32[G, len(counters)]``, each
+    generation's mean over its member-steps, out of the train steps
+    that made them (the ``train`` span's attributes).
     """
     if generations < 1:  # static arg: raises at trace time, not opaquely later
         raise ValueError(f"generations must be >= 1, got {generations}")
@@ -140,7 +145,8 @@ def run_fused_pbt(
         st, u, k = carry
         k, k_train, k_pbt = jax.random.split(k, 3)
         hp = hparams_fn(u)
-        st, _ = trainer.train_segment(st, hp, train_x, train_y, k_train, steps_per_gen)
+        st, reported = trainer.train_segment(st, hp, train_x, train_y, k_train, steps_per_gen)
+        counted = (jnp.mean(reported[1], axis=0),) if trainer.member.counters else ()
         if objectives is not None:
             mo = eval_population_objectives(
                 trainer, st, val_x, val_y, objectives.names
@@ -154,37 +160,37 @@ def run_fused_pbt(
                 cfg,
                 norm_bounds=norm_bounds,
             )
-            st = trainer.gather_members(st, src_idx)
+            st = trainer.exploit_members(st, src_idx)
             # a non-finite value in ANY objective is the member failure
             n_fail = jnp.sum(~jnp.all(jnp.isfinite(mo), axis=-1)).astype(jnp.int32)
             return (st, new_u, k), (
                 scores.max(), scores.mean(), n_fail, scores[src_idx],
                 scores, u, mo, mo[src_idx],
-            )
+            ) + counted
         scores = trainer.eval_population(st, val_x, val_y)
         new_u, src_idx, _ = pbt_exploit_explore(k_pbt, u, scores, disc, cfg)
-        st = trainer.gather_members(st, src_idx)
+        st = trainer.exploit_members(st, src_idx)
         # the post-exploit population's scores are exactly the gathered
         # pre-exploit scores (weights are copied verbatim, eval is
         # deterministic) — so no final re-eval is ever needed
         n_fail = jnp.sum(~jnp.isfinite(scores)).astype(jnp.int32)
         return (st, new_u, k), (
             scores.max(), scores.mean(), n_fail, scores[src_idx], scores, u,
-        )
+        ) + counted
 
     if objectives is not None:
         (state, unit, key), (
-            best, mean, fails, gen_scores, pre_scores, pre_units, pre_mo, gen_mo
+            best, mean, fails, gen_scores, pre_scores, pre_units, pre_mo, gen_mo, *counted
         ) = jax.lax.scan(one_generation, (state, unit, key), jnp.arange(generations))
         return (
             state, unit, key, best, mean, fails, gen_scores[-1],
-            pre_scores, pre_units, pre_mo, gen_mo[-1],
+            pre_scores, pre_units, pre_mo, gen_mo[-1], *counted,
         )
 
-    (state, unit, key), (best, mean, fails, gen_scores, pre_scores, pre_units) = (
+    (state, unit, key), (best, mean, fails, gen_scores, pre_scores, pre_units, *counted) = (
         jax.lax.scan(one_generation, (state, unit, key), jnp.arange(generations))
     )
-    return state, unit, key, best, mean, fails, gen_scores[-1], pre_scores, pre_units
+    return state, unit, key, best, mean, fails, gen_scores[-1], pre_scores, pre_units, *counted
 
 
 @trainer_jit(
@@ -213,7 +219,7 @@ def finish_generation(
     disc = jnp.asarray(discrete_mask, dtype=bool)
     scores = trainer.eval_population(state, val_x, val_y)
     new_u, src_idx, _ = pbt_exploit_explore(key, unit, scores, disc, cfg)
-    state = trainer.gather_members(state, src_idx)
+    state = trainer.exploit_members(state, src_idx)
     n_fail = jnp.sum(~jnp.isfinite(scores)).astype(jnp.int32)
     return (
         state, new_u, scores.max(), scores.mean(), n_fail, scores[src_idx],
@@ -1051,6 +1057,7 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                 # like a real warmup OOM (the staging.py docstring's
                 # pop=1024 death shape) — typed via the funnel above
                 resources.launch_fault("launch")
+                counted = ()  # the members' own counters (one-program launches)
                 if step_chunk > 0:
                     # one generation as k sub-segment launches + a boundary
                     # launch; the carried key advances exactly once per gen
@@ -1073,7 +1080,7 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                     # the MO program journals the raw objective matrix per
                     # generation besides the scalarized curve; selection
                     # already happened on-device via pareto_score
-                    state, unit, k_run, best, mean, fails, final_scores, pre_s, pre_u, pre_mo, final_mo = run_fused_pbt(
+                    state, unit, k_run, best, mean, fails, final_scores, pre_s, pre_u, pre_mo, final_mo, *counted = run_fused_pbt(
                         trainer,
                         state,
                         unit,
@@ -1093,7 +1100,7 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                     # k_run is the scan-carried key returned by the previous
                     # launch: the chain continues exactly as one longer scan
                     # would
-                    state, unit, k_run, best, mean, fails, final_scores, pre_s, pre_u = run_fused_pbt(
+                    state, unit, k_run, best, mean, fails, final_scores, pre_s, pre_u, *counted = run_fused_pbt(
                         trainer,
                         state,
                         unit,
@@ -1122,6 +1129,11 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                 # WITHOUT the attr (no inflated TF/s from partial work)
                 if flops_gen:
                     _sp["flops"] = flops_gen * launch_lens[i]
+                # what the members counted of their own work in this
+                # launch's train steps
+                if counted:
+                    means = fetch_global(counted[0]).mean(axis=0)
+                    _sp.update(zip(trainer.member.counters, map(float, means)))
                 # post-barrier device-memory watermark (obs/memory.py):
                 # resident population + activations just peaked
                 memory.note(_sp)

@@ -129,6 +129,56 @@ def _augment(key: jax.Array, x: jax.Array, flip_prob: jax.Array, shift: jax.Arra
         return jnp.roll(x, (dy, dx), axis=(1, 2))
 
 
+class ClassifierMember:
+    """What ONE member computes, for the image (or tabular) classifier:
+    its loss on a minibatch and its score on the validation rows. The
+    trainer owns everything around it (the loop nest, the optimizer,
+    the scopes ``member_loss`` and ``eval_population``); a workload
+    whose member is something else hands the trainer another object
+    with these names (``workloads/language.py``).
+
+    ``loss(params, hp, key, bx, by)``: float32 scalar, ``hp`` the
+    member's hyperparameters as scalars, ``key`` its own key this step.
+    ``counters``: names of what the member counts of its own work in a
+    step (a decoder's selected keys, its routed tokens); where there
+    are any, ``loss`` returns ``(loss, float32[len(counters)])`` and the
+    train segment hands their mean over the members out beside the
+    losses, for the ``train`` span (each name is a span attribute).
+    ``score_sum(params, cx, cy)``: a chunk of validation rows' part of
+    the score, added up over chunks in ``score_dtype`` (rows padded onto
+    the last chunk have labels < 0 and add nothing); ``score(total,
+    n_val)``: the member's score, higher is better. ``eval_chunk`` is
+    the validation rows a chunk holds. ``single_unbatched``: a cohort of
+    ONE member is called without a batch axis (for a member that
+    branches on its own values: under a batch axis a ``lax.cond`` runs
+    both branches).
+    """
+
+    eval_chunk = 1024
+    score_dtype = jnp.int32
+    single_unbatched = False
+    counters = ()
+
+    def __init__(self, apply_fn: Callable, augment: bool):
+        self.apply_fn = apply_fn
+        self.augment = augment
+
+    def loss(self, params, hp, key, bx, by):
+        if self.augment and bx.ndim == 4:
+            bx = _augment(key, bx, hp.flip_prob, hp.shift)
+        logits = self.apply_fn(params, bx)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        return -jnp.mean(jnp.take_along_axis(logp, by[:, None], axis=1))
+
+    def score_sum(self, params, cx, cy):
+        logits = self.apply_fn(params, cx)
+        pred = jnp.argmax(logits, axis=-1)
+        return jnp.sum((pred == cy) & (cy >= 0))
+
+    def score(self, total, n_val: int):
+        return total.astype(jnp.float32) / n_val
+
+
 def _where_members(mask: jax.Array, a, b):
     """Per-member choice between two population pytrees: member m of
     the result is ``a``'s where ``mask[m]``, else ``b``'s."""
@@ -144,10 +194,13 @@ class PopulationTrainer:
 
     Args:
         apply_fn: ``apply(params, x) -> logits`` (flax ``Module.apply``
-            partial'd over everything but params and inputs).
+            partial'd over everything but params and inputs); the
+            classifier's, unused where ``member`` is given.
         init_fn: ``init(rng, sample_x) -> params``.
         batch_size: per-step minibatch size (shared across members).
         augment: whether image augmentation applies (False for tabular).
+        member: what one member computes (``ClassifierMember``'s five
+            names); default the classifier around ``apply_fn``.
         member_chunk: if >0, at most this many members' activations
             live at once (activation-memory relief for big
             populations; params/momentum still resident for all
@@ -184,11 +237,13 @@ class PopulationTrainer:
         donate: bool = False,
         mesh=None,
         momentum_dtype=None,
+        member=None,
     ):
         self.apply_fn = apply_fn
         self.init_fn = init_fn
         self.batch_size = batch_size
         self.augment = augment
+        self.member = member if member is not None else ClassifierMember(apply_fn, augment)
         self.member_chunk = member_chunk
         self.donate = donate
         self.mesh = mesh
@@ -244,14 +299,13 @@ class PopulationTrainer:
         # the one scope that splits forward from backward in a device
         # trace: JAX names the backward ops transpose(jvp(member_loss))
         with jax.named_scope("member_loss"):
-            if self.augment and bx.ndim == 4:
-                bx = _augment(key, bx, hp.flip_prob, hp.shift)
-            logits = self.apply_fn(params, bx)
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-            return -jnp.mean(jnp.take_along_axis(logp, by[:, None], axis=1))
+            return self.member.loss(params, hp, key, bx, by)
 
     def _member_update(self, params, momentum, step, hp: OptHParams, key, bx, by):
-        loss, grads = jax.value_and_grad(self._member_loss)(params, hp, key, bx, by)
+        # ``loss`` is the member's (loss, counters) where it counts its work
+        loss, grads = jax.value_and_grad(self._member_loss, has_aux=bool(self.member.counters))(
+            params, hp, key, bx, by
+        )
         # SGD + momentum + coupled L2 weight decay (wd*p folded into the
         # gradient, so the effective decay is lr-scaled), hparams as
         # traced scalars. Math in f32 regardless of the momentum STORAGE
@@ -310,6 +364,8 @@ class PopulationTrainer:
             n = jax.tree.leaves(xs)[0].shape[0]
             per_device = self._per_device_chunks(n)
             if per_device is None:
+                if chunk == 1 and self.member.single_unbatched:
+                    return jax.lax.map(fn, xs)
                 return jax.lax.map(fn, xs, batch_size=chunk)
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -331,7 +387,12 @@ class PopulationTrainer:
         """One step of these members (the population or one chunk of it)
         on a shared batch: ``(state, loss[members])``."""
         fn = lambda *a: self._member_update(*a, bx, by)
-        p, m, s, loss = jax.vmap(fn)(state.params, state.momentum, state.step, hp, keys)
+        args = (state.params, state.momentum, state.step, hp, keys)
+        if state.step.shape[0] == 1 and self.member.single_unbatched:
+            out = fn(*jax.tree.map(lambda a: a[0], args))
+            p, m, s, loss = jax.tree.map(lambda a: a[None], out)
+        else:
+            p, m, s, loss = jax.vmap(fn)(*args)
         return PopState(params=p, momentum=m, step=s), loss
 
     def _train_input(self, k, train_x, train_y, n: int, window=None):
@@ -423,7 +484,10 @@ class PopulationTrainer:
 
     def _train_nest(self, state, hp, train_x, train_y, key, steps, window=None, rem=None):
         """The loop nest of the three train-segment programs:
-        ``(state, mean losses [steps])``. They differ in ``window`` (the
+        ``(state, mean losses [steps])``; where the member counts its
+        own work (``member.counters``) the second is ``(mean losses
+        [steps], mean counters [steps, len(counters)])``, every mean
+        over the members. They differ in ``window`` (the
         wave form's ``(n_total, offset)`` of ``_train_input``) and in
         ``rem`` (the masked form's int32[P] budgets) alone.
 
@@ -450,6 +514,14 @@ class PopulationTrainer:
             else functools.partial(jax.named_scope, "map_members")
         )
 
+        def masked(active, loss):
+            """What a step reports (the losses [members]; with them the
+            counters [members, c] where the member has any), nought for
+            the members past their budget."""
+            return jax.tree.map(
+                lambda a: jnp.where(jnp.expand_dims(active, tuple(range(1, a.ndim))), a, 0.0), loss
+            )
+
         def run(st, hp, rem, pick):
             """``steps`` steps of the members of ``st``; ``pick`` takes
             their rows of the whole state's per-step keys. A member
@@ -466,8 +538,10 @@ class PopulationTrainer:
                 if rem is not None:
                     active = t < rem  # bool[members]
                     new_st = _where_members(active, new_st, st)
-                    loss = jnp.where(active, loss, 0.0)
-                return (new_st, k), (loss if chunks else jnp.mean(loss))
+                    loss = masked(active, loss)
+                if not chunks:
+                    loss = jax.tree.map(lambda a: jnp.mean(a, axis=0), loss)
+                return (new_st, k), loss
 
             (st, _), losses = jax.lax.scan(one_step, (st, key), jnp.arange(steps))
             return st, losses
@@ -484,16 +558,46 @@ class PopulationTrainer:
                     pick = lambda keys: jax.random.wrap_key_data(
                         take(view(jax.random.key_data(keys)))
                     )
+                    if in_place:
+                        return steps_in_place(st, j, take, pick)
                     piece, losses = run(*jax.tree.map(take, (st, hp_v, rem_v)), pick)
                     return jax.tree.map(lambda a, p: put(a, p, j), st, piece), losses
 
+                # A member walked alone and without a batch axis is one
+                # too large to hold twice (``single_unbatched``): cutting
+                # it out for a segment would be a second copy of its
+                # parameters and momentum beside the population's. Its
+                # steps read and write its row of the state itself, cut
+                # and put back inside every step, where the slices fuse
+                # into the step's own reads and its update (the bytes of
+                # a cut a step are nothing beside such a member's step).
+                in_place = chunk == 1 and self.member.single_unbatched
+
+                def steps_in_place(st, j, take, pick):
+                    hp_j, rem_j = jax.tree.map(take, (hp_v, rem_v))
+
+                    def one_step(carry, t):
+                        st, k = carry
+                        k, member_keys, bx, by = self._train_input(k, train_x, train_y, n, window)
+                        piece = jax.tree.map(take, st)
+                        new, loss = self._pop_update(piece, hp_j, pick(member_keys), bx, by)
+                        if rem_j is not None:
+                            active = t < rem_j
+                            new = _where_members(active, new, piece)
+                            loss = masked(active, loss)
+                        return (jax.tree.map(lambda a, p: put(a, p, j), st, new), k), loss
+
+                    (st, _), losses = jax.lax.scan(one_step, (st, key), jnp.arange(steps))
+                    return st, losses
+
                 st, losses = jax.lax.scan(one_chunk, jax.tree.map(view, state), jnp.arange(k))
-                total = jnp.sum(losses, axis=(0, 2))  # [k, steps, members] -> [steps]
-                tail = n - k * losses.shape[2]
+                # [k, steps, members, ...] -> [steps, ...]
+                total = jax.tree.map(lambda a: jnp.sum(a, axis=(0, 2)), losses)
+                tail = n - k * jax.tree.leaves(losses)[0].shape[2]
                 if tail:
                     st, losses = one_chunk(st, k, tail)
-                    total = total + jnp.sum(losses, axis=1)
-                return jax.tree.map(unview, st), total / n
+                    total = jax.tree.map(lambda t, a: t + jnp.sum(a, axis=1), total, losses)
+                return jax.tree.map(unview, st), jax.tree.map(lambda t: t / n, total)
 
     def _train_segment(
         self,
@@ -570,40 +674,40 @@ class PopulationTrainer:
 
     @trainer_jit(static_argnames=("eval_chunk",))
     def eval_population(
-        self, state: PopState, val_x: jax.Array, val_y: jax.Array, eval_chunk: int = 1024
+        self, state: PopState, val_x: jax.Array, val_y: jax.Array, eval_chunk: int | None = None
     ) -> jax.Array:
-        """Validation accuracy per member: float32[P].
+        """The member's score on the validation rows (the classifier's:
+        accuracy), per member: float32[P], higher is better.
 
-        Scans the val set in fixed chunks so activation memory stays
+        Scans the val set in fixed chunks (``eval_chunk`` rows; default
+        the member's own) so activation memory stays
         O(P * eval_chunk) regardless of val-set size; with
         ``member_chunk`` set, members are additionally lax.map'ed in
         chunks, bounding activations at O(member_chunk * eval_chunk) —
         ResNet-scale populations OOM the forward pass without this. The
         tail chunk is masked, not dropped.
         """
+        member = self.member
+        eval_chunk = eval_chunk or member.eval_chunk
         with jax.named_scope("eval_population"):
             n_val = val_x.shape[0]
             n_chunks = -(-n_val // eval_chunk)
             pad = n_chunks * eval_chunk - n_val
             vx = jnp.pad(val_x, [(0, pad)] + [(0, 0)] * (val_x.ndim - 1))
-            vy = jnp.pad(val_y, (0, pad), constant_values=-1)
+            vy = jnp.pad(val_y, [(0, pad)] + [(0, 0)] * (val_y.ndim - 1), constant_values=-1)
             vx = vx.reshape((n_chunks, eval_chunk) + val_x.shape[1:])
-            vy = vy.reshape((n_chunks, eval_chunk))
-
-            def member_correct(params, cx, cy):
-                logits = self.apply_fn(params, cx)
-                pred = jnp.argmax(logits, axis=-1)
-                return jnp.sum((pred == cy) & (cy >= 0))
+            vy = vy.reshape((n_chunks, eval_chunk) + val_y.shape[1:])
 
             def chunk_step(acc, chunk):
                 cx, cy = chunk
                 cx, cy = self._constrain_data(cx, cy)
-                corr = self._map_members(lambda p: member_correct(p, cx, cy), state.params)
-                acc = acc + corr
+                part = self._map_members(lambda p: member.score_sum(p, cx, cy), state.params)
+                acc = acc + part
                 return acc, None
 
-            correct, _ = jax.lax.scan(chunk_step, jnp.zeros((state.step.shape[0],), jnp.int32), (vx, vy))
-            return correct.astype(jnp.float32) / n_val
+            zero = jnp.zeros((state.step.shape[0],), member.score_dtype)
+            total, _ = jax.lax.scan(chunk_step, zero, (vx, vy))
+            return member.score(total, n_val)
 
     # -- multi-objective member metrics (ISSUE 17) ------------------------
 
@@ -657,6 +761,38 @@ class PopulationTrainer:
         """
         with jax.named_scope("gather_members"):
             return jax.tree.map(lambda x: x[src_idx], state)
+
+    #: a member whose parameters and momentum reach this many bytes is
+    #: copied row by row at an exploit (``exploit_members``)
+    ROW_COPY_BYTES = 1 << 30
+
+    @staticmethod
+    def exploit_members(state: PopState, src_idx: jax.Array) -> PopState:
+        """``gather_members`` for an EXPLOIT's source map, in which every
+        source keeps itself (``src_idx[src_idx[i]] == src_idx[i]``: the
+        losers copy winners, the winners stay; ``ops/pbt.py`` cuts at
+        most half the population, so its maps all are). Small members are
+        gathered as ever. A gather writes a second population before
+        the first is free, which a population of gigabyte members has
+        no room for: there the replaced rows are copied one at a time
+        into the state itself (a ``dynamic_update_slice`` on the loop's
+        carry, no second copy), which is the same result because no
+        source row is ever overwritten.
+        """
+        n = state.step.shape[0]
+        leaves = jax.tree.leaves((state.params, state.momentum))
+        if sum(x.size * x.dtype.itemsize for x in leaves) < n * PopulationTrainer.ROW_COPY_BYTES:
+            return PopulationTrainer.gather_members(state, src_idx)
+        with jax.named_scope("gather_members"):
+
+            def copy_row(i, st):
+                src = src_idx[i]
+                take = lambda x: jax.lax.dynamic_update_index_in_dim(
+                    x, jax.lax.dynamic_index_in_dim(x, src, 0, keepdims=False), i, 0
+                )
+                return jax.lax.cond(src == i, lambda s: s, lambda s: jax.tree.map(take, s), st)
+
+            return jax.lax.fori_loop(0, n, copy_row, state)
 
     @staticmethod
     @jax.jit

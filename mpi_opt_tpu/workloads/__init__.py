@@ -35,7 +35,7 @@ def available() -> list[str]:
 
 
 # import for registration side effects (chaos last: it wraps the others)
-from mpi_opt_tpu.workloads import digits, synthetic, tabular, vision  # noqa: E402,F401
+from mpi_opt_tpu.workloads import digits, language, synthetic, tabular, vision  # noqa: E402,F401
 from mpi_opt_tpu.workloads import chaos  # noqa: E402,F401
 
 __all__ = ["Workload", "register", "get_workload", "available"]

@@ -155,3 +155,23 @@ def test_smallcnn_member_chunk_train_step_compiles_and_fits(on_chip, key_on_chip
         + ma.temp_size_in_bytes - ma.alias_size_in_bytes
     )
     assert live < 16 * 2**30  # one v5e chip's HBM
+
+
+def test_selected_attention_compiles_to_kernels_at_the_decoders_widths(on_chip):
+    """The last query tile of an 8192-token row at the token member's
+    published widths (32 query heads over 4 key/value heads of 128, 512
+    queries against all 8192 keys, one run-time mask for all heads):
+    the forward, dq and dkv kernels of ops/selected_attention.py."""
+    from mpi_opt_tpu.ops.selected_attention import masked_attention
+
+    heads, kv_heads, rows, keys, d = 32, 4, 512, 8192, 128
+
+    def loss(q, k, v, mask):
+        out, lse = masked_attention(q, k, v, mask, 512)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(jax.lax.stop_gradient(lse))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip((heads, rows, d), jnp.bfloat16), on_chip((kv_heads, keys, d), jnp.bfloat16),
+        on_chip((kv_heads, keys, d), jnp.bfloat16), on_chip((rows, keys), jnp.bool_),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

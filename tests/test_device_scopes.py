@@ -34,17 +34,28 @@ def _tiny_cnn():
     return wl
 
 
-@pytest.fixture(scope="module")
-def op_names():
+def _tiny_decoder():
+    """The token member at its configuration's rehearse sizes."""
+    import json
+
+    from mpi_opt_tpu.workloads import get_workload
+
+    with open(os.path.join(REPO_ROOT, "benchmarks", "configs", "keye_vl2_30b_a3b.json")) as f:
+        attrs = json.load(f)["rehearse"]["workload_attrs"]
+    wl = get_workload("keye_vl2_30b_a3b")
+    for name, value in attrs.items():
+        setattr(wl, name, value)
+    return wl
+
+
+def _fused_pbt_op_names(wl, member_chunk):
     """Every ``op_name`` of the compiled fused PBT program at a tiny
-    size (SmallCNN, 4 members in chunks of 2, one generation of 2
-    steps), and its path components' core names."""
+    size (4 members, one generation of 2 steps)."""
     from mpi_opt_tpu.ops.pbt import PBTConfig
     from mpi_opt_tpu.train.common import HParamsFn, workload_arrays
     from mpi_opt_tpu.train.fused_pbt import run_fused_pbt
 
-    wl = _tiny_cnn()
-    trainer, space, tx, ty, vx, vy = workload_arrays(wl, member_chunk=2)
+    trainer, space, tx, ty, vx, vy = workload_arrays(wl, member_chunk=member_chunk)
     state = trainer.init_population(jax.random.key(1), tx[:2], 4)
     compiled = run_fused_pbt.program(trainer).lower(
         state, space.sample_unit(jax.random.key(0), 4), HParamsFn(space, wl),
@@ -55,15 +66,66 @@ def op_names():
     return sorted(set(re.findall(r'op_name="([^"]+)"', compiled.as_text())))
 
 
+@pytest.fixture(scope="module")
+def op_names():
+    """SmallCNN, 4 members in chunks of 2."""
+    return _fused_pbt_op_names(_tiny_cnn(), 2)
+
+
+@pytest.fixture(scope="module")
+def decoder_op_names():
+    """The token member, one member at a time."""
+    return _fused_pbt_op_names(_tiny_decoder(), 1)
+
+
+#: the scopes a member of its own opens inside ``member_loss`` and
+#: ``eval_population`` (models/sparse_moe_decoder.py): they book no phase
+MEMBER_SCOPES = ("attention", "indexer", "router", "experts", "loss_head")
+
+
 def test_benchmark_keeps_the_same_scope_names():
-    assert tuple(scopes.SCOPES) == tuple(DEVICE_SCOPES)
-    assert set(scopes.PHASE_OF_SCOPE) | {"map_members"} == set(DEVICE_SCOPES)
+    """The contract PERF.md section 7 states: every scope the
+    benchmark's reduction books a phase by is a scope of the program;
+    the program's other scopes are the members' own."""
+    assert set(scopes.SCOPES) <= set(DEVICE_SCOPES)
+    assert set(scopes.PHASE_OF_SCOPE) | {"map_members"} == set(scopes.SCOPES)
+    assert set(DEVICE_SCOPES) - set(scopes.SCOPES) == set(MEMBER_SCOPES)
+    # the benchmark's own half (benchmarks/tests/test_scopes.py), kept in tier-1 too
+    assert set(scopes.PHASE_OF_SCOPE.values()) | {"backward", "unscoped"} == set(scopes.PHASES)
 
 
-@pytest.mark.parametrize("scope", DEVICE_SCOPES)
-def test_compiled_program_carries_every_scope(op_names, scope):
+def test_a_members_own_scopes_are_opened_inside_a_phase_scope_only(decoder_op_names):
+    """Every operation under one of the member's scopes is also under
+    ``member_loss`` or ``eval_population``, further out on its path: the
+    innermost LISTED scope books the phase, so the phases stay an exact
+    partition of the busy time."""
+    inside = 0
+    for name in decoder_op_names:
+        if not name.startswith("jit("):
+            continue  # a reducer's own computation (its add, its max): no device operation
+        cores = [scopes._core(c) for c in name.split("/")]
+        for i, core in enumerate(cores):
+            if core in MEMBER_SCOPES:
+                assert {"member_loss", "eval_population"} & set(cores[:i]), name
+                assert scopes.phase_of(name) in ("forward", "backward", "eval"), name
+                inside += 1
+    assert inside > 100
+
+
+@pytest.mark.parametrize(
+    "program,scope",
+    [("cnn", s) for s in scopes.SCOPES]
+    + [("decoder", s) for s in DEVICE_SCOPES if s != "augment"],
+)
+def test_compiled_program_carries_every_scope(op_names, decoder_op_names, program, scope):
+    names = op_names if program == "cnn" else decoder_op_names
+    cores = {scopes._core(c) for name in names for c in name.split("/")}
+    assert scope in cores, f"no op_name of the {program}'s fused PBT program carries {scope!r}"
+
+
+def test_the_cnn_program_carries_no_scope_of_another_member(op_names):
     cores = {scopes._core(c) for name in op_names for c in name.split("/")}
-    assert scope in cores, f"no op_name of the fused PBT program carries {scope!r}"
+    assert not cores & set(MEMBER_SCOPES)
 
 
 def test_backward_convolution_is_named_by_jax(op_names):

@@ -78,6 +78,46 @@ def test_gather_members_copies_state(setup):
     np.testing.assert_allclose(p0[2], orig[2])
 
 
+@pytest.mark.parametrize("row_copy", [False, True], ids=["gathered", "row by row"])
+def test_exploit_members_is_the_gather_of_an_exploits_map(setup, monkeypatch, row_copy):
+    """Losers copy winners, winners keep themselves: small members are
+    gathered as ever, members of a gigabyte are copied row by row into
+    the state itself; both are ``gather_members``'s result."""
+    trainer, data = setup
+    st = trainer.init_population(jax.random.key(8), data["train_x"][:2], 5)
+    st = st.replace(momentum=jax.tree.map(lambda p: p * 2.0, st.params), step=jnp.arange(5, dtype=jnp.int32))
+    src_idx = jnp.array([0, 3, 2, 3, 0])  # members 1 and 4 are replaced
+    if row_copy:
+        monkeypatch.setattr(PopulationTrainer, "ROW_COPY_BYTES", 0)
+    exploit = jax.jit(lambda s, i: trainer.exploit_members(s, i))  # traced anew under the patch
+    assert ("dynamic_update_slice" in exploit.lower(st, src_idx).as_text()) == row_copy
+    got = exploit(st, src_idx)
+    want = trainer.gather_members(st, src_idx)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [3, 4, 7, 32])
+def test_an_exploits_sources_are_never_replaced(n):
+    """What ``exploit_members`` copies in place by: the exploit cuts at
+    most half the population, whatever the fraction asked for, so every
+    source row keeps itself (at 0.5, three members round to a cut of
+    two of three: top and bottom would share the middle one)."""
+    from mpi_opt_tpu.ops.pbt import PBTConfig, pbt_exploit_explore
+
+    for seed in range(4):
+        scores = jax.random.normal(jax.random.key(seed), (n,))
+        _, src, bottom = pbt_exploit_explore(
+            jax.random.key(100 + seed), jnp.zeros((n, 2)), scores, jnp.zeros((2,), bool), PBTConfig(truncation_frac=0.5)
+        )
+        src, bottom = np.asarray(src), np.asarray(bottom)
+        np.testing.assert_array_equal(src[src], src)
+        assert bottom.sum() == n // 2 and not bottom[src].any()
+    for frac in (0.0, 0.75, 1.0):
+        with pytest.raises(ValueError, match="truncation_frac"):
+            PBTConfig(truncation_frac=frac)
+
+
 def test_select_members_mixes_fresh_and_existing(setup):
     trainer, data = setup
     a = trainer.init_population(jax.random.key(6), data["train_x"][:2], 4)
@@ -312,3 +352,47 @@ def test_masked_segment_freezes_members_at_their_budget(setup):
     k1 = next(l for l in jax.tree.leaves(out.params) if l.ndim >= 3)
     assert not np.allclose(np.asarray(k0[1]), np.asarray(k1[1]))
     assert not np.allclose(np.asarray(k0[2]), np.asarray(k1[2]))
+
+
+# -- the image classifier is one member among others: a digest of the parent's --
+
+# two steps of four members in chunks of two (n_train 64, n_val 32, batch
+# 8; init key 3, unit key 4, train key 5) and their evaluation, recorded
+# on commit eb135a3 (PR 27), before the trainer took its member from the
+# workload (PR 28)
+PARENT_DIGEST = {
+    "cifar10_cnn": {
+        "kwargs": {},
+        "param_sq_norm": 15483.715519129535,
+        "momentum_sq_norm": 78869.0256225158,
+        "losses": [2.556962013244629, 49.37770080566406],
+        "scores": [0.09375, 0.0625, 0.09375, 0.03125],
+    },
+    "cifar100_resnet18": {
+        "kwargs": {"width": 8},
+        "param_sq_norm": 5286.809479172202,
+        "momentum_sq_norm": 486.21161993276115,
+        "losses": [5.024986743927002, 5.368114471435547],
+        "scores": [0.03125, 0.03125, 0.03125, 0.0],
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_DIGEST))
+def test_image_workloads_reach_the_parents_state(name):
+    from mpi_opt_tpu.train.common import workload_arrays
+    from mpi_opt_tpu.workloads import get_workload
+
+    want = PARENT_DIGEST[name]
+    wl = get_workload(name, n_train=64, n_val=32, **want["kwargs"])
+    wl.batch_size = 8
+    trainer, space, tx, ty, vx, vy = workload_arrays(wl, member_chunk=2)
+    state = trainer.init_population(jax.random.key(3), tx[:2], 4)
+    hp = wl.make_hparams(space.from_unit(space.sample_unit(jax.random.key(4), 4)))
+    state, losses = trainer.train_segment(state, hp, tx, ty, jax.random.key(5), 2)
+    scores = trainer.eval_population(state, vx, vy)
+    sq = lambda tree: sum(float(np.sum(np.square(np.asarray(l, np.float64)))) for l in jax.tree.leaves(tree))
+    assert sq(state.params) == pytest.approx(want["param_sq_norm"], rel=1e-9)
+    assert sq(state.momentum) == pytest.approx(want["momentum_sq_norm"], rel=1e-9)
+    np.testing.assert_array_equal(np.asarray(losses), np.float32(want["losses"]))
+    np.testing.assert_array_equal(np.asarray(scores), np.float32(want["scores"]))
