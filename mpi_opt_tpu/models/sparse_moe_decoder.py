@@ -31,13 +31,20 @@ For text tokens the three M-RoPE sections carry the same position, so
 the rotation is plain RoPE (half-split pairing over all dims).
 
 How it is computed. Query tiles of ``q_chunk`` rows, each against the
-keys up to its last row only (the causal half is never formed), each
-tile recomputed in the backward pass (``jax.checkpoint``) so that
-scores are never stored. The selection is a threshold mask: the k-th
-largest index score of a row is found exactly by a bitwise bisection
-over the float's order-preserving integer image (32 counting passes
-over the tile; no sort, no gather), and ``I >= threshold`` is the set —
-where index scores tie at the threshold it holds every tied key. On a
+keys up to its last row only (the causal half is never formed). A
+layer is rematerialised (``nn.remat``) and so is every tile inside it
+(``jax.checkpoint``), so that no score is ever stored; both save, by
+name, the three values that cost most to make again: the tile's
+selection mask and, on the kernel path, the forward kernel's context
+and log-sum-exp (``saved_for_backward``). So the selection and the
+forward kernel run once a train step, the backward pass makes only
+the index scores and the probabilities again, and a member-step holds
+``saved_residual_mib`` from its forward to its backward. The selection
+is a threshold mask: the k-th largest index score of a row is found
+exactly by a bitwise bisection over the float's order-preserving
+integer image (32 counting passes over the tile; no sort, no gather),
+and ``I >= threshold`` is the set — where index scores tie at the
+threshold it holds every tied key. On a
 TPU the attention over that mask runs as kernels
 (ops/selected_attention.py: no score leaves the core; the head-mean
 probabilities the indexer's loss needs are rebuilt from the kernels'
@@ -59,6 +66,7 @@ import math
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 F32 = jnp.float32
 # operands of every product and the activations between them. Tests set
@@ -157,6 +165,19 @@ def select_keys(index_scores, first_row: int, top_k: int):
 
 # -- one query tile of the sparse attention ----------------------------------------
 
+# the tile's selection mask, for a remat policy to save
+SELECTION = "attention_selection"
+
+
+def saved_for_backward():
+    """The policy of both remats (the layer's, the query tile's): save
+    the selection mask and the forward kernel's context and log-sum-exp
+    (on XLA's own path only the mask exists), make everything else
+    again."""
+    from mpi_opt_tpu.ops.selected_attention import RESIDUALS
+
+    return jax.checkpoint_policies.save_only_these_names(SELECTION, RESIDUALS)
+
 
 def use_kernels(dims: DecoderDims, positions: int) -> bool:
     """Whether the attention over the selection runs as TPU kernels
@@ -175,13 +196,15 @@ def _attention_tile(qt, k, v, qi, ki, w, first_row: int, dims: DecoderDims, inde
     """Queries ``first_row ..`` against keys ``0 .. K-1`` (``K`` = one
     past the tile's last row): (context [R, kv, group, D], the
     tile's sum over rows of KL(pbar || softmax over S of I), the
-    selected keys counted)."""
+    selected keys counted, the bytes of the values named for
+    ``saved_for_backward``)."""
     scale = 1.0 / math.sqrt(dims.head_dim)
     with jax.named_scope("indexer"):
         # I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])
         dots = _dot("qjd,kd->jqk", qi, ki)
         scores_i = jnp.sum(jax.nn.relu(dots) * jnp.transpose(w)[:, :, None], axis=0)
-        sel = select_keys(scores_i, first_row, dims.top_k_keys)
+        sel = checkpoint_name(select_keys(scores_i, first_row, dims.top_k_keys), SELECTION)
+    held = sel.nbytes
     if kernels:
         from mpi_opt_tpu.ops.selected_attention import masked_attention
 
@@ -189,7 +212,8 @@ def _attention_tile(qt, k, v, qi, ki, w, first_row: int, dims: DecoderDims, inde
             r, kv, group, d = qt.shape
             qh = jnp.transpose((qt * scale).astype(qt.dtype).reshape(r, kv * group, d), (1, 0, 2))
             kh, vh = jnp.transpose(k, (1, 0, 2)), jnp.transpose(v, (1, 0, 2))
-            out, lse = masked_attention(qh, kh, vh, sel, min(dims.q_chunk, r))
+            out, lse = masked_attention(qh, kh, vh, sel, min(dims.q_chunk, r))  # named there
+            held += out.nbytes + lse.nbytes
             ctx = jnp.transpose(out, (1, 0, 2)).reshape(r, kv, group, d).astype(COMPUTE_DTYPE)
         if index_loss:
             with jax.named_scope("indexer"):
@@ -209,35 +233,39 @@ def _attention_tile(qt, k, v, qi, ki, w, first_row: int, dims: DecoderDims, inde
             pbar = jax.lax.stop_gradient(jnp.mean(p, axis=(0, 1)))
     with jax.named_scope("indexer"):
         n_sel = jnp.sum(sel, dtype=jnp.int32)
+        held = jnp.asarray(held, F32)
         if not index_loss:
-            return ctx, jnp.zeros((), F32), n_sel
+            return ctx, jnp.zeros((), F32), n_sel, held
         logq = jax.nn.log_softmax(jnp.where(sel, scores_i, -jnp.inf), axis=-1)
         kl = jnp.sum(jax.scipy.special.xlogy(pbar, pbar)) - jnp.sum(
             jnp.where(sel, pbar * logq, 0.0)
         )
-        return ctx, kl, n_sel
+        return ctx, kl, n_sel, held
 
 
 def sparse_attention(q, k, v, qi, ki, w, dims: DecoderDims, index_loss: bool):
     """(context [T, heads * D], sum over rows of the indexer's KL,
-    selected keys counted) of one row's layer. Tiles of ``q_chunk``
-    queries, each recomputed in the backward pass."""
+    selected keys counted, bytes saved for the backward pass) of one
+    row's layer. Tiles of ``q_chunk`` queries, each made again in the
+    backward pass but for what ``saved_for_backward`` names."""
     t = q.shape[0]
     step = min(dims.q_chunk, t)
     kernels = use_kernels(dims, t)
-    ctxs, kl, n_sel = [], jnp.zeros((), F32), jnp.zeros((), jnp.int32)
+    ctxs, kl, n_sel, held = [], jnp.zeros((), F32), jnp.zeros((), jnp.int32), jnp.zeros((), F32)
+    policy = saved_for_backward()
     for lo in range(0, t, step):
         hi = min(t, lo + step)
         tile = jax.checkpoint(
             lambda *a, lo=lo: _attention_tile(
                 *a, first_row=lo, dims=dims, index_loss=index_loss, kernels=kernels
-            )
+            ),
+            policy=policy,
         )
-        c, kl_t, n_t = tile(q[lo:hi], k[:hi], v[:hi], qi[lo:hi], ki[:hi], w[lo:hi])
+        c, kl_t, n_t, held_t = tile(q[lo:hi], k[:hi], v[:hi], qi[lo:hi], ki[:hi], w[lo:hi])
         ctxs.append(c)
-        kl, n_sel = kl + kl_t, n_sel + n_t
+        kl, n_sel, held = kl + kl_t, n_sel + n_t, held + held_t
     ctx = jnp.concatenate(ctxs, axis=0) if len(ctxs) > 1 else ctxs[0]
-    return ctx.reshape(t, dims.heads * dims.head_dim), kl, n_sel
+    return ctx.reshape(t, dims.heads * dims.head_dim), kl, n_sel, held
 
 
 # -- the held experts ----------------------------------------------------------------
@@ -331,8 +359,9 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         """x [T, d] -> (x2, the layer's indexer loss L_I,
-        int32[3] counts: selected keys, tokens routed to held experts,
-        the fullest held expert's tokens)."""
+        float32[4] counts: selected keys, tokens routed to held experts,
+        the fullest held expert's tokens, bytes saved by name for the
+        backward pass)."""
         m = self.dims
         d, t = m.hidden, x.shape[0]
         group = m.heads // m.kv_heads
@@ -369,7 +398,7 @@ class DecoderLayer(nn.Module):
             qi = rope(qi, pos, m.rope_theta).astype(COMPUTE_DTYPE)
             ki = rope(_dot("td,de->te", hi, wki), pos, m.rope_theta).astype(COMPUTE_DTYPE)
             w = _dot("td,dj->tj", hi, wwi)
-        ctx, kl, n_sel = sparse_attention(q, k, v, qi, ki, w, m, self.index_loss)
+        ctx, kl, n_sel, held = sparse_attention(q, k, v, qi, ki, w, m, self.index_loss)
         with jax.named_scope("attention"):
             x1 = x + _dot("te,ed->td", ctx, wo).astype(x.dtype)
         with jax.named_scope("router"):
@@ -378,14 +407,14 @@ class DecoderLayer(nn.Module):
             load = jnp.sum(gates > 0.0, axis=0, dtype=jnp.int32)
         y = held_experts(h2, gates, wg, wu, wd, m)
         x2 = x1 + y.astype(x.dtype)
-        counts = jnp.stack([n_sel, jnp.sum(load), jnp.max(load)])
+        counts = jnp.stack([n_sel.astype(F32), jnp.sum(load).astype(F32), jnp.max(load).astype(F32), held])
         return x2, kl / t, counts
 
 
 class SparseMoEDecoder(nn.Module):
     """One row of tokens -> (sum over positions of the next-token
     cross-entropy over the held vocabulary slice, sum over layers of the
-    indexer's loss, int32 [layers, 3] counts)."""
+    indexer's loss, float32 [layers, 4] counts)."""
 
     dims: DecoderDims
     index_loss: bool = True  # evaluation needs the selection, not its loss
@@ -395,7 +424,7 @@ class SparseMoEDecoder(nn.Module):
         m = self.dims
         table = self.param("embed", _embed_init, (m.vocab, m.hidden), F32)
         x = table[tokens].astype(COMPUTE_DTYPE)
-        layer = nn.remat(DecoderLayer)
+        layer = nn.remat(DecoderLayer, policy=saved_for_backward())
         index_loss, counts = jnp.zeros((), F32), []
         for i in range(m.layers):
             x, kl, c = layer(m, self.index_loss, name=f"layer_{i}")(x)

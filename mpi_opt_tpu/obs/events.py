@@ -218,6 +218,7 @@ SPAN_ATTRS = frozenset(
         "selected_keys",  # keys a query attends to under the selection
         "routed_tokens",  # tokens routed to the held experts, a layer
         "fullest_expert_tokens",  # the most any one held expert was sent in a step
+        "saved_residual_mib",  # MiB a member-step saves by name from its forward to its backward
         # provenance
         "op",  # boundary/digest flavor (exploit/rung_cut/suggest/...)
         "objectives",  # MO sweep: comma-joined objective names (train)

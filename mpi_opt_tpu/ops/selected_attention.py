@@ -12,6 +12,13 @@ leaves empty (the causal upper half is never touched) and store only
 each row's log-sum-exp. That log-sum-exp is also what the indexer's
 loss needs to rebuild the attention probabilities, so it is returned.
 
+The forward kernel runs once a train step: its context and log-sum-exp
+are all the backward kernels need of it, and both carry the name
+``RESIDUALS`` (``jax.ad_checkpoint.checkpoint_name``), so that a caller
+that rematerialises around this call saves them by a
+``save_only_these_names`` policy and its recompute holds no forward
+kernel.
+
 The kernels are the library's own (forward, dq, dkv, grouped-query
 heads, softmax in float32); this module only builds the mask
 information inside the traced program, shares one mask by all heads (a
@@ -34,6 +41,8 @@ from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_ma
 _INTERPRET = False  # tests flip this for CPU interpret-mode runs
 
 LANES = 128
+# the forward kernel's context and log-sum-exp, for a remat policy to save
+RESIDUALS = "selected_attention_residuals"
 
 
 def supported(positions: int, head_dim: int) -> bool:
@@ -63,7 +72,7 @@ def _mask_infos(mask, bs: sk.BlockSizes):
 def _kernel_args(bs: sk.BlockSizes) -> dict:
     return dict(
         mask_value=sk.DEFAULT_MASK_VALUE, is_mqa=False, block_sizes=bs,
-        residual_checkpoint_name=None, mask_function=None,
+        residual_checkpoint_name=RESIDUALS, mask_function=None,
         attn_logits_soft_cap=None, interpret=_INTERPRET,
     )
 

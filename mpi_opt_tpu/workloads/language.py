@@ -32,9 +32,11 @@ class DecoderMember:
     single_unbatched = True  # the expert layer branches on its own load
     # what a step's forward pass counted, beside its loss (span ``train``):
     # keys a query attends to under the selection (mean over layers),
-    # tokens routed to the held experts a layer (mean over layers), and
-    # the most any one held expert was sent in any layer
-    counters = ("selected_keys", "routed_tokens", "fullest_expert_tokens")
+    # tokens routed to the held experts a layer (mean over layers), the
+    # most any one held expert was sent in any layer, and the MiB of
+    # values the step saves by name from its forward to its backward
+    # (the model's ``saved_for_backward``; from the shapes, at trace time)
+    counters = ("selected_keys", "routed_tokens", "fullest_expert_tokens", "saved_residual_mib")
 
     def __init__(self, dims, positions: int):
         import jax.numpy as jnp
@@ -65,10 +67,11 @@ class DecoderMember:
     def loss(self, params, hp, key, bx, by):
         import jax.numpy as jnp
 
-        # counts int32 [rows, layers, (selected keys, routed tokens, fullest expert's)]
-        ce, index_loss, counts = self._rows(self._train, params, bx, by)
-        c = counts.astype(jnp.float32)
-        counters = jnp.stack([jnp.mean(c[..., 0]) / bx.shape[1], jnp.mean(c[..., 1]), jnp.max(c[..., 2])])
+        # c float32 [rows, layers, (selected keys, routed tokens, fullest expert's, bytes saved)]
+        ce, index_loss, c = self._rows(self._train, params, bx, by)
+        counters = jnp.stack(
+            [jnp.mean(c[..., 0]) / bx.shape[1], jnp.mean(c[..., 1]), jnp.max(c[..., 2]), jnp.sum(c[..., 3]) / 2**20]
+        )
         return jnp.mean(ce) / bx.shape[1] + jnp.mean(index_loss), counters
 
     def score_sum(self, params, cx, cy):

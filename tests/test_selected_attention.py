@@ -85,7 +85,7 @@ def test_a_query_tile_is_the_same_with_and_without_the_kernels():
 
     def run(kernels):
         def f(*a):
-            ctx, kl, n = smd._attention_tile(*a, first_row=128, dims=dims, index_loss=True, kernels=kernels)
+            ctx, kl, n, _ = smd._attention_tile(*a, first_row=128, dims=dims, index_loss=True, kernels=kernels)
             return jnp.sum(ctx.astype(jnp.float32) * weight) + kl, (kl, n)
 
         return jax.jit(jax.value_and_grad(f, argnums=tuple(range(6)), has_aux=True))(*args)
@@ -149,3 +149,81 @@ def test_the_decoder_on_the_kernels_is_the_reference(monkeypatch):
         np.testing.assert_allclose(
             np.asarray(got_grads[leaf], np.float32), g, rtol=0, atol=0.02 * np.abs(g).max(), err_msg=str(leaf)
         )
+
+
+def _census(jaxpr, counts=None):
+    """{kernel name | "selection loop": equations} of a jaxpr and every
+    jaxpr inside it (remat bodies, custom-derivative calls, loops): the
+    splash kernels by their names' stems, and the selection's bisection
+    (``kth_largest``: the one loop of 32 passes the member has)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        key = None
+        if eqn.primitive.name == "pallas_call":
+            key = next(s for s in ("fwd", "dq", "dkv") if f"splash_mha_{s}" in str(eqn.params["name"]))
+        elif eqn.primitive.name == "scan" and eqn.params["length"] == 32:
+            key = "selection loop"
+        if key is not None:
+            counts[key] = counts.get(key, 0) + 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _census(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize(
+    "kernels, dtype",
+    [(True, jnp.float32), (True, jnp.bfloat16), (False, jnp.float32)],
+    ids=["kernels-float32", "kernels-bfloat16", "xla-float32"],
+)
+def test_a_train_step_runs_each_tiles_kernel_and_selection_once(monkeypatch, kernels, dtype):
+    """Two layers of two query tiles under ``grad``: the layer's remat
+    and the tile's checkpoint both save the selection mask and the
+    forward kernel's context and log-sum-exp by name
+    (``saved_for_backward``), so the program holds ONE forward kernel
+    and ONE selection loop a tile, and one dq and one dkv; with nothing
+    saved (both policies ``None``: the nest as it was) it holds three of
+    each forward, and gives the same loss and gradients: the saved
+    values are the ones it makes again. (Compiled without XLA's excess
+    precision, which skips roundings to bfloat16 inside a fusion and so
+    rounds two programs of the same arithmetic differently: by up to 7%
+    of a small leaf's gradient here.)"""
+    monkeypatch.setattr(smd, "COMPUTE_DTYPE", dtype)
+    monkeypatch.setattr(smd, "use_kernels", lambda dims, positions: kernels)
+    dims = smd.DecoderDims(
+        vocab=64, hidden=32, layers=2, heads=2, kv_heads=1, head_dim=128, index_heads=2, index_dim=16,
+        top_k_keys=96, q_chunk=128, experts_published=8, experts_held=2, experts_per_token=2,
+        expert_width=16, expert_capacity=0, loss_rows=128,
+    )
+    model = smd.SparseMoEDecoder(dims)
+    tokens = jax.random.randint(jax.random.key(0), (257,), 0, dims.vocab)
+    x, y = tokens[:-1], tokens[1:]
+    params = model.init(jax.random.key(1), x, y)["params"]
+
+    def loss(p):
+        ce, index_loss, counts = model.apply({"params": p}, x, y)
+        return ce / x.shape[0] + index_loss, counts[:, 3]
+
+    def trace():
+        step = jax.value_and_grad(loss, has_aux=True)
+        exact = jax.jit(step, compiler_options={"xla_allow_excess_precision": False})
+        return _census(jax.make_jaxpr(step)(params).jaxpr), exact(params)
+
+    tiles = dims.layers * (x.shape[0] // dims.q_chunk)
+    once = {"selection loop": tiles, **({"fwd": tiles, "dq": tiles, "dkv": tiles} if kernels else {})}
+    census, ((got, held), got_grads) = trace()
+    assert census == once
+    # a tile's mask [128, K] for K = 128, 256 and, from the kernel, 2 heads'
+    # context [128, 128] in the compute dtype and log-sum-exp [128] in float32
+    a_layer = 128 * (128 + 256) + (2 * 2 * 128 * (128 * jnp.dtype(dtype).itemsize + 4) if kernels else 0)
+    assert [float(b) for b in held] == [a_layer] * dims.layers
+    monkeypatch.setattr(smd, "saved_for_backward", lambda: None)
+    census, ((want, _), want_grads) = trace()
+    assert census == {name: n if name in ("dq", "dkv") else 3 * n for name, n in once.items()}
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_grads), jax.tree.leaves(want_grads)):
+        w = np.asarray(w, np.float32)
+        assert np.abs(w).max() > 0, path
+        np.testing.assert_allclose(np.asarray(g, np.float32), w, rtol=0, atol=1e-5 * np.abs(w).max(), err_msg=str(path))

@@ -36,6 +36,10 @@ CELL = "keye_vl2_30b_a3b.pbt_pop4_seq8k"
 LIMITS = os.path.join(BENCH, "tests", "data", "rehearse_limits.json")
 SEED = 7
 INDEXER = ("wq_index", "wk_index", "ww_index")
+# what a member-step saves by name from its forward to its backward at the
+# rehearse sizes (XLA's own path: the selection masks alone): 2 layers of two
+# tiles of 32 queries against 32 and 64 keys, a byte a pair
+SAVED_MIB = 2 * 32 * (32 + 64) / 2**20
 
 
 @pytest.fixture(scope="module")
@@ -204,8 +208,9 @@ def test_a_member_trained_in_place_reaches_the_cut_members_state(cfg, ref, float
     # the steps' own counters come out beside the losses, [steps, counters],
     # each the mean over the two members
     np.testing.assert_allclose(np.asarray(ca), np.asarray(cb), rtol=1e-6)
-    assert trainer.member.counters == ("selected_keys", "routed_tokens", "fullest_expert_tokens")
-    selected, routed, fullest = np.asarray(ca).T
+    assert trainer.member.counters == ("selected_keys", "routed_tokens", "fullest_expert_tokens", "saved_residual_mib")
+    selected, routed, fullest, saved = np.asarray(ca).T
+    assert np.all(saved == SAVED_MIB)
     # min(16, t + 1) keys a query of 64, and the keys tied with the last of
     # them (relu leaves many index scores 0 at these sizes); dense reads 32.5
     assert np.all((selected >= (136 + 48 * 16) / 64) & (selected < 20))
@@ -216,8 +221,9 @@ def test_a_member_trained_in_place_reaches_the_cut_members_state(cfg, ref, float
 def test_the_train_span_carries_the_members_counters(cfg, tmp_path, capsys):
     """A traced sweep through ``cli.main``: every launch's ``train``
     span carries what its members' train steps counted of their own
-    work, out of the program that trained them (no program beside it),
-    and the stream passes the registry."""
+    work and what they saved by name for their backward pass, out of
+    the program that trained them (no program beside it), and the
+    stream passes the registry."""
     from mpi_opt_tpu.cli import main
     from mpi_opt_tpu.obs import events
 
@@ -234,6 +240,7 @@ def test_the_train_span_carries_the_members_counters(cfg, tmp_path, capsys):
     trains = [r for r in spans if r["span"] == "train"]
     assert [r["launch"] for r in trains] == [1, 2]
     for r in trains:
+        assert r["saved_residual_mib"] == SAVED_MIB  # from the shapes: the same on every launch
         assert 14.125 <= r["selected_keys"] < 20  # min(16, t + 1) of 64, and ties
         assert 8 < r["routed_tokens"] < 64 and r["routed_tokens"] / 2 <= r["fullest_expert_tokens"] <= 64
     assert not [r for r in spans if r.get("op") == "member_counts"]
