@@ -35,9 +35,12 @@ in-process twin deliberately abandons a wedged worker thread.
 
 from __future__ import annotations
 
+import contextlib
 import signal
 import sys
 import threading
+
+import pytest
 
 #: signals the ShutdownGuard contract covers (install-on-enter,
 #: restore-on-exit); SIGINT also guards against tests clobbering
@@ -48,6 +51,43 @@ _GUARDED_SIGNALS = ("SIGTERM", "SIGINT")
 #: pool shutdown may still be unwinding when the test body returns;
 #: joining briefly separates "slow teardown" from "leaked forever")
 _JOIN_GRACE_S = 2.0
+
+
+#: seconds a test's call phase may take, for every test alike: more than
+#: four times the dearest test of a serial tier-1 run on 8 cores (25 s)
+#: and twice the dearest before PR 30 (52 s; CHANGES.md). There is no
+#: marker, option or environment variable that raises it: a test that
+#: needs longer gets smaller, or is marked `slow`.
+TEST_LIMIT_S = 120.0
+
+
+@contextlib.contextmanager
+def time_limit(seconds: float, name: str):
+    """Fail ``name`` once the block has run for ``seconds``.
+
+    An interval timer on the main thread: ``SIGALRM``'s handler raises
+    pytest's failure where the test stands, so its ``finally`` clauses
+    and fixtures unwind as after any failed assertion (a child a test
+    runs under its own shorter ``timeout=`` still fails by that first; a
+    longer one is cut here, and ``subprocess.run`` kills the child on the
+    way out). Python runs a handler between two bytecodes, so a call
+    that never comes back from C is not interrupted: that is the
+    command's own ``timeout``. The timer and the previous handler are
+    put back on every exit. ``SIGALRM`` is not one of the signals the
+    leak check watches (``_GUARDED_SIGNALS``), and nothing under
+    ``mpi_opt_tpu/`` installs a handler for it.
+    """
+
+    def expired(signum, frame):
+        pytest.fail(f"{name} ran past the limit of {seconds:g} s a test has", pytrace=True)
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    was = signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, *was)
+        signal.signal(signal.SIGALRM, previous)
 
 
 def _live_threads() -> dict:

@@ -1449,6 +1449,55 @@ def test_sanitizer_opt_out_marker_is_honored():
     signal.signal(signal.SIGTERM, prev)  # ...and restored before exit
 
 
+# -- the limit every test has, and the session's workloads (ISSUE 30) ------
+
+
+def test_time_limit_fails_the_test_by_name_and_puts_the_timer_back():
+    """``sanitizers.time_limit`` (conftest.py puts every test's call
+    phase under it): a block that outlasts its limit is failed with the
+    name it was given; the timer that was running before (this test's
+    own limit) and the previous handler are back afterwards, whether
+    the block ran out or not."""
+    import signal
+    import time
+
+    import sanitizers
+
+    handler = signal.getsignal(signal.SIGALRM)
+    remaining_before, _ = signal.getitimer(signal.ITIMER_REAL)
+    # the hook is on: this test itself is being timed, by the one constant
+    assert 0 < remaining_before <= sanitizers.TEST_LIMIT_S
+    with pytest.raises(pytest.fail.Exception, match=r"tests/x\.py::test_slow\[a\] ran past the limit of 0\.05 s"):
+        with sanitizers.time_limit(0.05, "tests/x.py::test_slow[a]"):
+            give_up = time.monotonic() + 5.0
+            while time.monotonic() < give_up:
+                time.sleep(0.01)
+    with sanitizers.time_limit(5.0, "quick"):
+        pass  # inside its limit: nothing is raised
+    assert signal.getsignal(signal.SIGALRM) is handler
+    remaining, interval = signal.getitimer(signal.ITIMER_REAL)
+    assert 0 < remaining <= remaining_before and interval == 0.0
+
+
+def test_shared_workload_is_one_instance_for_the_same_arguments(shared_workload):
+    from mpi_opt_tpu.workloads import get_workload
+
+    a = shared_workload("fashion_mlp", n_train=64, n_val=32)
+    assert shared_workload("fashion_mlp", n_val=32, n_train=64) is a
+    assert type(a) is type(get_workload("fashion_mlp")) and (a.n_train, a.n_val) == (64, 32)
+    # other sizes, another name, a label (the variant of the trainer the
+    # test will ask for) or an attribute: an instance each
+    others = [
+        shared_workload("fashion_mlp", n_train=64, n_val=16),
+        shared_workload("digits_mlp"),
+        shared_workload("fashion_mlp", label="pop8 data1 mesh", n_train=64, n_val=32),
+        shared_workload("fashion_mlp", n_train=64, n_val=32, attrs={"batch_size": 8}),
+    ]
+    assert len({id(w) for w in [a, *others]}) == 5
+    assert others[3].batch_size == 8 and a.batch_size != 8
+    assert shared_workload("fashion_mlp", n_train=64, n_val=32, attrs={"batch_size": 8}) is others[3]
+
+
 # -- lock-order runtime sanitizer (ISSUE 15) ------------------------------
 
 

@@ -126,13 +126,13 @@ def test_fused_hyperband_nan_bracket_never_sticks(monkeypatch):
     assert res["best_score"] == pytest.approx(0.9)
 
 
-def test_fused_bohb_runs_and_uses_model():
+def test_fused_bohb_runs_and_uses_model(shared_workload):
     """Fused BOHB: every bracket executes as a fused on-device SHA; by
     the later brackets the model store has qualified, so cohorts carry
     model-sampled rows (random_fraction=0 makes the count exact)."""
     from mpi_opt_tpu.train.fused_bohb import fused_bohb
 
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256)
     # bracket 0's first rung alone contributes 9 observations at budget
     # 1 (the FULL cohort scores, not just stop-rung ones), clearing the
     # 5-dim space's default n_min = d+3 = 8 — so the model qualifies for
@@ -146,14 +146,14 @@ def test_fused_bohb_runs_and_uses_model():
     assert res["brackets"][2]["n_model_sampled"] == 3
 
 
-def test_fused_sha_init_unit_digest_guards_resume(tmp_path):
+def test_fused_sha_init_unit_digest_guards_resume(shared_workload, tmp_path):
     """A fused SHA resumed under DIFFERENT initial configurations is a
     different search: the checkpoint's cohort digest must refuse it."""
     import jax
 
     from mpi_opt_tpu.train.fused_asha import fused_sha
 
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256)
     space = wl.default_space()
     ck = str(tmp_path / "ck")
     unit_a = np.asarray(space.sample_unit(jax.random.key(1), 6))
@@ -234,7 +234,7 @@ def test_bohb_refuses_hyperband_checkpoint():
         algo.load_state_dict(hb_state)
 
 
-def test_fused_hyperband_persists_cohorts_for_resume(tmp_path):
+def test_fused_hyperband_persists_cohorts_for_resume(shared_workload, tmp_path):
     """Resume correctness must not depend on the model regenerating
     bit-identical cohorts: each bracket's sampled cohort is persisted
     (cohort_b.npz) and reused, so a resumed sweep whose sampler would
@@ -244,7 +244,7 @@ def test_fused_hyperband_persists_cohorts_for_resume(tmp_path):
 
     from mpi_opt_tpu.train.fused_asha import fused_hyperband
 
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256)
     space = wl.default_space()
     ck = str(tmp_path / "ck")
 

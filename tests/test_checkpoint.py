@@ -57,13 +57,12 @@ def test_kill_and_resume_matches_uninterrupted(tmp_path, quad):
     assert algo2.best().score == pytest.approx(ref.best().score, abs=1e-6)
 
 
-def test_tpu_backend_pool_roundtrip(tmp_path):
+def test_tpu_backend_pool_roundtrip(shared_workload, tmp_path):
     """PBT through the population backend: kill mid-sweep, resume with a
     fresh backend whose slot pool is restored from orbax; the finished
     search must match the uninterrupted run exactly (weights inherited
     across the kill boundary, not retrained)."""
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
-    wl.batch_size = 16
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128, attrs={"batch_size": 16})
     space = wl.default_space()
 
     def make_algo():

@@ -1,13 +1,50 @@
 """CLI end-to-end: the config-1 minimum slice, in-process."""
 
+import contextlib
+import io
 import json
 import os
+import shutil
 
 import jax.errors
 
 import pytest
 
 from mpi_opt_tpu.cli import build_parser, main
+
+
+def _run(argv):
+    """stdout of one ``main(argv)`` that ends with 0, for the module's
+    shared runs (``capsys`` is a test's own)."""
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        assert main(argv) == 0
+    return out.getvalue()
+
+
+FUSED_PBT = [
+    "--workload", "fashion_mlp",
+    "--algorithm", "pbt",
+    "--fused",
+    "--population", "8",
+    "--generations", "2",
+    "--steps-per-generation", "5",
+    "--seed", "0",
+]
+
+
+@pytest.fixture(scope="module")
+def fused_pbt_checkpointed(tmp_path_factory):
+    """``FUSED_PBT --checkpoint-dir ck`` run once a module: (stdout, ck).
+    A test that goes on in the directory copies it first."""
+    ck = str(tmp_path_factory.mktemp("fused_pbt") / "ck")
+    return _run(FUSED_PBT + ["--checkpoint-dir", ck]), ck
+
+
+@pytest.fixture(scope="module")
+def fused_pbt_no_mesh():
+    """stdout of ``FUSED_PBT --no-mesh``, run once a module."""
+    return _run(FUSED_PBT + ["--no-mesh"])
 
 
 def test_parser_defaults():
@@ -57,21 +94,8 @@ def test_cli_quadratic_pbt(capsys):
     assert summary["n_trials"] == 24
 
 
-def test_fused_pbt_cli(capsys, tmp_path):
-    rc = main(
-        [
-            "--workload", "fashion_mlp",
-            "--algorithm", "pbt",
-            "--fused",
-            "--population", "8",
-            "--generations", "2",
-            "--steps-per-generation", "5",
-            "--seed", "0",
-            "--checkpoint-dir", str(tmp_path / "ck"),
-        ]
-    )
-    assert rc == 0
-    lines = [l for l in capsys.readouterr().out.strip().splitlines() if l.startswith("{")]
+def test_fused_pbt_cli(fused_pbt_checkpointed):
+    lines = [l for l in fused_pbt_checkpointed[0].strip().splitlines() if l.startswith("{")]
     summary = json.loads(lines[-1])
     assert summary["backend"] == "fused"
     assert summary["n_trials"] == 16
@@ -223,17 +247,7 @@ def test_fused_cli_auto_mesh(capsys):
     """On a multi-device host the fused CLI path must run sharded by
     default (VERDICT r2 item 1): the conftest's 8 virtual devices should
     yield an 8-way 'pop' mesh with per-chip accounting to match."""
-    rc = main(
-        [
-            "--workload", "fashion_mlp",
-            "--algorithm", "pbt",
-            "--fused",
-            "--population", "8",
-            "--generations", "2",
-            "--steps-per-generation", "5",
-            "--seed", "0",
-        ]
-    )
+    rc = main(FUSED_PBT)
     assert rc == 0
     summary = _summary(capsys)
     assert summary["mesh"] == {"pop": 8, "data": 1}
@@ -259,21 +273,8 @@ def test_fused_cli_mesh_flags(capsys):
     assert summary["n_chips"] == 8
 
 
-def test_fused_cli_no_mesh_runs_single_device(capsys):
-    rc = main(
-        [
-            "--workload", "fashion_mlp",
-            "--algorithm", "pbt",
-            "--fused",
-            "--no-mesh",
-            "--population", "8",
-            "--generations", "2",
-            "--steps-per-generation", "5",
-            "--seed", "0",
-        ]
-    )
-    assert rc == 0
-    summary = _summary(capsys)
+def test_fused_cli_no_mesh_runs_single_device(fused_pbt_no_mesh):
+    summary = _summary_from(fused_pbt_no_mesh)
     assert summary["mesh"] is None
     # ADVICE r2: per-chip divisor = devices the sweep actually ran on (1)
     assert summary["n_chips"] == 1
@@ -292,23 +293,15 @@ def test_no_mesh_contradicts_mesh_flags():
         )
 
 
-def test_fused_checkpoint_requires_explicit_resume(capsys, tmp_path):
+def test_fused_checkpoint_requires_explicit_resume(capsys, tmp_path, fused_pbt_checkpointed):
     """A checkpoint dir holding a previous sweep must not silently
     replay it: resuming is --resume opt-in, like the driver path
     (ADVICE r2)."""
+    out, left = fused_pbt_checkpointed
     ck = str(tmp_path / "ck")
-    argv = [
-        "--workload", "fashion_mlp",
-        "--algorithm", "pbt",
-        "--fused",
-        "--population", "8",
-        "--generations", "2",
-        "--steps-per-generation", "5",
-        "--seed", "0",
-        "--checkpoint-dir", ck,
-    ]
-    assert main(argv) == 0
-    first = _summary(capsys)
+    shutil.copytree(left, ck)  # the directory the module's run left, this test's own copy
+    argv = FUSED_PBT + ["--checkpoint-dir", ck]
+    first = _summary_from(out)
     with pytest.raises(SystemExit):  # stale dir, no --resume: refuse
         main(argv)
     capsys.readouterr()
@@ -561,24 +554,11 @@ def test_cli_chaos_rejects_tpu_backend(capsys):
     assert "cpu backend" in capsys.readouterr().err
 
 
-def test_fused_summary_reports_member_failures(capsys):
+def test_fused_summary_reports_member_failures(fused_pbt_no_mesh):
     """Every fused sweep's summary carries the per-generation diverged-
     member tallies (ROADMAP open item) — zero for a healthy sweep, but
     PRESENT, so operators can alarm on it."""
-    rc = main(
-        [
-            "--workload", "fashion_mlp",
-            "--algorithm", "pbt",
-            "--fused",
-            "--population", "8",
-            "--generations", "2",
-            "--steps-per-generation", "5",
-            "--seed", "0",
-            "--no-mesh",
-        ]
-    )
-    assert rc == 0
-    out = capsys.readouterr().out
+    out = fused_pbt_no_mesh
     summary = _summary_from(out)
     assert summary["member_failures"] == [0, 0]
     # ...and the metrics summary event carries the total

@@ -26,12 +26,9 @@ sys.path.insert(0, os.path.join(REPO_ROOT, "benchmarks"))
 import scopes  # noqa: E402  (benchmarks/scopes.py)
 
 
-def _tiny_cnn():
-    from mpi_opt_tpu.workloads import get_workload
-
-    wl = get_workload("cifar10_cnn", n_train=128, n_val=64)
-    wl.batch_size = 16
-    return wl
+@pytest.fixture(scope="module")
+def tiny_cnn(shared_workload):
+    return shared_workload("cifar10_cnn", n_train=128, n_val=64, attrs={"batch_size": 16})
 
 
 def _tiny_decoder():
@@ -67,9 +64,9 @@ def _fused_pbt_op_names(wl, member_chunk):
 
 
 @pytest.fixture(scope="module")
-def op_names():
+def op_names(tiny_cnn):
     """SmallCNN, 4 members in chunks of 2."""
-    return _fused_pbt_op_names(_tiny_cnn(), 2)
+    return _fused_pbt_op_names(tiny_cnn, 2)
 
 
 @pytest.fixture(scope="module")
@@ -220,28 +217,41 @@ def test_model_primitives_fall_in_their_class(name):
 
 # -- scopes change no arithmetic: a golden taken on the parent commit ------
 
-# fused_pbt(cifar10_cnn n_train=128 n_val=64 batch 16, population=4,
+# fused_pbt(cifar10_cnn n_train=128 n_val=16 batch 8, population=4,
 # generations=3, steps_per_gen=2, seed=5, gen_chunk=1, member_chunk=2)
-# at commit f27c065 (PR 24), before any scope existed
+# at commit f27c065 (PR 24), before any scope existed. Taken again at
+# PR 30 at a third of the rows (the golden of PR 25 evaluated 64 rows at
+# batch 16 and ran 25 s, 18 of them XLA:CPU's convolutions; every number
+# below is exact at any size), from a checkout of that commit:
+#   git archive f27c065 | tar -x -C /root/scratch/golden && cd /root/scratch/golden && JAX_PLATFORMS=cpu python - <<'EOF'
+#   import jax, numpy as np; jax.config.update("jax_num_cpu_devices", 8)
+#   import mpi_opt_tpu.train.fused_pbt as fp; from mpi_opt_tpu.workloads import get_workload
+#   wl = get_workload("cifar10_cnn", n_train=128, n_val=16); wl.batch_size = 8
+#   res = fp.fused_pbt(wl, population=4, generations=3, steps_per_gen=2, seed=5, gen_chunk=1, member_chunk=2)
+#   print(res["best_curve"], res["mean_curve"], res["best_score"], np.asarray(res["unit"]).tolist(),
+#         sum(float(np.sum(np.square(np.asarray(l, np.float64)))) for l in jax.tree.leaves(res["state"].params)))
+#   EOF
+# and this tree gives the same bytes for the same script.
 GOLDEN = {
-    "best_curve": [0.125, 0.171875, 0.125],
-    "mean_curve": [0.12109375, 0.1328125, 0.0859375],
-    "best_score": 0.125,
+    "best_curve": [0.0625, 0.25, 0.3125],
+    "mean_curve": [0.0625, 0.140625, 0.140625],
+    "best_score": 0.3125,
     "unit": [
         [0.08381330966949463, 0.22921323776245117, 0.11234712600708008, 0.2685335874557495, 0.12162470817565918],
-        [0.027728164568543434, 0.39616650342941284, 0.15413367748260498, 0.1053071990609169, 0.20800741016864777],
-        [0.011786477640271187, 0.36144089698791504, 0.0, 0.2527726888656616, 0.023098068311810493],
-        [0.0, 0.3556182086467743, 0.006437122821807861, 0.38210609555244446, 0.0],
+        [0.4554823637008667, 0.5360288619995117, 0.47860443592071533, 0.1630462408065796, 0.07066178321838379],
+        [0.015344500541687012, 0.5327630043029785, 0.1616910696029663, 0.7874373197555542, 0.07561564445495605],
+        [0.1320643573999405, 0.6594825387001038, 0.3564930856227875, 0.8730793595314026, 0.19269245862960815],
     ],
-    "param_sq_norm": 2085.3160184662147,
+    "param_sq_norm": 2108.3435368090763,
 }
 
 
-def test_resident_sweep_matches_the_parents_golden():
+def test_resident_sweep_matches_the_parents_golden(shared_workload):
     import mpi_opt_tpu.train.fused_pbt as fp
 
+    wl = shared_workload("cifar10_cnn", n_train=128, n_val=16, attrs={"batch_size": 8})
     res = fp.fused_pbt(
-        _tiny_cnn(), population=4, generations=3, steps_per_gen=2, seed=5,
+        wl, population=4, generations=3, steps_per_gen=2, seed=5,
         gen_chunk=1, member_chunk=2,
     )
     np.testing.assert_array_equal(res["best_curve"], np.float32(GOLDEN["best_curve"]))
@@ -258,14 +268,13 @@ def test_resident_sweep_matches_the_parents_golden():
 # -- the observer at the boundary -------------------------------------------
 
 
-def test_boundary_observer_is_handed_the_state():
+def test_boundary_observer_is_handed_the_state(shared_workload):
     """``launch_boundary(..., state=)`` hands the population state to an
     installed observer before the slice hook; resident and wave sweeps
     both pass it; nothing is called with none installed."""
     import mpi_opt_tpu.train.fused_pbt as fp
     from mpi_opt_tpu.health import shutdown
     from mpi_opt_tpu.train.common import launch_boundary
-    from mpi_opt_tpu.workloads import get_workload
 
     order = []
     shutdown.set_boundary_observer(lambda stage, state: order.append(("observer", stage, state)))
@@ -288,7 +297,7 @@ def test_boundary_observer_is_handed_the_state():
 
     shutdown.set_boundary_observer(keep)
     try:
-        wl = get_workload("fashion_mlp", n_train=128, n_val=64)
+        wl = shared_workload("fashion_mlp", n_train=128, n_val=64)
         kw = dict(population=4, generations=2, steps_per_gen=3, seed=1, gen_chunk=1)
         fp.fused_pbt(wl, **kw)
         fp.fused_pbt(wl, wave_size=2, **kw)

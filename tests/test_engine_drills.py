@@ -30,19 +30,47 @@ import mpi_opt_tpu.train.fused_tpe as ft
 from mpi_opt_tpu.health import shutdown
 from mpi_opt_tpu.ledger import SweepLedger, validate_ledger
 from mpi_opt_tpu.utils import resources
-from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.workloads.chaos import inject_oom
 
 
 @pytest.fixture(scope="module")
-def wl():
-    # one instance for the whole module: workload_arrays caches the
-    # trainer on it, so every test shares one compile set
-    return get_workload("fashion_mlp", n_train=256, n_val=128)
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", n_train=256, n_val=128)
 
 
 SHA_KW = dict(n_trials=8, min_budget=2, max_budget=8, eta=2, seed=3)
 TPE_KW = dict(n_trials=10, batch=4, budget=4, seed=5)
+BOHB_KW = dict(max_budget=4, eta=2, seed=7)
+
+# the undisturbed sweeps the cases below are held equal to, each run once
+# a module (results are read, never written into)
+
+
+@pytest.fixture(scope="module")
+def sha_resident(wl):
+    return fa.fused_sha(wl, **SHA_KW)
+
+
+@pytest.fixture(scope="module")
+def sha_waves_of_4(wl):
+    return fa.fused_sha(wl, wave_size=4, **SHA_KW)
+
+
+@pytest.fixture(scope="module")
+def tpe_resident(wl):
+    return ft.fused_tpe(wl, **TPE_KW)
+
+
+@pytest.fixture(scope="module")
+def tpe_waves_of_2(wl):
+    return ft.fused_tpe(wl, wave_size=2, **TPE_KW)
+
+
+@pytest.fixture(scope="module")
+def bohb_waves_of_2(wl):
+    from mpi_opt_tpu.train.fused_bohb import fused_bohb
+
+    return fused_bohb(wl, wave_size=2, **BOHB_KW)
 
 
 def _tree_equal(a, b):
@@ -80,8 +108,8 @@ def _records(path):
 
 
 @pytest.mark.parametrize("wave_size", [3, 4])  # [3,3,2] and [4,4]
-def test_sha_wave_bit_identical_to_resident(wl, wave_size):
-    res = fa.fused_sha(wl, **SHA_KW)
+def test_sha_wave_bit_identical_to_resident(wl, sha_resident, wave_size):
+    res = sha_resident
     wav = fa.fused_sha(wl, wave_size=wave_size, **SHA_KW)
     np.testing.assert_array_equal(res["last_score"], wav["last_score"])
     np.testing.assert_array_equal(res["stop_rung"], wav["stop_rung"])
@@ -97,8 +125,8 @@ def test_sha_wave_bit_identical_to_resident(wl, wave_size):
 
 
 @pytest.mark.parametrize("wave_size", [2, 3])  # [2,2] and [2,1] per gen of 4
-def test_tpe_wave_bit_identical_to_resident(wl, wave_size):
-    res = ft.fused_tpe(wl, **TPE_KW)
+def test_tpe_wave_bit_identical_to_resident(wl, tpe_resident, wave_size):
+    res = tpe_resident
     wav = ft.fused_tpe(wl, wave_size=wave_size, **TPE_KW)
     np.testing.assert_array_equal(res["obs_unit"], wav["obs_unit"])
     np.testing.assert_array_equal(res["obs_scores"], wav["obs_scores"])
@@ -111,12 +139,11 @@ def test_tpe_wave_bit_identical_to_resident(wl, wave_size):
     assert "wave_size" not in res
 
 
-def test_bohb_wave_matches_resident(wl):
+def test_bohb_wave_matches_resident(wl, bohb_waves_of_2):
     from mpi_opt_tpu.train.fused_bohb import fused_bohb
 
-    kw = dict(max_budget=4, eta=2, seed=7)
-    res = fused_bohb(wl, **kw)
-    wav = fused_bohb(wl, wave_size=2, **kw)
+    res = fused_bohb(wl, **BOHB_KW)
+    wav = bohb_waves_of_2
     assert res["best_score"] == wav["best_score"]
     assert res["best_params"] == wav["best_params"]
     assert res["member_failures"] == wav["member_failures"]
@@ -238,15 +265,14 @@ def test_tpe_oom_backoff_record_identical(wl, tmp_path):
     assert _records(tmp_path / "clean.jsonl") == _records(tmp_path / "oom.jsonl")
 
 
-def test_bohb_oom_backoff_matches_clean(wl):
+def test_bohb_oom_backoff_matches_clean(wl, bohb_waves_of_2):
     """BOHB inherits the drill through its brackets' fused_sha: an OOM
     in the FIRST bracket's first wave backs off inside that bracket;
     later brackets see identical observations, so the model's cohorts
     — and the final pick — match the clean run exactly."""
     from mpi_opt_tpu.train.fused_bohb import fused_bohb
 
-    kw = dict(max_budget=4, eta=2, seed=7)
-    clean = fused_bohb(wl, wave_size=2, **kw)
+    kw, clean = BOHB_KW, bohb_waves_of_2
     inj, uninstall = inject_oom(at_launch=1, kind="wave")
     try:
         faulted = fused_bohb(wl, wave_size=2, oom_backoff=2, **kw)
@@ -275,11 +301,11 @@ def test_sha_oom_without_budget_raises_typed(wl):
 # -- drill: crash / preemption -> resume, record-identical ------------------
 
 
-def test_sha_wave_crash_resume_bit_identical(wl, tmp_path):
+def test_sha_wave_crash_resume_bit_identical(wl, sha_waves_of_4, tmp_path):
     """Hard crash inside rung 1's second wave: resume restores the
     rung-boundary snapshot, re-trains only the interrupted rung, and
     finishes with the undisturbed sweep's exact result."""
-    whole = fa.fused_sha(wl, wave_size=4, **SHA_KW)
+    whole = sha_waves_of_4
     real = fa._run_wave
     calls = {"n": 0}
 
@@ -370,7 +396,7 @@ def test_sha_wave_snapshot_refused_by_resident_resume(wl, tmp_path):
         fa.fused_sha(wl, checkpoint_dir=ckpt, **SHA_KW)
 
 
-def test_tpe_wave_resume_adopts_settled_cap(wl, tmp_path):
+def test_tpe_wave_resume_adopts_settled_cap(wl, tpe_waves_of_2, tmp_path):
     """The OOM-settled execution cap travels in snapshot meta
     (wave_size_run): a resume adopts it instead of re-paying the
     halvings, while the REQUESTED cap stays the config identity."""
@@ -398,7 +424,7 @@ def test_tpe_wave_resume_adopts_settled_cap(wl, tmp_path):
         uninstall()
     assert inj.faults_fired == 1
 
-    whole = ft.fused_tpe(wl, wave_size=2, **TPE_KW)
+    whole = tpe_waves_of_2
     resumed = ft.fused_tpe(
         wl, wave_size=2, oom_backoff=2, checkpoint_dir=ckpt, **TPE_KW
     )

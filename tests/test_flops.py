@@ -20,10 +20,9 @@ def test_compiled_flops_matmul_exact():
     assert f == pytest.approx(2 * 256**3, rel=0.01)
 
 
-def test_population_sweep_flops_linear_scaling():
-    from mpi_opt_tpu.workloads import get_workload
+def test_population_sweep_flops_linear_scaling(shared_workload):
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128)
     f1 = population_sweep_flops(wl, population=4, generations=2, steps_per_gen=3, n_evals=3)
     if f1 is None:
         pytest.skip("cost analysis unavailable on this backend")

@@ -20,9 +20,8 @@ def test_sha_cohort_sizes_mesh_rounding():
 
 
 @pytest.fixture(scope="module")
-def workload():
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
-    wl.batch_size = 32
+def workload(shared_workload):
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256, attrs={"batch_size": 32})
     return wl
 
 
@@ -54,10 +53,13 @@ def test_fused_sha_survivors_beat_stopped(workload):
     assert stopped.shape[0] == 4
 
 
-def test_fused_sha_sharded_matches_structure(workload):
+def test_fused_sha_sharded_matches_structure(shared_workload):
     from mpi_opt_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(n_pop=4, n_data=2)
+    workload = shared_workload(
+        "fashion_mlp", label="pop4 data2 mesh", n_train=512, n_val=256, attrs={"batch_size": 32}
+    )
     r = fused_sha(
         workload, n_trials=8, min_budget=2, max_budget=4, eta=2, seed=2, mesh=mesh
     )
@@ -74,6 +76,8 @@ def test_fused_sha_all_nan_cohort_reports_diverged(monkeypatch):
 
     from mpi_opt_tpu.train.common import workload_arrays
 
+    # its own instance, not the session's: a program traced around the
+    # patched eval_population stays on the trainer
     wl = get_workload("fashion_mlp", n_train=256, n_val=128)
     trainer, *_ = workload_arrays(wl)
     monkeypatch.setattr(trainer, "eval_population", lambda *a, **k: jnp.full(4, jnp.nan))
@@ -90,7 +94,7 @@ def test_fused_sha_one_nan_does_not_hijack(monkeypatch):
 
     from mpi_opt_tpu.train.common import workload_arrays
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = get_workload("fashion_mlp", n_train=256, n_val=128)  # its own, as above
     trainer, *_ = workload_arrays(wl)
     scores = jnp.asarray([jnp.nan, 0.2, 0.9, 0.4])
     monkeypatch.setattr(trainer, "eval_population", lambda *a, **k: scores)

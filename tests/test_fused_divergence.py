@@ -18,21 +18,30 @@ from mpi_opt_tpu.train.fused_pbt import fused_pbt
 from mpi_opt_tpu.workloads import get_workload
 
 
-def _wl():
+@pytest.fixture
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", n_train=256, n_val=128)
+
+
+@pytest.fixture
+def own_wl():
+    """An instance of this test's own, NOT the session's: the test puts
+    its scores in place of the trainer's ``eval_population``, and a
+    program traced around them stays on the trainer for whoever asks for
+    the same sizes next."""
     return get_workload("fashion_mlp", n_train=256, n_val=128)
 
 
-def test_fused_pbt_nan_survivor_does_not_hijack(monkeypatch):
+def test_fused_pbt_nan_survivor_does_not_hijack(own_wl, monkeypatch):
     """Two NaN members, truncation cut of 1: exactly one gets exploited
     (replaced by a top member's score via the src_idx gather), the other
     SURVIVES into final_scores as NaN — the scenario where a bare
     argmax would crown the NaN row. The winner must be the best finite
     score."""
-    wl = _wl()
-    trainer, *_ = workload_arrays(wl)
+    trainer, *_ = workload_arrays(own_wl)
     scores = jnp.asarray([0.9, jnp.nan, jnp.nan, 0.4])
     monkeypatch.setattr(trainer, "eval_population", lambda *a, **k: scores)
-    r = fused_pbt(wl, population=4, generations=1, steps_per_gen=1, seed=0)
+    r = fused_pbt(own_wl, population=4, generations=1, steps_per_gen=1, seed=0)
     assert r["diverged"] is False
     assert r["best_score"] == pytest.approx(0.9)
     assert r["best_params"] is not None
@@ -41,31 +50,29 @@ def test_fused_pbt_nan_survivor_does_not_hijack(monkeypatch):
     assert r["member_failures"] == [2]
 
 
-def test_fused_pbt_all_nan_reports_diverged(monkeypatch):
-    wl = _wl()
-    trainer, *_ = workload_arrays(wl)
+def test_fused_pbt_all_nan_reports_diverged(own_wl, monkeypatch):
+    trainer, *_ = workload_arrays(own_wl)
     monkeypatch.setattr(
         trainer, "eval_population", lambda *a, **k: jnp.full(4, jnp.nan)
     )
-    r = fused_pbt(wl, population=4, generations=1, steps_per_gen=1, seed=0)
+    r = fused_pbt(own_wl, population=4, generations=1, steps_per_gen=1, seed=0)
     assert r["diverged"] is True
     assert r["best_params"] is None
     assert np.isnan(r["best_score"])
     assert r["member_failures"] == [4]
 
 
-def test_fused_sha_counts_member_failures_per_rung(monkeypatch):
+def test_fused_sha_counts_member_failures_per_rung(own_wl, monkeypatch):
     """The single-rung (fused random) case: diverged members are tallied
     per rung in the result, exactly what the isfinite winner pick
     masks. Shared rung_history sourcing keeps the eager and deferred
     fetch paths in agreement by construction."""
     from mpi_opt_tpu.train.fused_asha import fused_sha
 
-    wl = _wl()
-    trainer, *_ = workload_arrays(wl)
+    trainer, *_ = workload_arrays(own_wl)
     scores = jnp.asarray([0.9, jnp.nan, jnp.nan, 0.4])
     monkeypatch.setattr(trainer, "eval_population", lambda *a, **k: scores)
-    r = fused_sha(wl, n_trials=4, min_budget=2, max_budget=2, seed=0)
+    r = fused_sha(own_wl, n_trials=4, min_budget=2, max_budget=2, seed=0)
     assert r["member_failures"] == [2]
     assert r["best_score"] == pytest.approx(0.9)
 
@@ -83,11 +90,10 @@ def _nan_row_injector(real, rows):
     return wrapped
 
 
-def test_fused_tpe_valid_nan_does_not_hijack(monkeypatch):
+def test_fused_tpe_valid_nan_does_not_hijack(wl, monkeypatch):
     """A valid-but-NaN observation must not win argmax (the old code
     masked only ~valid rows) and must not poison the running
     best_curve (jnp.max propagates NaN into every later point)."""
-    wl = _wl()
     monkeypatch.setattr(
         ft, "tpe_generation", _nan_row_injector(ft.tpe_generation, rows=[0])
     )
@@ -101,8 +107,7 @@ def test_fused_tpe_valid_nan_does_not_hijack(monkeypatch):
     assert np.isnan(r["obs_scores"][0])
 
 
-def test_fused_tpe_all_nan_reports_diverged(monkeypatch):
-    wl = _wl()
+def test_fused_tpe_all_nan_reports_diverged(wl, monkeypatch):
     monkeypatch.setattr(
         ft,
         "tpe_generation",

@@ -33,15 +33,13 @@ from mpi_opt_tpu.ledger import (
 )
 from mpi_opt_tpu.ledger.report import summarize_ledger
 from mpi_opt_tpu.objectives import ObjectiveSpec
-from mpi_opt_tpu.workloads import get_workload
-
 SPEC = ObjectiveSpec.parse("accuracy:max,params:min")
 KW = dict(population=6, generations=3, steps_per_gen=4, seed=3, gen_chunk=1)
 
 
 @pytest.fixture(scope="module")
-def wl():
-    return get_workload("digits_mlp")
+def wl(shared_workload):
+    return shared_workload("digits_mlp")
 
 
 def _mo_ledger(path, space, algorithm="pbt", spec=SPEC):
@@ -64,11 +62,18 @@ def _records(path):
     return [json.loads(l) for l in open(path).read().splitlines()[1:]]
 
 
-def test_mo_pbt_journals_vectors_and_scalarized_score(tmp_path, wl):
-    space = wl.default_space()
-    led = _mo_ledger(tmp_path / "mo.jsonl", space)
+@pytest.fixture(scope="module")
+def mo_sweep(wl, tmp_path_factory):
+    """(result, ledger) of the undisturbed multi-objective sweep, run and
+    journaled once a module; the tests read both and write into neither."""
+    led = _mo_ledger(tmp_path_factory.mktemp("mo_sweep") / "mo.jsonl", wl.default_space())
     res = fp.fused_pbt(wl, ledger=led, objectives=SPEC, **KW)
     led.close()
+    return res, led
+
+
+def test_mo_pbt_journals_vectors_and_scalarized_score(mo_sweep):
+    res, led = mo_sweep
 
     assert validate_ledger(led.path) == []
     recs = _records(led.path)
@@ -132,14 +137,12 @@ def test_scalar_fused_ledger_carries_no_mo_keys(tmp_path, wl):
         summarize_ledger(led.path, best_under="params<=100")
 
 
-def test_mo_crash_resume_record_identical(tmp_path, wl):
+def test_mo_crash_resume_record_identical(tmp_path, wl, mo_sweep):
     """Acceptance drill: kill an MO sweep mid-run, ``--resume`` it, and
     the journal — vectors included — is record-identical to an unkilled
     run's."""
     space = wl.default_space()
-    clean = _mo_ledger(tmp_path / "clean.jsonl", space)
-    fp.fused_pbt(wl, ledger=clean, objectives=SPEC, **KW)
-    clean.close()
+    _, clean = mo_sweep
 
     real = fp.run_fused_pbt
     calls = {"n": 0}
@@ -209,11 +212,8 @@ def test_resume_verify_catches_diverged_vector(tmp_path, wl):
     led2.close()
 
 
-def test_report_best_under_typed_answers(tmp_path, wl):
-    space = wl.default_space()
-    led = _mo_ledger(tmp_path / "bu.jsonl", space)
-    fp.fused_pbt(wl, ledger=led, objectives=SPEC, **KW)
-    led.close()
+def test_report_best_under_typed_answers(mo_sweep):
+    _, led = mo_sweep
 
     # a satisfiable bound answers feasible with a concrete winner
     mo = summarize_ledger(led.path)["multi_objective"]

@@ -13,20 +13,44 @@ import numpy as np
 import pytest
 
 import mpi_opt_tpu.train.fused_pbt as fp
-from mpi_opt_tpu.workloads import get_workload
+
+MLP = dict(n_train=256, n_val=128)
 
 
-def _wl():
-    return get_workload("fashion_mlp", n_train=256, n_val=128)
+@pytest.fixture(scope="module")
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", **MLP)
 
 
 KW = dict(population=8, generations=4, steps_per_gen=5, seed=2, gen_chunk=1)
+TPE_KW = dict(n_trials=9, batch=3, budget=4, seed=3)
+BOHB_KW = dict(max_budget=4, eta=2, seed=1, random_fraction=0.5)
+
+# the uninterrupted sweeps the drills below end equal to, run once a
+# module: results are host arrays and plain values, and no test writes
+# into one
 
 
-def test_crash_resume_bit_identical(tmp_path, monkeypatch):
-    wl = _wl()
-    whole = fp.fused_pbt(wl, **KW)
+@pytest.fixture(scope="module")
+def whole(wl):
+    return fp.fused_pbt(wl, **KW)
 
+
+@pytest.fixture(scope="module")
+def tpe_whole(wl):
+    import mpi_opt_tpu.train.fused_tpe as ft
+
+    return ft.fused_tpe(wl, **TPE_KW)
+
+
+@pytest.fixture(scope="module")
+def bohb_whole(wl):
+    from mpi_opt_tpu.train.fused_bohb import fused_bohb
+
+    return fused_bohb(wl, **BOHB_KW)
+
+
+def test_crash_resume_bit_identical(wl, whole, tmp_path, monkeypatch):
     real = fp.run_fused_pbt
     calls = {"n": 0}
 
@@ -55,7 +79,7 @@ def test_crash_resume_bit_identical(tmp_path, monkeypatch):
     assert all(w > 0 for w in resumed["launch_walls"])
 
 
-def test_pre_upgrade_snapshot_resume_reports_no_launch_walls(tmp_path, monkeypatch):
+def test_pre_upgrade_snapshot_resume_reports_no_launch_walls(wl, whole, tmp_path, monkeypatch):
     """A snapshot from before round 3 lacks BOTH the 'momentum_dtype'
     config key and the 'launch_walls' meta — emulated by editing the
     on-disk orbax JSON, exactly what an old snapshot looks like. The
@@ -69,8 +93,6 @@ def test_pre_upgrade_snapshot_resume_reports_no_launch_walls(tmp_path, monkeypat
 
     from mpi_opt_tpu.utils.metrics import sweep_wall_to_target
 
-    wl = _wl()
-    whole = fp.fused_pbt(wl, **KW)
     ckpt = str(tmp_path / "ck")
     real = fp.run_fused_pbt
     calls = {"n": 0}
@@ -115,8 +137,7 @@ def test_pre_upgrade_snapshot_resume_reports_no_launch_walls(tmp_path, monkeypat
     assert sweep_wall_to_target(resumed, 10.0, -1.0) == pytest.approx(2.5)
 
 
-def test_resume_after_completion_skips_all_launches(tmp_path, monkeypatch):
-    wl = _wl()
+def test_resume_after_completion_skips_all_launches(wl, tmp_path, monkeypatch):
     ckpt = str(tmp_path / "ck")
     first = fp.fused_pbt(wl, checkpoint_dir=ckpt, **KW)
 
@@ -129,13 +150,12 @@ def test_resume_after_completion_skips_all_launches(tmp_path, monkeypatch):
     assert again["best_score"] == first["best_score"]
 
 
-def test_step_chunk_deterministic_learns_and_matches_shapes():
+def test_step_chunk_deterministic_learns_and_matches_shapes(wl):
     """step_chunk (sub-generation launch splitting) is deterministic,
     returns the same result shapes as the fused scan, and still learns.
     It is NOT bit-identical to the unchunked sweep (documented: folded
     sub-segment keys), so equality is asserted between two step-chunked
     runs, not against the scan."""
-    wl = _wl()
     kw = dict(population=8, generations=3, steps_per_gen=6, seed=5, step_chunk=2)
     a = fp.fused_pbt(wl, **kw)
     b = fp.fused_pbt(wl, **kw)
@@ -149,10 +169,9 @@ def test_step_chunk_deterministic_learns_and_matches_shapes():
     assert set(a.keys()) == set(scan.keys())
 
 
-def test_step_chunk_crash_resume_identical(tmp_path, monkeypatch):
+def test_step_chunk_crash_resume_identical(wl, tmp_path, monkeypatch):
     """Generation-granular snapshots make a killed step-chunked sweep
     resume to the identical result of an uninterrupted one."""
-    wl = _wl()
     kw = dict(population=8, generations=4, steps_per_gen=6, seed=6, step_chunk=3)
     whole = fp.fused_pbt(wl, **kw)
 
@@ -175,18 +194,17 @@ def test_step_chunk_crash_resume_identical(tmp_path, monkeypatch):
     assert resumed["best_score"] == whole["best_score"]
 
 
-def test_step_chunk_changes_trajectory_and_guards_resume(tmp_path):
+def test_step_chunk_changes_trajectory_and_guards_resume(wl, tmp_path):
     """step_chunk is part of the checkpoint config: it changes the RNG
     derivation (a different search trajectory), so resuming an
     unchunked snapshot with step_chunk set must be refused."""
-    wl = _wl()
     ckpt = str(tmp_path / "ck")
     fp.fused_pbt(wl, checkpoint_dir=ckpt, **KW)
     with pytest.raises(ValueError, match="different sweep"):
         fp.fused_pbt(wl, checkpoint_dir=ckpt, step_chunk=2, **KW)
 
 
-def test_step_chunk_on_mesh_keeps_pop_sharding():
+def test_step_chunk_on_mesh_keeps_pop_sharding(shared_workload):
     """step_chunk adds host-side launch boundaries inside a generation;
     the population must stay sharded over 'pop' across them (XLA output
     shardings propagate through train sub-launches AND the boundary
@@ -197,7 +215,7 @@ def test_step_chunk_on_mesh_keeps_pop_sharding():
     from mpi_opt_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh(n_pop=8, n_data=1)
-    wl = _wl()
+    wl = shared_workload("fashion_mlp", label="pop8 data1 mesh", **MLP)
     res = fp.fused_pbt(
         wl, population=8, generations=2, steps_per_gen=4, seed=0,
         step_chunk=2, mesh=mesh,
@@ -207,42 +225,40 @@ def test_step_chunk_on_mesh_keeps_pop_sharding():
     assert 0.0 <= res["best_score"] <= 1.0
 
 
-def test_step_chunk_accepts_zero_steps_like_unchunked():
+def test_step_chunk_accepts_zero_steps_like_unchunked(wl):
     """Degenerate steps_per_gen=0 (eval/exploit only) must behave the
     same chunked and unchunked — regression: the split once divided by
     zero for total=0."""
-    wl = _wl()
     res = fp.fused_pbt(wl, population=4, generations=2, steps_per_gen=0, step_chunk=2)
     assert len(res["best_curve"]) == 2
 
 
-def test_step_chunk_rejects_gen_chunk_combination():
-    wl = _wl()
+def test_step_chunk_rejects_gen_chunk_combination(wl):
     with pytest.raises(ValueError, match="ambiguous"):
         fp.fused_pbt(
             wl, population=4, generations=4, steps_per_gen=4, gen_chunk=2, step_chunk=2
         )
 
 
-def test_snapshot_last_false_skips_final_save(tmp_path):
+def test_snapshot_last_false_skips_final_save(wl, tmp_path):
     """A bench-style caller consumes the result immediately; the final
     launch's snapshot (a multi-GB, minutes-long host fetch at ResNet
     scale on this platform) must be skippable without losing mid-sweep
     crash protection."""
     import os
 
-    wl = _wl()
     ckpt = str(tmp_path / "ck")
     fp.fused_pbt(wl, checkpoint_dir=ckpt, snapshot_every=2, snapshot_last=False, **KW)
     steps = sorted(int(d) for d in os.listdir(ckpt) if d.isdigit())
     assert steps == [2]  # 4 launches: mid-sweep save kept, final skipped
 
 
-def test_momentum_dtype_mismatch_refuses_resume(tmp_path, monkeypatch):
+def test_momentum_dtype_mismatch_refuses_resume(shared_workload, tmp_path, monkeypatch):
     """Momentum storage dtype is carried-state structure: resuming an
     f32-momentum snapshot under MPI_OPT_TPU_MOMENTUM_DTYPE=bfloat16 must
     refuse cleanly (config mismatch), not crash in the scan carry."""
-    wl = _wl()
+    # an instance of its own: the refused resume builds the bfloat16 trainer first
+    wl = shared_workload("fashion_mlp", label="momentum dtype flips", **MLP)
     ckpt = str(tmp_path / "ck")
     real = fp.run_fused_pbt
     calls = {"n": 0}
@@ -262,8 +278,7 @@ def test_momentum_dtype_mismatch_refuses_resume(tmp_path, monkeypatch):
         fp.fused_pbt(wl, checkpoint_dir=ckpt, **KW)
 
 
-def test_checkpoint_config_mismatch_raises(tmp_path):
-    wl = _wl()
+def test_checkpoint_config_mismatch_raises(wl, tmp_path):
     ckpt = str(tmp_path / "ck")
     fp.fused_pbt(wl, checkpoint_dir=ckpt, **KW)
     other = dict(KW, seed=KW["seed"] + 1)
@@ -271,12 +286,11 @@ def test_checkpoint_config_mismatch_raises(tmp_path):
         fp.fused_pbt(wl, checkpoint_dir=ckpt, **other)
 
 
-def test_sha_crash_resume_bit_identical(tmp_path, monkeypatch):
+def test_sha_crash_resume_bit_identical(wl, tmp_path, monkeypatch):
     """Rung-granular SHA recovery: kill after rung 2, resume, and the
     final result must equal the uninterrupted sweep exactly."""
     import mpi_opt_tpu.train.fused_asha as fa
 
-    wl = _wl()
     kw = dict(n_trials=9, min_budget=2, max_budget=18, eta=3, seed=4)
     whole = fa.fused_sha(wl, **kw)
 
@@ -303,10 +317,9 @@ def test_sha_crash_resume_bit_identical(tmp_path, monkeypatch):
     assert resumed["best_params"] == whole["best_params"]
 
 
-def test_sha_resume_after_completion(tmp_path, monkeypatch):
+def test_sha_resume_after_completion(wl, tmp_path, monkeypatch):
     import mpi_opt_tpu.train.fused_asha as fa
 
-    wl = _wl()
     kw = dict(n_trials=6, min_budget=2, max_budget=6, eta=3, seed=5)
     ckpt = str(tmp_path / "sha")
     first = fa.fused_sha(wl, checkpoint_dir=ckpt, **kw)
@@ -323,10 +336,9 @@ def test_sha_resume_after_completion(tmp_path, monkeypatch):
     assert again["best_trial"] == first["best_trial"]
 
 
-def test_sha_checkpoint_config_mismatch_raises(tmp_path):
+def test_sha_checkpoint_config_mismatch_raises(wl, tmp_path):
     import mpi_opt_tpu.train.fused_asha as fa
 
-    wl = _wl()
     ckpt = str(tmp_path / "sha")
     fa.fused_sha(wl, n_trials=6, min_budget=2, max_budget=6, eta=3, seed=5,
                  checkpoint_dir=ckpt)
@@ -360,13 +372,11 @@ def _arm_preempt(monkeypatch, after_boundaries: int):
     monkeypatch.setattr(sm, "active_signal", lambda: "SIGTERM")
 
 
-def test_tpe_preempt_drain_resume_bit_identical(tmp_path, monkeypatch):
+def test_tpe_preempt_drain_resume_bit_identical(wl, tpe_whole, tmp_path, monkeypatch):
     import mpi_opt_tpu.train.fused_tpe as ft
     from mpi_opt_tpu.health import SweepInterrupted
 
-    wl = _wl()
-    kw = dict(n_trials=9, batch=3, budget=4, seed=3)
-    whole = ft.fused_tpe(wl, **kw)
+    kw, whole = TPE_KW, tpe_whole
 
     ckpt = str(tmp_path / "tpe")
     _arm_preempt(monkeypatch, after_boundaries=1)
@@ -382,15 +392,13 @@ def test_tpe_preempt_drain_resume_bit_identical(tmp_path, monkeypatch):
     assert resumed["best_score"] == whole["best_score"]
 
 
-def test_tpe_crash_resume_reuses_snapshot_boundaries(tmp_path, monkeypatch):
+def test_tpe_crash_resume_reuses_snapshot_boundaries(wl, tpe_whole, tmp_path, monkeypatch):
     """SIGKILL-shaped death one generation after the last snapshot:
     the resume re-trains ONLY the incomplete generations (the crashing
     stub proves gen 1's program never re-runs)."""
     import mpi_opt_tpu.train.fused_tpe as ft
 
-    wl = _wl()
-    kw = dict(n_trials=9, batch=3, budget=4, seed=3)
-    whole = ft.fused_tpe(wl, **kw)
+    kw, whole = TPE_KW, tpe_whole
 
     real = ft.tpe_generation
     calls = {"n": 0}
@@ -419,16 +427,14 @@ def test_tpe_crash_resume_reuses_snapshot_boundaries(tmp_path, monkeypatch):
     assert resumed["best_score"] == whole["best_score"]
 
 
-def test_bohb_crash_resume_bit_identical(tmp_path, monkeypatch):
+def test_bohb_crash_resume_bit_identical(wl, bohb_whole, tmp_path, monkeypatch):
     """Bracket-granular BOHB recovery: die inside the SECOND bracket;
     the resume replays bracket 0 from its final snapshot (its persisted
     cohort reused) and finishes identically to an unkilled run."""
     import mpi_opt_tpu.train.fused_asha as fa
     from mpi_opt_tpu.train.fused_bohb import fused_bohb
 
-    wl = _wl()
-    kw = dict(max_budget=4, eta=2, seed=1, random_fraction=0.5)
-    whole = fused_bohb(wl, **kw)
+    kw, whole = BOHB_KW, bohb_whole
 
     real = fa.fused_sha
     calls = {"n": 0}
@@ -453,13 +459,11 @@ def test_bohb_crash_resume_bit_identical(tmp_path, monkeypatch):
     ]
 
 
-def test_bohb_preempt_drain_resume_bit_identical(tmp_path, monkeypatch):
+def test_bohb_preempt_drain_resume_bit_identical(wl, bohb_whole, tmp_path, monkeypatch):
     from mpi_opt_tpu.health import SweepInterrupted
     from mpi_opt_tpu.train.fused_bohb import fused_bohb
 
-    wl = _wl()
-    kw = dict(max_budget=4, eta=2, seed=1, random_fraction=0.5)
-    whole = fused_bohb(wl, **kw)
+    kw, whole = BOHB_KW, bohb_whole
 
     ckpt = str(tmp_path / "bohb")
     _arm_preempt(monkeypatch, after_boundaries=2)
@@ -472,7 +476,7 @@ def test_bohb_preempt_drain_resume_bit_identical(tmp_path, monkeypatch):
     assert resumed["best_params"] == whole["best_params"]
 
 
-def test_pbt_crash_resume_journal_identical_to_unkilled(tmp_path, monkeypatch):
+def test_pbt_crash_resume_journal_identical_to_unkilled(wl, tmp_path, monkeypatch):
     """The fused-ledger acceptance core at library level: a crashed +
     resumed sweep's journal holds the IDENTICAL record set an unkilled
     run writes (ids, members, boundaries, params, scores), with the
@@ -481,7 +485,6 @@ def test_pbt_crash_resume_journal_identical_to_unkilled(tmp_path, monkeypatch):
 
     from mpi_opt_tpu.ledger import SweepLedger, validate_ledger
 
-    wl = _wl()
     space = wl.default_space()
 
     def open_ledger(path):

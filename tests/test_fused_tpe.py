@@ -4,15 +4,14 @@ import numpy as np
 import pytest
 
 import mpi_opt_tpu.train.fused_tpe as ft
-from mpi_opt_tpu.workloads import get_workload
 
 
-def _wl():
-    return get_workload("fashion_mlp", n_train=256, n_val=128)
+@pytest.fixture
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", n_train=256, n_val=128)
 
 
-def test_fused_tpe_structure_and_determinism():
-    wl = _wl()
+def test_fused_tpe_structure_and_determinism(wl):
     kw = dict(n_trials=10, batch=4, budget=5, seed=0)
     r1 = ft.fused_tpe(wl, **kw)
     # ceil(10/4) = 3 generations: 4 + 4 + 2
@@ -28,8 +27,7 @@ def test_fused_tpe_structure_and_determinism():
     np.testing.assert_array_equal(r2["obs_scores"], r1["obs_scores"])
 
 
-def test_fused_tpe_crash_resume_bit_identical(tmp_path, monkeypatch):
-    wl = _wl()
+def test_fused_tpe_crash_resume_bit_identical(wl, tmp_path, monkeypatch):
     kw = dict(n_trials=8, batch=4, budget=5, seed=3)
     whole = ft.fused_tpe(wl, **kw)
 
@@ -55,15 +53,14 @@ def test_fused_tpe_crash_resume_bit_identical(tmp_path, monkeypatch):
     assert resumed["best_params"] == whole["best_params"]
 
 
-def test_fused_tpe_rejects_zero_trials():
+def test_fused_tpe_rejects_zero_trials(wl):
     with pytest.raises(ValueError, match="n_trials"):
-        ft.fused_tpe(_wl(), n_trials=0)
+        ft.fused_tpe(wl, n_trials=0)
 
 
-def test_fused_tpe_checkpoint_cfg_mismatch_raises(tmp_path):
+def test_fused_tpe_checkpoint_cfg_mismatch_raises(wl, tmp_path):
     from mpi_opt_tpu.ops.tpe import TPEConfig
 
-    wl = _wl()
     ckpt = str(tmp_path / "tpe")
     ft.fused_tpe(wl, n_trials=4, batch=4, budget=3, seed=1, checkpoint_dir=ckpt)
     with pytest.raises(ValueError, match="different sweep"):

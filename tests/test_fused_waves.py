@@ -23,17 +23,21 @@ import jax
 import mpi_opt_tpu.train.fused_pbt as fp
 from mpi_opt_tpu.health import shutdown
 from mpi_opt_tpu.ops.pbt import PBTConfig
-from mpi_opt_tpu.workloads import get_workload
 
 
 @pytest.fixture(scope="module")
-def wl():
-    # one instance for the whole module: workload_arrays caches the
-    # trainer on it, so every test shares one compile set
-    return get_workload("fashion_mlp", n_train=256, n_val=128)
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", n_train=256, n_val=128)
 
 
 KW = dict(population=8, generations=3, steps_per_gen=4, seed=2)
+
+
+@pytest.fixture(scope="module")
+def waves_of_3(wl):
+    """The undisturbed wave sweep the drills below end equal to, run
+    once a module (read, never written into)."""
+    return fp.fused_pbt(wl, wave_size=3, **KW)
 
 
 def _tree_equal(a, b):
@@ -63,13 +67,13 @@ def test_wave_mode_bit_identical_to_resident(wl):
     assert wav["stage_transfer_s"] >= 0 and wav["stage_overlap_s"] >= 0
 
 
-def test_wave_mode_bit_identical_on_mesh():
+def test_wave_mode_bit_identical_on_mesh(shared_workload):
     """Same parity on the virtual 8-device CPU mesh: waves shard over
     'pop' (W=8 divides the axis) and the result still matches the
     resident sharded sweep exactly."""
     from mpi_opt_tpu.parallel.mesh import make_mesh
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", label="pop8 data1 mesh", n_train=256, n_val=128)
     mesh = make_mesh(n_pop=8, n_data=1)
     kw = dict(population=16, generations=2, steps_per_gen=3, seed=3)
     res = fp.fused_pbt(wl, mesh=mesh, **kw)
@@ -125,10 +129,10 @@ def test_full_population_exploit_crosses_wave_boundaries(wl):
     assert 0.0 <= wav["best_score"] <= 1.0
 
 
-def test_wave_crash_resume_bit_identical(wl, tmp_path):
+def test_wave_crash_resume_bit_identical(wl, waves_of_3, tmp_path):
     """Hard crash mid-sweep: resume from the generation-boundary
     snapshot finishes with the uninterrupted sweep's exact result."""
-    whole = fp.fused_pbt(wl, wave_size=3, **KW)
+    whole = waves_of_3
     real = fp._run_wave
     calls = {"n": 0}
 
@@ -152,12 +156,12 @@ def test_wave_crash_resume_bit_identical(wl, tmp_path):
     assert len(resumed["launch_walls"]) == KW["generations"]
 
 
-def test_wave_preempt_between_waves_resumes_without_retraining(wl, tmp_path):
+def test_wave_preempt_between_waves_resumes_without_retraining(wl, waves_of_3, tmp_path):
     """Graceful shutdown BETWEEN waves flushes a mid-generation
     snapshot; the resume re-trains only the remaining waves (completed
     waves' states come from the host pools) and still reproduces the
     clean run bit-for-bit."""
-    whole = fp.fused_pbt(wl, wave_size=3, **KW)
+    whole = waves_of_3
     ckpt = str(tmp_path / "ck")
     real = fp._run_wave
     calls = {"n": 0}
@@ -195,7 +199,7 @@ def test_wave_preempt_between_waves_resumes_without_retraining(wl, tmp_path):
     assert _tree_equal(resumed["state"].params, whole["state"].params)
 
 
-def test_wave_corrupt_snapshot_falls_back_bit_identical(wl, tmp_path):
+def test_wave_corrupt_snapshot_falls_back_bit_identical(wl, waves_of_3, tmp_path):
     """The ISSUE-5 acceptance drill for wave sweeps: kill mid-sweep,
     bit-rot the LATEST snapshot, resume — restore quarantines the bad
     step (kept as evidence, not deleted), falls back to the previous
@@ -207,7 +211,7 @@ def test_wave_corrupt_snapshot_falls_back_bit_identical(wl, tmp_path):
     from mpi_opt_tpu.utils import integrity
     from mpi_opt_tpu.workloads.chaos import inject_corrupt_save
 
-    whole = fp.fused_pbt(wl, wave_size=3, **KW)
+    whole = waves_of_3
     real = fp._run_wave
     calls = {"n": 0}
 
@@ -415,7 +419,7 @@ def test_staging_engine_beats_heartbeat_per_transfer(tmp_path):
         heartbeat.deconfigure()
 
 
-def test_wave_journal_identical_to_resident(tmp_path):
+def test_wave_journal_identical_to_resident(wl, tmp_path):
     """Wave scheduling is bit-identical to resident mode, so one ledger
     records the same trajectory either way: the journaled record sets
     (ids, members, boundaries, params, scores) must be EQUAL — which is
@@ -424,7 +428,6 @@ def test_wave_journal_identical_to_resident(tmp_path):
 
     from mpi_opt_tpu.ledger import SweepLedger, validate_ledger
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
     space = wl.default_space()
     kw = dict(population=6, generations=2, steps_per_gen=3, seed=2)
 

@@ -195,16 +195,15 @@ def test_driver_completes_when_preempted_on_the_final_batch():
     assert res.n_trials == 1 and res.best is not None
 
 
-def test_fused_step_chunk_sub_launches_beat(tmp_path):
+def test_fused_step_chunk_sub_launches_beat(shared_workload, tmp_path):
     """Sub-launch heartbeat granularity (ROADMAP follow-up): a
     step-chunked fused PBT generation beats once per train sub-segment,
     so --stall-timeout can be sized to one step_chunk instead of a
     whole generation's train_segment scan."""
     from mpi_opt_tpu.health import heartbeat
     from mpi_opt_tpu.train.fused_pbt import fused_pbt
-    from mpi_opt_tpu.workloads import get_workload
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128)
     hb_path = str(tmp_path / "rank.hb")
     hb = heartbeat.configure(hb_path)
     try:

@@ -83,10 +83,10 @@ def test_hyperband_checkpoint_rejects_mismatched_config():
         b.load_state_dict(a.state_dict())
 
 
-def test_fused_hyperband():
+def test_fused_hyperband(shared_workload):
     from mpi_opt_tpu.train.fused_asha import fused_hyperband
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128)
     res = fused_hyperband(wl, max_budget=12, eta=3, seed=0)
     # R=12: brackets (6@1(rounded), ...) — just check structural contract
     assert res["n_trials"] == sum(b["n_trials"] for b in res["brackets"])
@@ -97,13 +97,13 @@ def test_fused_hyperband():
     assert res["best_score"] == max(b["best_score"] for b in res["brackets"])
 
 
-def test_fused_hyperband_checkpoint_resume(tmp_path, monkeypatch):
+def test_fused_hyperband_checkpoint_resume(shared_workload, tmp_path, monkeypatch):
     """Bracket-granular recovery: each bracket checkpoints its rungs in
     its own subdirectory; completed brackets replay without re-running."""
     import mpi_opt_tpu.train.fused_asha as fa
     from mpi_opt_tpu.train.fused_asha import fused_hyperband
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128)
     kw = dict(max_budget=6, eta=3, seed=2)
     whole = fused_hyperband(wl, **kw)
 

@@ -32,12 +32,11 @@ from mpi_opt_tpu.ledger.report import (
     summarize_ledger,
 )
 from mpi_opt_tpu.ledger.warmstart import best_observation, load_observations
-from mpi_opt_tpu.workloads import get_workload
 
 
 @pytest.fixture(scope="module")
-def space():
-    return get_workload("fashion_mlp", n_train=64, n_val=32).default_space()
+def space(shared_workload):
+    return shared_workload("fashion_mlp", n_train=64, n_val=32).default_space()
 
 
 def _fused_ledger(tmp_path, space, name="fused.jsonl"):

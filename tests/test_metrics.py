@@ -45,13 +45,12 @@ def test_wall_to_target_launchwise_uses_measured_boundaries():
         wall_to_target_launchwise(curve, [2, 3], [10.0, 30.0], 0.75)
 
 
-def test_fused_pbt_reports_launch_walls():
+def test_fused_pbt_reports_launch_walls(shared_workload):
     """The fused sweep returns measured per-launch durations aligned with
     its launch split, and a resumed sweep restores pre-crash durations."""
     from mpi_opt_tpu.train.fused_pbt import fused_pbt
-    from mpi_opt_tpu.workloads import get_workload
 
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256)
     res = fused_pbt(wl, population=4, generations=3, steps_per_gen=2, seed=0, gen_chunk=2)
     assert res["launch_gens"] == [2, 1]
     assert len(res["launch_walls"]) == 2

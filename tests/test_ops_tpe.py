@@ -4,6 +4,10 @@ import numpy as np
 
 from mpi_opt_tpu.ops import TPEConfig, tpe_suggest
 
+# as every caller in the package runs it: one program. Op by op its
+# hundred small compiles were most of this file's time
+suggest = jax.jit(tpe_suggest, static_argnames=("n_suggest", "cfg"))
+
 
 def _buffer(M, d, n_valid, fn, seed=0):
     """Fill a ring buffer with n_valid observations scored by fn."""
@@ -19,7 +23,7 @@ def test_empty_buffer_degrades_to_uniform():
     pts = jnp.zeros((M, d))
     scores = jnp.zeros((M,))
     valid = jnp.zeros((M,), dtype=bool)
-    sugg, acq = tpe_suggest(jax.random.key(0), pts, scores, valid, n_suggest=16)
+    sugg, acq = suggest(jax.random.key(0), pts, scores, valid, n_suggest=16)
     assert sugg.shape == (16, 3)
     arr = np.asarray(sugg)
     assert arr.min() >= 0 and arr.max() <= 1
@@ -33,7 +37,7 @@ def test_suggestions_concentrate_near_optimum():
     fn = lambda x: -jnp.sum((x - 0.8) ** 2, axis=-1)
     pts, scores, valid = _buffer(M, d, n_valid=100, fn=fn)
     cfg = TPEConfig(gamma=0.2, n_candidates=2048)
-    sugg, acq = tpe_suggest(jax.random.key(1), pts, scores, valid, n_suggest=8, cfg=cfg)
+    sugg, acq = suggest(jax.random.key(1), pts, scores, valid, n_suggest=8, cfg=cfg)
     # suggested points should be much closer to the optimum than uniform (mean dist ~0.46)
     dist = np.linalg.norm(np.asarray(sugg) - 0.8, axis=-1)
     assert dist.mean() < 0.25
@@ -58,7 +62,7 @@ def test_respects_higher_is_better():
     M, d = 128, 1
     fn = lambda x: -jnp.abs(x[:, 0] - 0.2)
     pts, scores, valid = _buffer(M, d, 90, fn, seed=3)
-    sugg, _ = tpe_suggest(jax.random.key(2), pts, scores, valid, n_suggest=8)
+    sugg, _ = suggest(jax.random.key(2), pts, scores, valid, n_suggest=8)
     assert np.abs(np.asarray(sugg) - 0.2).mean() < np.abs(np.asarray(sugg) - 0.8).mean()
 
 
@@ -73,8 +77,8 @@ def test_batched_suggest_diversity():
     k = 16
     plain = TPEConfig(n_candidates=2048, diversify_bw=0.0)
     div = TPEConfig(n_candidates=2048)  # defaults: diversify on
-    s_plain, _ = tpe_suggest(key, pts, scores, valid, n_suggest=k, cfg=plain)
-    s_div, a_div = tpe_suggest(key, pts, scores, valid, n_suggest=k, cfg=div)
+    s_plain, _ = suggest(key, pts, scores, valid, n_suggest=k, cfg=plain)
+    s_div, a_div = suggest(key, pts, scores, valid, n_suggest=k, cfg=div)
 
     def mean_pairwise(s):
         s = np.asarray(s)
@@ -101,7 +105,7 @@ def test_single_suggest_unchanged_by_diversity():
     fn = lambda x: x[:, 0]
     pts, scores, valid = _buffer(M, d, 40, fn, seed=2)
     key = jax.random.key(4)
-    s1, a1 = tpe_suggest(key, pts, scores, valid, n_suggest=1, cfg=TPEConfig())
-    s2, a2 = tpe_suggest(key, pts, scores, valid, n_suggest=1, cfg=TPEConfig(diversify_bw=0.0))
+    s1, a1 = suggest(key, pts, scores, valid, n_suggest=1, cfg=TPEConfig())
+    s2, a2 = suggest(key, pts, scores, valid, n_suggest=1, cfg=TPEConfig(diversify_bw=0.0))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2))
     np.testing.assert_allclose(np.asarray(a1), np.asarray(a2))

@@ -7,7 +7,6 @@ import pytest
 from mpi_opt_tpu.ops.pbt import PBTConfig
 from mpi_opt_tpu.parallel import make_mesh, pop_sharding, shard_popstate
 from mpi_opt_tpu.train.fused_pbt import fused_pbt
-from mpi_opt_tpu.workloads import get_workload
 
 
 def test_make_mesh_shapes():
@@ -21,11 +20,25 @@ def test_make_mesh_shapes():
         make_mesh(n_pop=16, n_data=1)
 
 
+MLP = dict(n_train=512, n_val=256, attrs={"batch_size": 32})
+
+
 @pytest.fixture(scope="module")
-def workload():
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
-    wl.batch_size = 32
-    return wl
+def workload(shared_workload):
+    return shared_workload("fashion_mlp", **MLP)
+
+
+@pytest.fixture(scope="module")
+def meshed(shared_workload):
+    """``meshed(n_pop, n_data)``: the mesh, and the instance that keeps
+    the trainer built for it (one trainer an instance: the unmeshed
+    sweeps' stays on ``workload``)."""
+
+    def meshed(n_pop, n_data):
+        wl = shared_workload("fashion_mlp", label=f"pop{n_pop} data{n_data} mesh", **MLP)
+        return make_mesh(n_pop=n_pop, n_data=n_data), wl
+
+    return meshed
 
 
 def test_fused_pbt_learns(workload):
@@ -37,7 +50,7 @@ def test_fused_pbt_learns(workload):
     assert set(r["best_params"]) == {"lr", "momentum", "weight_decay", "flip_prob", "shift"}
 
 
-def test_fused_pbt_sharded_matches_unsharded(workload):
+def test_fused_pbt_sharded_matches_unsharded(workload, meshed):
     """The same fused sweep over a ('pop','data') mesh must produce the
     same result — sharding is a layout, not a semantics change.
 
@@ -45,8 +58,8 @@ def test_fused_pbt_sharded_matches_unsharded(workload):
     reduction-order noise over 20 training steps); 0.02 leaves margin
     without hiding a real semantics change."""
     r1 = fused_pbt(workload, population=8, generations=2, steps_per_gen=10, seed=3)
-    mesh = make_mesh(n_pop=4, n_data=2)
-    r2 = fused_pbt(workload, population=8, generations=2, steps_per_gen=10, seed=3, mesh=mesh)
+    mesh, on_mesh = meshed(4, 2)
+    r2 = fused_pbt(on_mesh, population=8, generations=2, steps_per_gen=10, seed=3, mesh=mesh)
     assert r2["best_score"] == pytest.approx(r1["best_score"], abs=0.02)
     np.testing.assert_allclose(r2["mean_curve"], r1["mean_curve"], atol=0.02)
 
@@ -212,12 +225,12 @@ class TestInitializeMultihost:
             initialize_multihost(num_processes=2)
 
 
-def test_fused_pbt_final_state_sharded(workload):
+def test_fused_pbt_final_state_sharded(meshed):
     """The fused sweep's carried population must END sharded over 'pop'
     — if any launch-boundary op (exploit gather, snapshot round-trip)
     dropped the placement, multi-chip sweeps would silently degrade to
     replicated execution."""
-    mesh = make_mesh(n_pop=8, n_data=1)
+    mesh, workload = meshed(8, 1)
     r = fused_pbt(workload, population=8, generations=2, steps_per_gen=5, seed=1, mesh=mesh)
     leaves = jax.tree.leaves(r["state"].params)
     assert leaves, "fused_pbt result carries no state"
@@ -226,26 +239,26 @@ def test_fused_pbt_final_state_sharded(workload):
         assert not leaf.sharding.is_fully_replicated
 
 
-def test_fused_tpe_sharded_matches_unsharded(workload):
+def test_fused_tpe_sharded_matches_unsharded(workload, meshed):
     """Fused TPE over a mesh (incl. a tail generation that does not
     divide the 'pop' axis) must match the single-device trajectory."""
     from mpi_opt_tpu.train.fused_tpe import fused_tpe
 
     kw = dict(n_trials=12, batch=8, budget=5, seed=4)
     r1 = fused_tpe(workload, **kw)
-    mesh = make_mesh(n_pop=8, n_data=1)
-    r2 = fused_tpe(workload, mesh=mesh, **kw)
+    mesh, on_mesh = meshed(8, 1)
+    r2 = fused_tpe(on_mesh, mesh=mesh, **kw)
     assert r2["best_score"] == pytest.approx(r1["best_score"], abs=0.02)
     np.testing.assert_allclose(r2["best_curve"], r1["best_curve"], atol=0.02)
 
 
-def test_fused_sha_sharded_rounds_survivors_to_pop_axis(workload):
+def test_fused_sha_sharded_rounds_survivors_to_pop_axis(meshed):
     """On a mesh, rung survivor counts round UP to the 'pop' axis so
     cohorts stay shardable; a 16-trial eta-4 sweep on an 8-way mesh
     keeps 8 (not 4) survivors."""
     from mpi_opt_tpu.train.fused_asha import fused_sha
 
-    mesh = make_mesh(n_pop=8, n_data=1)
+    mesh, workload = meshed(8, 1)
     r = fused_sha(
         workload, n_trials=16, min_budget=5, max_budget=20, eta=4, seed=2, mesh=mesh
     )

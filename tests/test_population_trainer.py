@@ -279,7 +279,7 @@ def test_momentum_storage_dtype_knob(setup):
     assert float(acc1.max()) > float(acc0.max()) + 0.1
 
 
-def test_fused_pbt_gen_chunked_launches():
+def test_fused_pbt_gen_chunked_launches(shared_workload):
     """gen_chunk is pure launch-splitting: population state AND the
     scan-carried RNG key thread through launches, so a chunked sweep
     must be BIT-IDENTICAL to the single-launch sweep — same curves,
@@ -289,7 +289,7 @@ def test_fused_pbt_gen_chunked_launches():
     from mpi_opt_tpu.train.fused_pbt import fused_pbt
     from mpi_opt_tpu.workloads import get_workload
 
-    wl = get_workload("fashion_mlp", n_train=512, n_val=256)
+    wl = shared_workload("fashion_mlp", n_train=512, n_val=256)
     kw = dict(population=8, generations=3, steps_per_gen=10, seed=0)
     whole = fused_pbt(wl, gen_chunk=0, **kw)
     chunked = fused_pbt(wl, gen_chunk=2, **kw)  # balanced split [2, 1]
@@ -300,13 +300,13 @@ def test_fused_pbt_gen_chunked_launches():
     assert chunked["best_score"] == whole["best_score"]
 
 
-def test_fused_pbt_rejects_zero_generations():
+def test_fused_pbt_rejects_zero_generations(shared_workload):
     import pytest
 
     from mpi_opt_tpu.train.fused_pbt import fused_pbt
     from mpi_opt_tpu.workloads import get_workload
 
-    wl = get_workload("fashion_mlp", n_train=256, n_val=128)
+    wl = shared_workload("fashion_mlp", n_train=256, n_val=128)
     with pytest.raises(ValueError, match="generations"):
         fused_pbt(wl, population=4, generations=0, steps_per_gen=5)
 

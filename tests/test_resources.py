@@ -31,7 +31,6 @@ from mpi_opt_tpu.cli import main as cli_main
 from mpi_opt_tpu.service import tenants as tstates
 from mpi_opt_tpu.utils import resources
 from mpi_opt_tpu.utils.exitcodes import EX_IOERR, classify
-from mpi_opt_tpu.workloads import get_workload
 from mpi_opt_tpu.workloads.chaos import (
     DiskFullInjector,
     OOMInjector,
@@ -41,10 +40,8 @@ from mpi_opt_tpu.workloads.chaos import (
 
 
 @pytest.fixture(scope="module")
-def wl():
-    # one instance for the whole module: workload_arrays caches the
-    # trainer on it, so every test shares one compile set
-    return get_workload("fashion_mlp", n_train=256, n_val=128)
+def wl(shared_workload):
+    return shared_workload("fashion_mlp", n_train=256, n_val=128)
 
 
 KW = dict(population=8, generations=3, steps_per_gen=4, seed=2)

@@ -174,12 +174,14 @@ def _census(jaxpr, counts=None):
 
 
 @pytest.mark.parametrize(
-    "kernels, dtype",
-    [(True, jnp.float32), (True, jnp.bfloat16), (False, jnp.float32)],
+    "kernels, dtype, layers",
+    # the nest of two remats is one code for both paths: two layers of it on
+    # XLA's own products, one where the interpreted kernels make a layer dear
+    [(True, jnp.float32, 1), (True, jnp.bfloat16, 1), (False, jnp.float32, 2)],
     ids=["kernels-float32", "kernels-bfloat16", "xla-float32"],
 )
-def test_a_train_step_runs_each_tiles_kernel_and_selection_once(monkeypatch, kernels, dtype):
-    """Two layers of two query tiles under ``grad``: the layer's remat
+def test_a_train_step_runs_each_tiles_kernel_and_selection_once(monkeypatch, kernels, dtype, layers):
+    """Layers of two query tiles under ``grad``: the layer's remat
     and the tile's checkpoint both save the selection mask and the
     forward kernel's context and log-sum-exp by name
     (``saved_for_backward``), so the program holds ONE forward kernel
@@ -193,14 +195,15 @@ def test_a_train_step_runs_each_tiles_kernel_and_selection_once(monkeypatch, ker
     monkeypatch.setattr(smd, "COMPUTE_DTYPE", dtype)
     monkeypatch.setattr(smd, "use_kernels", lambda dims, positions: kernels)
     dims = smd.DecoderDims(
-        vocab=64, hidden=32, layers=2, heads=2, kv_heads=1, head_dim=128, index_heads=2, index_dim=16,
+        vocab=64, hidden=32, layers=layers, heads=2, kv_heads=1, head_dim=128, index_heads=2, index_dim=16,
         top_k_keys=96, q_chunk=128, experts_published=8, experts_held=2, experts_per_token=2,
         expert_width=16, expert_capacity=0, loss_rows=128,
     )
     model = smd.SparseMoEDecoder(dims)
     tokens = jax.random.randint(jax.random.key(0), (257,), 0, dims.vocab)
     x, y = tokens[:-1], tokens[1:]
-    params = model.init(jax.random.key(1), x, y)["params"]
+    # one program: op by op, the interpreted kernels make the forward pass of an init dear
+    params = jax.jit(model.init)(jax.random.key(1), x, y)["params"]
 
     def loss(p):
         ce, index_loss, counts = model.apply({"params": p}, x, y)
@@ -208,8 +211,10 @@ def test_a_train_step_runs_each_tiles_kernel_and_selection_once(monkeypatch, ker
 
     def trace():
         step = jax.value_and_grad(loss, has_aux=True)
-        exact = jax.jit(step, compiler_options={"xla_allow_excess_precision": False})
-        return _census(jax.make_jaxpr(step)(params).jaxpr), exact(params)
+        # traced once: the census is of the program that then runs
+        traced = jax.jit(step).trace(params)
+        exact = traced.lower().compile(compiler_options={"xla_allow_excess_precision": False})
+        return _census(traced.jaxpr.jaxpr), exact(params)
 
     tiles = dims.layers * (x.shape[0] // dims.q_chunk)
     once = {"selection loop": tiles, **({"fwd": tiles, "dq": tiles, "dkv": tiles} if kernels else {})}

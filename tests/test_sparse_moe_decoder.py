@@ -108,9 +108,11 @@ def test_loss_and_every_gradient_match_the_reference(workload, ref, cfg, members
     bx, by = jnp.asarray(data["train_x"][:2]), jnp.asarray(data["train_y"][:2])
     params = jax.tree.map(lambda a: a[1], members)
     want_params, _ = ref.init_member(1)
-    want, want_grads = jax.value_and_grad(
+    # one program, as the harness runs the reference (common.make_member_programs):
+    # op by op its hundred small compiles were most of this test's time
+    want, want_grads = jax.jit(jax.value_and_grad(
         lambda p: ref.model.loss(p, None, None, bx, by, "f32", cfg)
-    )(want_params)
+    ))(want_params)
     for capacity in (63, 4):
         model = smd.SparseMoEDecoder(_dims(workload, expert_capacity=capacity))
         (got, counts), grads = jax.jit(jax.value_and_grad(
