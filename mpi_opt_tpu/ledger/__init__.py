@@ -2,7 +2,8 @@
 
 The coordinator's trial history IS the product of a long HPO sweep, and
 this package makes it durable at TRIAL granularity: ``store.SweepLedger``
-appends one fsync'd JSONL record per FINAL TrialResult, the driver
+appends one fsync'd JSONL record per FINAL TrialResult (a fused
+boundary's member records as one block, one fsync), the driver
 replays completed records through the algorithm on resume
 (``run_search(ledger=...)``), ``cache.EvalCache`` skips re-evaluating
 exactly-seen params, ``warmstart`` feeds a prior sweep's ledger into a

@@ -12,9 +12,10 @@ and a status derived from the score's finiteness (the same non-finite
 rule the fused member-failure tallies apply).
 
 Ordering contract (the fused twin of the driver's fsync-before-report
-invariant): a boundary's records are journaled BEFORE that boundary's
-snapshot is saved, so the journal can never lag the snapshot it will
-be replayed against. Consequences:
+invariant): a boundary's records are journaled as ONE block — written
+and flushed one by one, fsync'd once at its end — and made durable
+BEFORE that boundary's snapshot is saved, so the journal can never lag
+the snapshot it will be replayed against. Consequences:
 
 - the only append-crash damage shape is a torn FINAL boundary (no
   snapshot covers it — ``SweepLedger`` truncates it on load and the
@@ -109,16 +110,20 @@ class FusedJournal:
 
     def record_boundary(
         self, b_local: int, members, units, scores, step: int, scores_mo=None
-    ) -> None:
-        """Journal (or verify) one boundary's member records.
+    ) -> int:
+        """Journal (or verify) one boundary's member records; returns
+        the fsyncs the boundary cost.
 
         ``members`` are the boundary's member identities (local — the
         journal applies ``member_offset``), ``units`` their unit-cube
         rows, ``scores`` their evaluation scores, ``step`` the budget
-        the scores were measured at. First visit appends one fsync'd
-        record per member; a re-computed boundary (resume) verifies
-        status/score against the journal instead — divergence raises
-        ``LedgerError`` (the journal belongs to a different trajectory).
+        the scores were measured at. First visit decodes the unit rows
+        in one pass and appends one record per member inside one
+        ``batched()`` block: durable together, one fsync, before this
+        returns (inside a caller's open block, at that block's exit
+        instead). A re-computed boundary (resume) verifies status/score
+        against the journal instead — divergence raises ``LedgerError``
+        (the journal belongs to a different trajectory).
 
         ``scores_mo`` (optional ``[n, m]`` raw objective matrix, ISSUE
         17) rides each record as its ``scores`` vector; ``scores``
@@ -135,7 +140,7 @@ class FusedJournal:
         existing = self._by_boundary.get(b)
         if existing is not None:
             self._verify(b, members, scores, scores_mo)
-            return
+            return 0
         # trial ids are the journal's record ordinals, derived from the
         # already-journaled boundaries of THIS view so a resume that
         # skipped straight past completed boundaries still numbers
@@ -145,24 +150,26 @@ class FusedJournal:
             for k in self._by_boundary
             if self.boundary_offset <= k < b
         )
+        params = self.space.materialize_rows(units)
         grp: dict[int, dict] = {}
-        for i, m in enumerate(members):
-            rec = self.ledger.record_member(
-                trial_id=base + i,
-                member=self.member_offset + m,
-                boundary=b,
-                boundary_size=len(members),
-                canonical_params=self.space.canonical_params(
-                    self.space.materialize_row(units[i])
-                ),
-                score=scores[i],
-                step=step,
-                scores=None if scores_mo is None else scores_mo[i],
-            )
-            grp[self.member_offset + m] = rec
+        before = self.ledger.n_fsyncs
+        with self.ledger.batched():
+            for i, m in enumerate(members):
+                rec = self.ledger.record_member(
+                    trial_id=base + i,
+                    member=self.member_offset + m,
+                    boundary=b,
+                    boundary_size=len(members),
+                    canonical_params=self.space.canonical_params(params[i]),
+                    score=scores[i],
+                    step=step,
+                    scores=None if scores_mo is None else scores_mo[i],
+                )
+                grp[self.member_offset + m] = rec
         self._by_boundary[b] = grp
         self._sizes[b] = len(members)
         self.written += len(members)
+        return self.ledger.n_fsyncs - before
 
     def _verify(self, b: int, members, scores, scores_mo=None) -> None:
         """The resume cross-check: a re-computed boundary must match its

@@ -28,9 +28,13 @@ records for the last boundary) — recoverable exactly like a torn tail
 line, because the journal-before-snapshot ordering guarantees no
 snapshot ever covers a partially-journaled boundary.
 
-Durability contract: each record is flushed AND fsync'd before the
-driver reports it to the algorithm (fused: before the boundary's
-snapshot is saved), so the journal can never lag the search state it
+Durability contract: a driver trial's record is flushed AND fsync'd
+before the driver reports it to the algorithm; a fused boundary's
+records are flushed one by one and fsync'd ONCE, as a block, before
+the boundary's snapshot is saved and before the next launch (the
+boundary is the fused unit of durability: a crash mid-block loses the
+whole boundary or none of it, exactly as a crash between per-record
+fsyncs did). Either way the journal can never lag the search state it
 will be replayed into. Recovery is tolerant of exactly the failures
 append-fsync can produce — a TORN FINAL LINE (the process died
 mid-write): the tail fragment is truncated away on load and the
@@ -358,6 +362,7 @@ class SweepLedger:
             if (self.n_torn or self.n_torn_boundary) and not self.read_only:
                 self._rewrite_complete_records()
         self._defer_fsync = False
+        self.n_fsyncs = 0  # fsyncs of the append handle (span attr ``fsyncs``)
         if self.read_only:
             self._file = None
             return
@@ -496,14 +501,18 @@ class SweepLedger:
         the fsync; exit fsyncs ONCE, so the whole batch becomes durable
         together. This is the HTTP front door's journal-before-ack at
         batch granularity (the answer is published only after the block
-        exits). Crash-safety shape: a kill mid-batch leaves a flushed
-        prefix (page cache survives a process SIGKILL) and possibly a
-        torn tail — exactly the damage the load-time torn-tail self-heal
-        already recovers, and the client's idempotent retry re-journals
-        whatever the prefix lost. Not reentrant; single-writer only
-        (the front door's one executor thread)."""
+        exits) and a fused boundary's journal-before-snapshot
+        (``FusedJournal.record_boundary``). Crash-safety shape: a kill
+        mid-batch leaves a flushed prefix (page cache survives a process
+        SIGKILL) and possibly a torn tail — exactly the damage the
+        load-time torn-tail (and torn-final-boundary) self-heal already
+        recovers, and the client's idempotent retry re-journals whatever
+        the prefix lost. Single-writer only (the front door's one
+        executor thread). A nested block joins the open one: the outer
+        exit's one fsync makes both durable."""
         if self._defer_fsync:
-            raise LedgerError("ledger.batched() does not nest")
+            yield self
+            return
         self._defer_fsync = True
         try:
             yield self
@@ -513,6 +522,7 @@ class SweepLedger:
                 try:
                     self._file.flush()
                     os.fsync(self._file.fileno())
+                    self.n_fsyncs += 1
                 except OSError as e:
                     from mpi_opt_tpu.utils import resources
 
@@ -587,7 +597,8 @@ class SweepLedger:
         scores=None,
     ) -> dict:
         """Journal one fused population member's boundary evaluation
-        (``ledger/fused.py`` drives this); durable before returning.
+        (``ledger/fused.py`` drives this, inside one ``batched()``
+        block a boundary: durable when that block exits).
 
         Status derives from the score's finiteness — the same rule the
         fused trainers' member-failure tallies apply: a non-finite
@@ -643,6 +654,9 @@ class SweepLedger:
         return rec
 
     def _write_line(self, rec: dict) -> None:
+        """Append one record: write and flush always, fsync unless a
+        ``batched()`` block is open (its exit fsyncs the block once).
+        Every fsync of the append handle counts in ``n_fsyncs``."""
         from mpi_opt_tpu.utils import resources
 
         try:
@@ -653,6 +667,7 @@ class SweepLedger:
             self._file.flush()
             if not self._defer_fsync:
                 os.fsync(self._file.fileno())
+                self.n_fsyncs += 1
         except OSError as e:
             if resources.is_storage_full(e):
                 # a full disk is an ANSWER, not a retryable blip: park

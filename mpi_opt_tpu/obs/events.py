@@ -209,6 +209,7 @@ SPAN_ATTRS = frozenset(
         "members",  # population members in the phase
         "steps",  # train steps in the segment
         "n",  # generic count (journal records, suggest batch)
+        "fsyncs",  # ledger fsyncs the phase cost (journal; set at exit)
         "items",  # manifest items (digest)
         "bytes",  # bytes moved (stage_in/stage_out; set at exit)
         "flops",  # segment FLOPs for achieved TF/s (set at exit)

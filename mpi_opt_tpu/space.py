@@ -237,20 +237,30 @@ class SearchSpace:
     # -- host-side edges --------------------------------------------------
 
     def materialize_row(self, u_row: np.ndarray) -> dict[str, Any]:
-        """One unit-cube row -> a plain-Python hparam dict (host side).
+        """One unit-cube row -> a plain-Python hparam dict (host side)."""
+        return self.materialize_rows(np.asarray(u_row)[None])[0]
 
-        CPU-pinned: this runs one tiny ``from_unit`` op per dimension
-        per trial — on the default device each is a dispatch and a
-        blocking fetch (utils.hostdev).
+    def materialize_rows(self, units: np.ndarray) -> list[dict[str, Any]]:
+        """Unit-cube rows ``[n, d]`` -> one plain-Python hparam dict a
+        row (host side).
+
+        One ``from_unit`` a dimension over the whole column, in float32
+        as the rows arrive: elementwise, so each value is bit-identical
+        to decoding its row alone (a fused boundary's 512 records cost
+        ``d`` ops, not ``512 * d``). CPU-pinned: on the default device
+        each op is a dispatch and a blocking fetch (utils.hostdev).
         """
         from mpi_opt_tpu.utils.hostdev import host_ops
 
-        out = {}
+        units = np.asarray(units)
+        cols = {}
         with host_ops():
             for i, (name, dom) in enumerate(self.domains.items()):
-                v = np.asarray(dom.from_unit(jnp.asarray(u_row[i])))
-                out[name] = dom.materialize(v)
-        return out
+                cols[name] = np.asarray(dom.from_unit(jnp.asarray(units[:, i])))
+        return [
+            {name: dom.materialize(cols[name][j]) for name, dom in self.domains.items()}
+            for j in range(units.shape[0])
+        ]
 
     def discrete_mask(self) -> np.ndarray:
         """bool[d]: which dims are discrete (used by TPE/PBT perturbation)."""

@@ -303,3 +303,138 @@ def test_open_ledger_reentry_heals_partial_boundary(tmp_path, space):
     assert validate_ledger(led.path) == []
     recs = [json.loads(l) for l in open(led.path).read().splitlines()[1:]]
     assert [r["trial_id"] for r in recs] == list(range(6))
+
+
+# -- one block a boundary: one fsync, the per-record path's lines ----------
+
+
+def _decode_per_element(space, row):
+    """The per-record decode the block replaced: one scalar
+    ``from_unit`` a dimension (the values ``params_key`` has always
+    keyed), kept here as the reference ``materialize_rows`` must match."""
+    import jax.numpy as jnp
+
+    from mpi_opt_tpu.utils.hostdev import host_ops
+
+    with host_ops():
+        return {
+            name: dom.materialize(np.asarray(dom.from_unit(jnp.asarray(row[i]))))
+            for i, (name, dom) in enumerate(space.domains.items())
+        }
+
+
+def _count_fsyncs(monkeypatch):
+    fsyncs = []
+    real_fsync = os.fsync
+    monkeypatch.setattr(os, "fsync", lambda fd: (fsyncs.append(fd), real_fsync(fd)))
+    return fsyncs
+
+
+def _lines(path):
+    return [json.loads(l) for l in open(path).read().splitlines()[1:]]
+
+
+@pytest.mark.parametrize("n", [1, 32, 512])
+def test_boundary_is_one_block_with_one_fsync(tmp_path, space, monkeypatch, n):
+    from mpi_opt_tpu.obs import trace
+    from mpi_opt_tpu.obs.report import load_stream
+    from mpi_opt_tpu.train.common import journal_boundary
+    from mpi_opt_tpu.utils.metrics import MetricsLogger
+
+    led = _fused_ledger(tmp_path, space)
+    u = _units(n, space, seed=n)
+    u[0] = 1.0  # the unit cube's far corner decodes like any other row
+    members = list(range(n))
+    scores = np.linspace(0.1, 0.9, n)
+    scores[n // 2] = np.nan
+    fsyncs = _count_fsyncs(monkeypatch)
+    stream = str(tmp_path / "m.jsonl")
+    m = MetricsLogger(path=stream)
+    prior = trace.configure(m)
+    try:
+        journal_boundary(FusedJournal(led, space), 0, members, u, scores, step=7)
+    finally:
+        trace.deconfigure(prior)
+        m.close()
+    assert len(fsyncs) == 1
+    spans = [r for r in load_stream(stream) if r.get("span") == "journal"]
+    assert [(s["n"], s["fsyncs"]) for s in spans] == [(n, 1)]
+    led.close()
+
+    # the per-record path, over a copy of the same header: n fsyncs, and
+    # the same lines field for field but the wall-clock stamp
+    ref_path = tmp_path / "ref.jsonl"
+    ref_path.write_text(open(led.path).readline())
+    ref = SweepLedger(str(ref_path))
+    del fsyncs[:]
+    for i in members:
+        ref.record_member(
+            trial_id=i, member=i, boundary=0, boundary_size=n,
+            canonical_params=space.canonical_params(_decode_per_element(space, u[i])),
+            score=scores[i], step=7,
+        )
+    ref.close()
+    assert len(fsyncs) == n
+    got, want = _lines(led.path), _lines(str(ref_path))
+    for rec in got + want:
+        del rec["ts"]
+    assert got == want
+    assert open(led.path).read().count("\n") == n + 1
+
+
+def test_record_boundary_joins_an_open_batch(tmp_path, space, monkeypatch):
+    led = _fused_ledger(tmp_path, space)
+    j = FusedJournal(led, space)
+    fsyncs = _count_fsyncs(monkeypatch)
+    with led.batched():
+        # joins the caller's block: no fsync of its own
+        assert j.record_boundary(0, [0, 1, 2], _units(3, space), [0.1, 0.2, 0.3], step=5) == 0
+        assert fsyncs == []
+    assert len(fsyncs) == 1  # the outer block's exit makes both durable
+    assert j.record_boundary(1, [0, 1, 2], _units(3, space), [0.4, 0.5, 0.6], step=9) == 1
+    led.close()
+    assert validate_ledger(led.path) == []
+
+
+class _Killed(BaseException):
+    """The kill: nothing after it runs but the blocks' own exits."""
+
+
+@pytest.mark.parametrize("written", ["whole", "short"])
+def test_kill_inside_the_block_before_its_fsync_recovers(tmp_path, space, monkeypatch, written):
+    """A kill after the block's writes (all of them, or some) and before
+    its fsync: the snapshot of that boundary was never saved, so a
+    resume restores one boundary and re-computes the second. A whole
+    block loads complete and verifies; a short one is a torn final
+    boundary, truncated on load and re-journaled."""
+    led = _fused_ledger(tmp_path, space)
+    u = _units(4, space)
+    j = FusedJournal(led, space)
+    j.record_boundary(0, [0, 1, 2, 3], u, [0.1, 0.2, 0.3, 0.4], step=5)
+    cut = 4 if written == "whole" else 2
+    real_write = led._write_line
+    calls = []
+
+    def write_then_die(rec):
+        real_write(rec)
+        calls.append(rec)
+        if len(calls) == cut:
+            raise _Killed
+
+    monkeypatch.setattr(led, "_write_line", write_then_die)
+    monkeypatch.setattr(os, "fsync", lambda fd: None)  # the kill comes first
+    with pytest.raises(_Killed):
+        j.record_boundary(1, [0, 1, 2, 3], u, [0.5, 0.6, 0.7, 0.8], step=9)
+    led._file.close()
+    monkeypatch.undo()
+
+    led2 = SweepLedger(led.path)
+    assert led2.n_torn_boundary == (0 if written == "whole" else cut)
+    j2 = FusedJournal(led2, space)
+    j2.require_prefix(1)  # the snapshot the resume restores
+    j2.record_boundary(1, [0, 1, 2, 3], u, [0.5, 0.6, 0.7, 0.8], step=9)
+    assert (j2.verified, j2.written) == ((4, 0) if written == "whole" else (0, 4))
+    j2.require_prefix(2)
+    led2.close()
+    assert validate_ledger(led.path) == []
+    assert [r["trial_id"] for r in _lines(led.path)] == list(range(8))

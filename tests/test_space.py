@@ -60,6 +60,46 @@ def test_materialize_row(space):
     assert h["act"] == "gelu"
 
 
+def _decode_per_element(space, row):
+    """The one-scalar-a-dimension decode ``materialize_row`` did before
+    rows were decoded a column at a time (what ``params_key`` keyed)."""
+    from mpi_opt_tpu.utils.hostdev import host_ops
+
+    with host_ops():
+        return {
+            name: dom.materialize(np.asarray(dom.from_unit(jnp.asarray(row[i]))))
+            for i, (name, dom) in enumerate(space.domains.items())
+        }
+
+
+@pytest.mark.parametrize("at", ["zero", "one", "random"])
+def test_materialize_rows_equals_row_by_row(at):
+    space = SearchSpace(
+        {
+            "lr": LogUniform(1e-5, 1e-1),
+            "momentum": Uniform(0.5, 0.99),
+            "layers": IntUniform(1, 7),
+            "act": Choice(["relu", "tanh", "gelu"]),
+            "bias": Choice([True, False, None]),
+            "wd": LogUniform(1e-6, 1e-2),
+        }
+    )
+    n = 257
+    if at == "random":
+        units = np.array(space.sample_unit(jax.random.key(11), n))
+    else:
+        units = np.full((n, space.dim), 0.0 if at == "zero" else 1.0, np.float32)
+    rows = space.materialize_rows(units)
+    assert len(rows) == n
+    for u, got in zip(units, rows):
+        for want in (_decode_per_element(space, u), space.materialize_row(u)):
+            assert list(got) == list(want)
+            # value AND Python type: a ledger's params bytes may not drift
+            assert [(type(v), v) for v in got.values()] == [
+                (type(v), v) for v in want.values()
+            ]
+
+
 def test_discrete_mask(space):
     np.testing.assert_array_equal(space.discrete_mask(), [False, False, True, True])
 
