@@ -101,6 +101,11 @@ class DecoderDims:
     rope_theta: float = 1e7
     eps: float = 1e-6
     loss_rows: int = 1024  # rows of the head's logits formed at once
+    # the router's scores: "softmax" over the published experts, or
+    # "sigmoid" of each; the chosen weights, renormalised, are scaled by
+    # ``routed_scaling``
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0
 
 
 def _dot(eq: str, a, b):
@@ -116,15 +121,21 @@ def rms_norm(x, gain, eps):
     return y.astype(x.dtype)
 
 
-def rope(x, positions, theta):
+def rope(x, positions, theta, interleaved: bool = False):
     """Rotate ``x [T, ..., D]`` by its positions: pair ``i`` is dims
-    ``(i, i + D/2)``, frequency ``theta ** (-i / (D/2))``."""
+    ``(i, i + D/2)`` (``interleaved``: dims ``(2i, 2i + 1)``, left in
+    that order), frequency ``theta ** (-i / (D/2))``."""
     half = x.shape[-1] // 2
     inv = 1.0 / (theta ** (jnp.arange(half, dtype=F32) / half))
     ang = positions.astype(F32)[:, None] * inv[None, :]
     ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (half,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x32 = x.astype(F32)
+    if interleaved:
+        pairs = x32.reshape(x.shape[:-1] + (half, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     a, b = x32[..., :half], x32[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
@@ -271,16 +282,25 @@ def sparse_attention(q, k, v, qi, ki, w, dims: DecoderDims, index_loss: bool):
 # -- the held experts ----------------------------------------------------------------
 
 
-def route(h2, w_router, dims: DecoderDims):
+def route(h2, w_router, dims: DecoderDims, with_choice: bool = False):
     """float32 [T, held]: each token's combine weight for every held
     expert (0 where the expert is not among the token's top choices).
-    Softmax over all PUBLISHED experts, top ``experts_per_token``,
-    weights renormalised over the chosen."""
-    probs = jax.nn.softmax(_dot("td,de->te", h2, w_router), axis=-1)
+    Scores over all PUBLISHED experts (``dims.router_scoring``: a
+    softmax, or a sigmoid of each), top ``experts_per_token``, weights
+    renormalised over the chosen and scaled by ``routed_scaling``.
+    ``with_choice``: (weights, scores [T, published], chosen [T, top])."""
+    logits = _dot("td,de->te", h2, w_router)
+    if dims.router_scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     top, idx = jax.lax.top_k(probs, dims.experts_per_token)
     c = top / jnp.sum(top, axis=-1, keepdims=True)
+    if dims.routed_scaling != 1.0:
+        c = c * dims.routed_scaling
     held = jnp.arange(dims.experts_held)
-    return jnp.sum(jnp.where(idx[:, :, None] == held, c[:, :, None], 0.0), axis=1)
+    gates = jnp.sum(jnp.where(idx[:, :, None] == held, c[:, :, None], 0.0), axis=1)
+    return (gates, probs, idx) if with_choice else gates
 
 
 def _experts_every_token(h2, gates, wg, wu, wd):
@@ -432,18 +452,24 @@ class SparseMoEDecoder(nn.Module):
             counts.append(c)
         g_out = self.param("g_out", nn.initializers.ones, (m.hidden,), F32)
         head = self.param("head", nn.initializers.lecun_normal(), (m.hidden, m.vocab), F32)
-        with jax.named_scope("loss_head"):
-            x = rms_norm(x, g_out, m.eps)
+        return loss_head(x, targets, g_out, head, m), index_loss, jnp.stack(counts)
 
-            @jax.checkpoint
-            def block(xb, yb):
-                logits = _dot("td,dv->tv", xb, head)
-                picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
-                return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
 
-            t = tokens.shape[0]
-            ce = sum(
-                block(x[lo : lo + m.loss_rows], targets[lo : lo + m.loss_rows])
-                for lo in range(0, t, m.loss_rows)
-            )
-        return ce, index_loss, jnp.stack(counts)
+def loss_head(x, targets, g_out, head, dims):
+    """Sum over positions of the next-token cross-entropy: ``rms``, the
+    head over the held vocabulary slice, ``loss_rows`` rows of logits
+    at a time (each block made again in the backward pass)."""
+    with jax.named_scope("loss_head"):
+        x = rms_norm(x, g_out, dims.eps)
+
+        @jax.checkpoint
+        def block(xb, yb):
+            logits = _dot("td,dv->tv", xb, head)
+            picked = jnp.take_along_axis(logits, yb[:, None], axis=-1)[:, 0]
+            return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+        t = targets.shape[0]
+        return sum(
+            block(x[lo : lo + dims.loss_rows], targets[lo : lo + dims.loss_rows])
+            for lo in range(0, t, dims.loss_rows)
+        )

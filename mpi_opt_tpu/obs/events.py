@@ -175,12 +175,17 @@ DEVICE_SCOPES = (
     "gather_members",  # the winners' state copy
     # inside member_loss / eval_population only, so they book no phase
     # of their own and the phases stay an exact partition
-    # (models/sparse_moe_decoder.py); forward, backward and evaluation
+    # (models/sparse_moe_decoder.py, models/latent_moe_decoder.py);
+    # forward, backward and evaluation
     "attention",  # q/k/v/o products, per-head norms, RoPE, attention over the selection
     "indexer",  # index scorer: its products, index scores, the selection, its loss
     "router",  # router product, the top experts a token, dispatch and combine
     "experts",  # the held experts' products
     "loss_head",  # final norm, the head's logits, cross-entropy
+    # Wq, Wkv_a, the latent's norm, Wkv_b, RoPE, the causal attention, Wo
+    "latent_attention",
+    "shared_experts",  # the shared experts' products, every token
+    "dense_mlp",  # a leading dense layer's norm and products
 )
 
 

@@ -24,8 +24,11 @@ heads, softmax in float32); this module only builds the mask
 information inside the traced program, shares one mask by all heads (a
 mask with a head axis of one is the library's own convention for that),
 and ties forward and backward together so that the log-sum-exp is an
-output. ``_INTERPRET`` runs the kernels in the Pallas interpreter (the
-CPU tests flip it).
+output. ``causal_attention`` is the same kernels under a static causal
+mask, for a decoder that attends to every earlier key
+(models/latent_moe_decoder.py): the library's own kernel object, whose
+value width may differ from the query/key width. ``_INTERPRET`` runs
+the kernels in the Pallas interpreter (the CPU tests flip it).
 """
 
 from __future__ import annotations
@@ -36,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask as ml
 from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_mask_info as mi
 
 _INTERPRET = False  # tests flip this for CPU interpret-mode runs
@@ -117,3 +121,22 @@ def _backward(block, res, cts):
 
 
 masked_attention.defvjp(_forward, _backward)
+
+
+def causal_attention(q, k, v, block: int = 512):
+    """Causal softmax attention of ``q [H, T, Dqk]`` (already scaled)
+    over ``k [H, T, Dqk]``, ``v [H, T, Dv]``, one key head a query head:
+    context ``[H, T, Dv]``. The mask is static (each query reads the
+    keys at and before its own position), so the library's own kernel
+    object is built from it at trace time: blocks above the diagonal
+    are skipped, those on it masked inside the kernel. The value width
+    may differ from the query/key width. Differentiable in ``q``,
+    ``k``, ``v``; the forward kernel's context and log-sum-exp carry
+    the name ``RESIDUALS``, as ``masked_attention``'s do."""
+    heads, t, _ = q.shape
+    mask = ml.MultiHeadMask([ml.CausalMask((t, t))] * heads)
+    kernel = sk.make_splash_mha(
+        mask, block_sizes=_block_sizes(t, block), head_shards=1, q_seq_shards=1,
+        residual_checkpoint_name=RESIDUALS, interpret=_INTERPRET,
+    )
+    return kernel(q, k, v)

@@ -267,10 +267,17 @@ def segment_flops_hint(workload, population: int, steps: int):
     and probe compiles are span-suppressed so they don't pollute the
     very attribution they serve. None when tracing is off or the
     backend offers no cost analysis; callers then omit the ``flops``
-    span attr and the trace CLI reports TF/s as unavailable.
+    span attr and the trace CLI reports TF/s as unavailable. A workload
+    that counts its own work from its equations
+    (``segment_flops(population, steps)``) answers instead, and nothing
+    is lowered: at a member of half a billion parameters the probe
+    would lower its programs a second time.
     """
     if not trace.enabled():
         return None
+    analytic = getattr(workload, "segment_flops", None)
+    if analytic is not None:
+        return analytic(int(population), int(steps))
     cache = getattr(workload, "_flops_hint_cache", None)
     if cache is None:
         cache = workload._flops_hint_cache = {}

@@ -1016,11 +1016,17 @@ def fused_pbt(  # sweeplint: barrier(resident host loop: launch boundaries, expl
                     unit = jax.numpy.asarray(unit)
             state = trainer.init_population(k_init, train_x[:2], population)
         if mesh is not None:
-            from mpi_opt_tpu.parallel.mesh import place_pop
+            from mpi_opt_tpu.parallel.mesh import place_pop, replicate
 
             # datasets were already replicated over the mesh by workload_arrays
             state = shard_popstate(state, mesh)
             unit = place_pop(unit, mesh)
+            # the carried key too: launch 1 returns it on the mesh, and an
+            # unplaced first key would make launch 2 compile again. Placed
+            # from the host, as every process may place (a process
+            # cannot put its device's array on the others')
+            k_data = np.asarray(jax.random.key_data(k_run))
+            k_run = jax.random.wrap_key_data(jax.device_put(k_data, replicate(mesh)))
 
     # hparams_fn must be hashable-static; space comes from the per-
     # workload cache above so its identity is stable across calls

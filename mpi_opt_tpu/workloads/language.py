@@ -1,8 +1,10 @@
 """Next-token workloads: a population member that reads token rows.
 
-The member is a decoder with learned sparse attention and one chip's
-share of a mixture of experts (models/sparse_moe_decoder.py), trained on
-seeded token rows (data/tokens.py) and scored on a held-out loss. The
+The member is a decoder with one chip's share of a mixture of experts:
+learned sparse attention (models/sparse_moe_decoder.py, Keye-VL-2.0's
+block) or latent attention with shared experts and a leading dense
+layer (models/latent_moe_decoder.py, Kimi-VL-A3B's), trained on seeded
+token rows (data/tokens.py) and scored on a held-out loss. The
 population protocol is ``PopulationWorkload``'s; what differs from the
 image classifiers is the member handed to the trainer
 (``DecoderMember``: its loss, its score, one validation row a chunk) and
@@ -79,11 +81,40 @@ class DecoderMember:
 
         # a padded row has targets < 0; validation rows divide into
         # chunks of one, so none is ever formed
-        ce, _, _ = self._rows(self._eval, params, cx, jnp.maximum(cy, 0))
+        ce = self._rows(self._eval, params, cx, jnp.maximum(cy, 0))[0]
         return jnp.sum(jnp.where(cy[:, 0] >= 0, ce, 0.0))
 
     def score(self, total, n_val: int):
         return -total / (n_val * self.positions)
+
+
+class LatentDecoderMember(DecoderMember):
+    """The decoder with latent attention and shared experts
+    (``LatentMoEDecoder``) for the trainer: the loss is the mean
+    next-token cross-entropy plus every expert layer's weighted balance
+    loss; its counters are the tokens routed to the held experts a layer
+    (mean over the expert layers), the most any one held expert was sent
+    in any layer, and the MiB the step saves by name from its forward to
+    its backward."""
+
+    counters = ("routed_tokens", "fullest_expert_tokens", "saved_residual_mib")
+
+    def __init__(self, dims, positions: int):
+        import jax.numpy as jnp
+
+        from mpi_opt_tpu.models.latent_moe_decoder import LatentMoEDecoder
+
+        self.score_dtype = jnp.float32
+        self.positions = positions
+        self._train = self._eval = LatentMoEDecoder(dims)
+
+    def loss(self, params, hp, key, bx, by):
+        import jax.numpy as jnp
+
+        # c float32 [rows, expert layers, (routed tokens, fullest expert's)]
+        ce, balance, c, saved = self._rows(self._train, params, bx, by)
+        counters = jnp.stack([jnp.mean(c[..., 0]), jnp.max(c[..., 1]), jnp.sum(saved) / 2**20])
+        return jnp.mean(ce) / bx.shape[1] + jnp.mean(balance), counters
 
 
 @register
@@ -95,6 +126,7 @@ class KeyeVL2Decoder(PopulationWorkload):
     attributes, so a test or a rehearsal runs the same code tiny."""
 
     name = "keye_vl2_30b_a3b"
+    member_cls = DecoderMember
     dataset = "successor_tokens"
     batch_size = 1
     augment = False
@@ -137,7 +169,7 @@ class KeyeVL2Decoder(PopulationWorkload):
 
         from mpi_opt_tpu.train import PopulationTrainer
 
-        member = DecoderMember(self._dims(), self.positions)
+        member = self.member_cls(self._dims(), self.positions)
         if momentum_dtype is None:
             momentum_dtype = resolve_momentum_dtype()
         return PopulationTrainer(
@@ -151,3 +183,39 @@ class KeyeVL2Decoder(PopulationWorkload):
             momentum_dtype=jnp.dtype(momentum_dtype) if momentum_dtype else None,
             member=member,
         )
+
+
+@register
+class KimiVLDecoder(KeyeVL2Decoder):
+    """Kimi-VL-A3B's language model at its published widths, one chip's
+    share: the leading dense layer and 4 of its 26 expert layers, 8 of
+    each expert layer's 64 routed experts (the router stays 64 wide,
+    top 6) beside the two shared experts, 1/8 of the vocabulary, rows of
+    8192 tokens. ``dims`` and the data sizes are plain attributes, as
+    the Keye member's."""
+
+    name = "kimi_vl_a3b"
+    member_cls = LatentDecoderMember
+
+    def __init__(self, n_train=None, n_val=None, positions: int = 8192, dims=None):
+        from mpi_opt_tpu.models.latent_moe_decoder import LatentDims
+
+        dims = dims if dims is not None else dataclasses.asdict(LatentDims())
+        super().__init__(n_train=n_train, n_val=n_val, positions=positions, dims=dims)
+
+    def _dims(self):
+        from mpi_opt_tpu.models.latent_moe_decoder import LatentDims
+
+        return LatentDims(**self.dims)
+
+    def segment_flops(self, population: int, steps: int) -> float:
+        """FLOPs of one generation (``steps`` train steps and one
+        evaluation of every member) from the layer equations
+        (``latent_moe_decoder.forward_macs``): a train step three times
+        a forward pass, recomputation not counted. It stands in for the
+        traced sweep's probe, which would lower the member's programs a
+        second time to count them."""
+        from mpi_opt_tpu.models.latent_moe_decoder import forward_macs
+
+        row = 2.0 * forward_macs(self._dims(), self.positions)
+        return population * row * (3 * steps * self.batch_size + self.n_val)

@@ -175,3 +175,22 @@ def test_selected_attention_compiles_to_kernels_at_the_decoders_widths(on_chip):
         on_chip((kv_heads, keys, d), jnp.bfloat16), on_chip((rows, keys), jnp.bool_),
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_causal_attention_compiles_to_kernels_at_the_latent_decoders_widths(on_chip):
+    """A whole 8192-token row at Kimi-VL-A3B's published widths (16
+    heads, query/key width 192 = 128 + 64 RoPE dims, value width 128):
+    the forward, dq and dkv kernels of ops/selected_attention.py's
+    static causal mask take the two widths as they are, unpadded."""
+    from mpi_opt_tpu.ops.selected_attention import causal_attention
+
+    heads, t, qk, v = 16, 8192, 192, 128
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, 512).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip((heads, t, qk), jnp.bfloat16), on_chip((heads, t, qk), jnp.bfloat16),
+        on_chip((heads, t, v), jnp.bfloat16),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 3

@@ -76,8 +76,10 @@ def decoder_op_names():
 
 
 #: the scopes a member of its own opens inside ``member_loss`` and
-#: ``eval_population`` (models/sparse_moe_decoder.py): they book no phase
-MEMBER_SCOPES = ("attention", "indexer", "router", "experts", "loss_head")
+#: ``eval_population`` (models/sparse_moe_decoder.py,
+#: models/latent_moe_decoder.py): they book no phase
+LATENT_SCOPES = ("latent_attention", "shared_experts", "dense_mlp")
+MEMBER_SCOPES = ("attention", "indexer", "router", "experts", "loss_head") + LATENT_SCOPES
 
 
 def test_benchmark_keeps_the_same_scope_names():
@@ -112,7 +114,7 @@ def test_a_members_own_scopes_are_opened_inside_a_phase_scope_only(decoder_op_na
 @pytest.mark.parametrize(
     "program,scope",
     [("cnn", s) for s in scopes.SCOPES]
-    + [("decoder", s) for s in DEVICE_SCOPES if s != "augment"],
+    + [("decoder", s) for s in DEVICE_SCOPES if s != "augment" and s not in LATENT_SCOPES],
 )
 def test_compiled_program_carries_every_scope(op_names, decoder_op_names, program, scope):
     names = op_names if program == "cnn" else decoder_op_names

@@ -239,6 +239,35 @@ def test_fused_pbt_final_state_sharded(meshed):
         assert not leaf.sharding.is_fully_replicated
 
 
+def test_fused_pbt_on_a_mesh_compiles_its_generation_once(meshed, tmp_path):
+    """Launches after the first reuse the first's program: every carried
+    input (state, unit and the PRNG key) enters launch 1 already placed
+    on the mesh, so what launch 1 returns has the same input types. A
+    key left unplaced came back on the mesh and launch 2 compiled
+    ``run_fused_pbt`` again, inside a timed window on four chips."""
+    from mpi_opt_tpu.obs import trace
+    from mpi_opt_tpu.obs.report import load_stream
+    from mpi_opt_tpu.utils.metrics import MetricsLogger
+
+    mesh, workload = meshed(8, 1)
+    stream = str(tmp_path / "m.jsonl")
+    m = MetricsLogger(path=stream)
+    prior = trace.configure(m)
+    try:
+        # steps_per_gen 7 is this test's own, so its program is new here
+        fused_pbt(workload, population=8, generations=3, steps_per_gen=7, seed=2, mesh=mesh, gen_chunk=1)
+    finally:
+        trace.deconfigure(prior)
+        m.close()
+    spans = [r for r in load_stream(stream) if "span" in r]
+    launches = [i for i, s in enumerate(spans) if s["span"] == "train"]
+    assert [spans[i]["launch"] for i in launches] == [1, 2, 3]
+    in_train = [s for s in spans if s["span"] == "compile" and s["during"] == "train"]
+    assert in_train, "launch 1 compiles the generation"
+    later = [s for s in spans[launches[0] + 1 :] if s["span"] == "compile" and s["during"] == "train"]
+    assert later == []
+
+
 def test_fused_tpe_sharded_matches_unsharded(workload, meshed):
     """Fused TPE over a mesh (incl. a tail generation that does not
     divide the 'pop' axis) must match the single-device trajectory."""
